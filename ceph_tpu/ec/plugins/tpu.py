@@ -10,8 +10,10 @@ plugin because both build identical coding matrices.
 All seven jerasure-compatible techniques are supported; every one reduces
 to a binary matrix, so they all ride the same TPU kernel.  On hosts
 without a TPU (e.g. the monitor validating a profile, reference
-mon/OSDMonitor.cc:7371-7392) JAX falls back to its CPU backend — same
-results, no special-casing.
+mon/OSDMonitor.cc:7371-7392) the same code runs on JAX's CPU backend
+with the XLA kernels — same results.  Which kernel serves is decided
+by platform and geometry (jax_engine gf8_kernel / packet_kernel); on
+a TPU a kernel that fails to compile raises, it is not replaced.
 
 Beyond the reference's synchronous per-stripe API, this plugin exposes
 the batched entry points the OSD write pipeline uses to amortize
@@ -220,7 +222,9 @@ class TpuCodecMixin:
         per-signature executable to warm — just the staging ring and
         the pool matrix at the encode shape.  Idempotent per
         (geometry, chunk_size); ``dirty_cols`` is accepted for API
-        compatibility but no longer selects an executable."""
+        compatibility but no longer selects an executable.  A compile
+        or dispatch failure raises (callers record it, see
+        EncodeBatcher.note_prewarm_error)."""
         if not self.delta_async_supported():
             return
         pre = getattr(self.core.backend, "prewarm_geometry", None)
@@ -230,12 +234,9 @@ class TpuCodecMixin:
                int(chunk_size))
         if key in _PREWARMED_SHAPES:
             return
-        _PREWARMED_SHAPES.add(key)
         z = np.zeros((1, 1, int(chunk_size)), dtype=np.uint8)
-        try:
-            self.delta_encode_batch_async(z, (0,)).wait()
-        except Exception:
-            _PREWARMED_SHAPES.discard(key)  # best-effort
+        self.delta_encode_batch_async(z, (0,)).wait()
+        _PREWARMED_SHAPES.add(key)       # only once it really is warm
 
     def prewarm_decode(self, chunk_size: int, batches=(1,)) -> None:
         """Make the common recovery signatures hot before the first
@@ -249,12 +250,9 @@ class TpuCodecMixin:
             return
         core = self.core
         n = self.k + self.m
-        try:
-            for e in range(n):
-                chosen = tuple(i for i in range(n) if i != e)[:self.k]
-                core._recovery_rows(chosen, (e,))
-        except Exception:
-            return
+        for e in range(n):
+            chosen = tuple(i for i in range(n) if i != e)[:self.k]
+            core._recovery_rows(chosen, (e,))
         pre = getattr(core.backend, "prewarm_geometry", None)
         if pre is not None:
             pre(self.k, chunk_size, batches=batches, w=self.w)
@@ -262,13 +260,10 @@ class TpuCodecMixin:
                int(chunk_size))
         if key in _PREWARMED_SHAPES:
             return
-        _PREWARMED_SHAPES.add(key)
         z = {i: np.zeros((1, int(chunk_size)), dtype=np.uint8)
              for i in range(n) if i != 0}
-        try:
-            self.decode_batch_async(z, int(chunk_size)).wait()
-        except Exception:
-            _PREWARMED_SHAPES.discard(key)  # best-effort
+        self.decode_batch_async(z, int(chunk_size)).wait()
+        _PREWARMED_SHAPES.add(key)       # only once it really is warm
 
     def prewarm_geometry(self, chunk_size: int,
                          batches=(1,)) -> None:
@@ -288,13 +283,10 @@ class TpuCodecMixin:
                    int(chunk_size), int(nb))
             if key in _PREWARMED_SHAPES:
                 continue
-            _PREWARMED_SHAPES.add(key)
             z = np.zeros((max(1, int(nb)), self.k, int(chunk_size)),
                          dtype=np.uint8)
-            try:
-                self.encode_batch_async(z).wait()
-            except Exception:
-                _PREWARMED_SHAPES.discard(key)  # best-effort
+            self.encode_batch_async(z).wait()
+            _PREWARMED_SHAPES.add(key)   # only once it really is warm
 
     def stage_batch(self, data: np.ndarray):
         """Transfer a stripe batch to device HBM ahead of encode."""
@@ -305,8 +297,8 @@ class TpuCodecMixin:
         """Device-resident encode: device array in, device array out (no
         host round trip) — the codec-kernel boundary.  w=8 byte-domain
         codes ride the fused bit-plane MXU pallas kernel (jax_engine
-        gf8_fn routing), packet codes the static XOR-schedule pallas
-        kernel, others the bit-plane XLA path."""
+        gf8_fn routing), packet codes the fused MXU packet kernel
+        (packet_chain_fn), others the bit-plane XLA path."""
         core = self.core
         if core.layout == "byte" and core.w == 8 \
                 and core.coding_matrix is not None:

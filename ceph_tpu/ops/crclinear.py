@@ -270,19 +270,16 @@ class Crc32cLinear:
         """[B, L] uint8 window -> [nbands, B] folded LINEAR partials.
         One bitmatrix apply for the whole window (device when a codec
         backend is supplied — same byte-domain contraction as the EC
-        kernels — else a host matmul)."""
+        kernels — else a host matmul).  A backend that fails raises:
+        the callers own the fallback (and the count of windows the
+        device really served)."""
         stack = np.asarray(stack, dtype=np.uint8)
         Bn = stack.shape[0]
         x = self.stack_blocks(stack)                 # [B, block, nblk]
         M = self.block_bitmatrix(tuple(scales))
-        out = None
         if backend is not None:
-            try:
-                out = np.asarray(
-                    backend.apply_bitmatrix_bytes(M, x, 8))
-            except Exception:
-                out = None
-        if out is None:
+            out = np.asarray(backend.apply_bitmatrix_bytes(M, x, 8))
+        else:
             from .engine import bytes_to_bitplanes
             bits = bytes_to_bitplanes(x, 8)
             ob = (M.astype(np.int64) @ bits.astype(np.int64)) & 1

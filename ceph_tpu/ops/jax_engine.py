@@ -139,8 +139,8 @@ def _xtime(x: jnp.ndarray) -> jnp.ndarray:
 
 def _gf8_chain(data: jnp.ndarray, coeffs) -> jnp.ndarray:
     """GF(2^8) matrix apply as a fused XOR/xtime chain — the portable
-    byte-domain w=8 kernel (CPU fallback; on TPU the fused bit-plane
-    MXU pallas kernel wins — see _gf_mxu_pallas_fn and gf8_fn routing).
+    byte-domain w=8 kernel off-TPU (on TPU the fused bit-plane MXU
+    pallas kernel serves — see gf8_kernel).
 
     Each constant multiply unrolls to xtime shifts + XORs on uint8
     lanes (one fused elementwise kernel; XLA CSEs the shared xtime
@@ -231,61 +231,6 @@ def _packet_chain(data: jnp.ndarray, schedule, w: int,
     return out.reshape(batch, m_out, nw * sw)
 
 
-def _packet_pallas_fn(schedule, w: int, packetsize: int,
-                      interpret: bool = False):
-    """Pallas packet-XOR kernel: one VMEM-resident [k, w, ps] super-word
-    block per grid step computes ALL schedule rows from a single HBM
-    read — the XLA elementwise path re-reads input rows per output,
-    ~fan-in x amplified; this kernel's traffic is read-once/write-once
-    (the decode bound the north star's rebuild MB/s metric lives on).
-    Returns fn: uint8 [batch, k, L] -> [batch, R/w, L]."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    R = len(schedule)
-    m_out = R // w
-    ps = packetsize
-
-    def fn(data):
-        batch, k, L = data.shape
-        sw = w * ps
-        nw = L // sw
-        xin = data.reshape(batch, k, nw, w, ps)
-
-        def kernel(in_ref, out_ref):
-            def get_row(c):
-                j, b = divmod(c, w)
-                return in_ref[0, j, 0, b, :]
-            outs = []
-            for prev, cols in schedule:
-                acc = outs[prev] if prev >= 0 else None
-                for c in cols:
-                    t = get_row(c)
-                    acc = t if acc is None else acc ^ t
-                if acc is None:
-                    acc = jnp.zeros((ps,), jnp.uint8)
-                outs.append(acc)
-            for r, v in enumerate(outs):
-                e, bp = divmod(r, w)
-                out_ref[0, e, 0, bp, :] = v
-
-        out = pl.pallas_call(
-            kernel,
-            grid=(batch, nw),
-            in_specs=[pl.BlockSpec((1, k, 1, w, ps),
-                                   lambda b, i: (b, 0, i, 0, 0),
-                                   memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec((1, m_out, 1, w, ps),
-                                   lambda b, i: (b, 0, i, 0, 0),
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((batch, m_out, nw, w, ps),
-                                           jnp.uint8),
-            interpret=interpret,
-        )(xin)
-        return out.reshape(batch, m_out, L)
-    return fn
-
-
 def _packet_mxu_pallas_fn(B: np.ndarray, w: int, packetsize: int,
                           interpret: bool = False):
     """Fused MXU kernel for packet-layout bitmatrix codes: uint8
@@ -297,14 +242,12 @@ def _packet_mxu_pallas_fn(B: np.ndarray, w: int, packetsize: int,
     VMEM-resident pass per super-word: extract the 8 bit-planes of the
     [k*w, ps] packet block, ONE int8 dot_general over all planes at
     once ([R, k*w] @ [k*w, 8*ps], mod 2 via the int32 accumulator's
-    low bit), repack to bytes.  Replaces the static XOR-schedule chain
-    (_packet_pallas_fn) on the MXU: the chain serializes ~fan-in
-    short VPU ops per output row, which measured ~14 GiB/s HBM on this
-    device where the byte-domain MXU twin (_gf_mxu_pallas_fn) streams
-    ~36 — decode (and with it rebuild MB/s) is bound by exactly this
-    kernel (VERDICT r4 Next #4).  Bit-exact with the CPU oracle: bit j
-    of an XOR of bytes is the mod-2 sum of the operands' bit j
-    (reference jerasure_schedule_encode / jerasure_matrix_decode,
+    low bit), repack to bytes.  On TPU it replaces the static
+    XOR-schedule chain (_packet_chain), which serializes ~fan-in short
+    VPU ops per output row; cauchy-family decode (and with it rebuild
+    MB/s) is bound by exactly this kernel.  Bit-exact with the CPU
+    oracle: bit j of an XOR of bytes is the mod-2 sum of the operands'
+    bit j (reference jerasure_schedule_encode / jerasure_matrix_decode,
     erasure-code/jerasure/ErasureCodeJerasure.cc:170,265 — same
     transform, dense instead of scheduled)."""
     from jax.experimental import pallas as pl
@@ -388,9 +331,7 @@ def _gf_mxu_pallas_fn(B: np.ndarray, k: int, w: int,
     compares), one int8 dot_general on the MXU (mod-2 via the int32
     accumulator's low bit), pack parity bits back to bytes — no HBM
     round trips for the 8x-inflated bit tensors that make the unfused
-    XLA path traffic-bound.  Honest fenced measurement on this device:
-    ~21 GiB/s vs ~7 GiB/s for the fused XOR/xtime chain and ~16 GiB/s
-    for the unfused bit-plane path (see bench.py's harness note).
+    XLA path traffic-bound.
     Bit-exact with the CPU oracle; serves encode (per-pool coding
     bitmatrix) and decode (per-erasure-signature inverse rows)."""
     from jax.experimental import pallas as pl
@@ -447,101 +388,39 @@ def _gf_mxu_pallas_fn(B: np.ndarray, k: int, w: int,
     return fn
 
 
+def gf8_kernel() -> str:
+    """Which kernel serves a byte-domain w=8 row set here.  Kernel
+    choice is by platform (and, for packet codes, geometry) only: on a
+    TPU the Pallas kernel is THE path, and a Mosaic refusal at some
+    block shape raises to the caller instead of quietly handing the
+    geometry to an XLA chain."""
+    return "gf_mxu_pallas" if jax.default_backend() == "tpu" \
+        else "gf8_xor_chain"
+
+
+def packet_kernel(packetsize: int) -> str:
+    """Which kernel serves a packet-layout bitmatrix here: the fused
+    MXU kernel on TPU for lane-aligned packets, the XLA XOR-schedule
+    chain otherwise (same rule as gf8_kernel: no probe, no silent
+    switch)."""
+    if jax.default_backend() == "tpu" and packetsize % 128 == 0:
+        return "packet_mxu_pallas"
+    return "packet_xor_chain"
+
+
 def gf8_inner(rows: np.ndarray):
     """Unjitted traceable kernel for a GF(2^8) row set [.., C, L] ->
     [.., R, L]: the SINGLE source of w=8 kernel routing (fused MXU
     pallas kernel on TPU, XOR/xtime elementwise chain elsewhere),
     shared by JaxBackend.gf8_fn and the mesh data plane
-    (parallel/mesh.py sharded_encode_gf8_fn)."""
+    (parallel/mesh.py sharded_rows_fn)."""
     rows = np.asarray(rows, dtype=np.int64)
-    if pallas_gf_mxu_ok():
+    if gf8_kernel() == "gf_mxu_pallas":
         from .matrix import matrix_to_bitmatrix
         return _gf_mxu_pallas_fn(matrix_to_bitmatrix(rows, 8),
                                  rows.shape[1], 8)
     coeffs = tuple(tuple(int(v) for v in row) for row in rows)
     return functools.partial(_gf8_chain, coeffs=coeffs)
-
-
-_PALLAS_PROBE = {"ok": None, "mxu": None, "pmxu": None}
-
-
-def pallas_gf_mxu_ok() -> bool:
-    """One-time probe of the fused MXU kernel on this platform."""
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:
-        return False
-    if _PALLAS_PROBE["mxu"] is None:
-        try:
-            from .matrix import (matrix_to_bitmatrix,
-                                 reed_sol_vandermonde_coding_matrix)
-            M = reed_sol_vandermonde_coding_matrix(2, 1, 8)
-            fn = jax.jit(_gf_mxu_pallas_fn(matrix_to_bitmatrix(M, 8), 2, 8))
-            x = np.arange(2 * 256, dtype=np.uint8).reshape(1, 2, 256)
-            from .engine import NumpyBackend
-            ref = NumpyBackend().apply_matrix(M, x, 8)
-            _PALLAS_PROBE["mxu"] = bool(
-                np.array_equal(np.asarray(fn(jnp.asarray(x))), ref))
-        except Exception:
-            _PALLAS_PROBE["mxu"] = False
-    return _PALLAS_PROBE["mxu"]
-
-
-def pallas_packet_mxu_ok(w: int, packetsize: int) -> bool:
-    """Whether the fused MXU packet kernel should serve this geometry
-    (preferred over the XOR-schedule chain on TPU — ~2.5x the HBM
-    efficiency); lane-aligned packets plus a one-time bit-exactness
-    smoke probe, mirroring pallas_packet_ok."""
-    try:
-        if jax.default_backend() != "tpu" or packetsize % 128:
-            return False
-    except Exception:
-        return False
-    if _PALLAS_PROBE["pmxu"] is None:
-        try:
-            B = np.array([[1, 0, 1, 1], [0, 1, 1, 0],
-                          [1, 1, 0, 1], [0, 1, 1, 1]], dtype=np.uint8)
-            fn = jax.jit(_packet_mxu_pallas_fn(B, 2, 128))
-            rng = np.random.default_rng(3)
-            x = rng.integers(0, 256, (1, 2, 512), dtype=np.uint8)
-            # numpy oracle: XOR the selected packet rows
-            pk = x.reshape(1, 2, 2, 2, 128).transpose(0, 2, 1, 3, 4) \
-                .reshape(1, 2, 4, 128)
-            rows = np.zeros((1, 2, 4, 128), dtype=np.uint8)
-            for r in range(4):
-                for c in range(4):
-                    if B[r, c]:
-                        rows[:, :, r] ^= pk[:, :, c]
-            ref = rows.reshape(1, 2, 2, 2, 128).transpose(
-                0, 2, 1, 3, 4).reshape(1, 2, 512)
-            _PALLAS_PROBE["pmxu"] = bool(
-                np.array_equal(np.asarray(fn(jnp.asarray(x))), ref))
-        except Exception:
-            _PALLAS_PROBE["pmxu"] = False
-    return _PALLAS_PROBE["pmxu"]
-
-
-def pallas_packet_ok(w: int, packetsize: int) -> bool:
-    """Whether the pallas packet kernel should serve this geometry:
-    TPU platform, lane-aligned packets, and a one-time smoke probe
-    (lowering through unusual plugin platforms may fail — fall back to
-    the XLA chain rather than crash the codec)."""
-    try:
-        if jax.default_backend() != "tpu" or packetsize % 128:
-            return False
-    except Exception:
-        return False
-    if _PALLAS_PROBE["ok"] is None:
-        try:
-            sched = tuple((-1, (c,)) for c in range(8))  # identity w=8
-            fn = jax.jit(_packet_pallas_fn(sched, 8, 128))
-            x = np.arange(8 * 128, dtype=np.uint8).reshape(1, 1, -1)
-            _PALLAS_PROBE["ok"] = bool(
-                np.array_equal(np.asarray(fn(jnp.asarray(x))), x))
-        except Exception:
-            _PALLAS_PROBE["ok"] = False
-    return _PALLAS_PROBE["ok"]
 
 
 def _matmul_mod2(B: jnp.ndarray, bits: jnp.ndarray) -> jnp.ndarray:
@@ -841,6 +720,12 @@ class AsyncBatch:
                 except Exception:
                     ledger["device"] = 0
 
+    @property
+    def device_ids(self) -> list:
+        """Ids of the devices the output is laid out on (one on a
+        single chip, every mesh device on a sharded dispatch)."""
+        return sorted(d.id for d in self._dev.sharding.device_set)
+
     def wait(self) -> np.ndarray:
         led = self.ledger
         if led is not None:
@@ -869,9 +754,10 @@ class AsyncBatch:
 
 
 class JaxBackend:
-    """Backend for CodecCore executing on the default JAX device (TPU when
-    present, CPU otherwise — the monitor-without-TPU fallback required by
-    SURVEY.md section 7)."""
+    """Backend for CodecCore executing on the default JAX platform.
+    A host without a TPU (a monitor validating a profile, tier-1) runs
+    the XLA:CPU kernels — chosen by platform, see gf8_kernel; on a TPU
+    nothing here falls back to them."""
 
     name = "jax"
 
@@ -890,6 +776,15 @@ class JaxBackend:
         self._mesh_sharding = None    # cached NamedSharding(dp, None, sp)
         self.mesh_events: list = []   # mesh_build records for the
                                       # flight recorder (batcher drains)
+        # dispatches per kernel name (gf8_kernel / packet_kernel /
+        # the XLA bit-plane applies): the evidence of WHICH kernel
+        # served, for dump_device and chip_smoke.py
+        self.kernel_calls: dict = {}
+        self._kernel_lock = threading.Lock()
+
+    def _note_kernel(self, name: str) -> None:
+        with self._kernel_lock:
+            self.kernel_calls[name] = self.kernel_calls.get(name, 0) + 1
 
     # -- staging ring ------------------------------------------------
     def configure_staging(self, depth: int = 0) -> None:
@@ -1147,10 +1042,7 @@ class JaxBackend:
         hundreds of geometries and XLA-CPU compile time of the
         unrolled chain dominates — there the runtime-arg bit-plane
         path serves."""
-        try:
-            return jax.default_backend() == "tpu"
-        except Exception:
-            return False
+        return jax.default_backend() == "tpu"
 
     def apply_gf8_matrix(self, M: np.ndarray, data: np.ndarray
                          ) -> np.ndarray:
@@ -1192,6 +1084,7 @@ class JaxBackend:
         rows = np.asarray(rows, dtype=np.int64)
         donate = donate and rows.shape[0] == rows.shape[1]
         coeffs = tuple(tuple(int(v) for v in row) for row in rows)
+        self._note_kernel(gf8_kernel())
         if mesh is not None:
             from ..parallel import mesh as pmesh
             dp = int(mesh.shape["dp"])
@@ -1233,16 +1126,16 @@ class JaxBackend:
         jitted [batch, k, L] -> [batch, R/w, L] callable."""
         key = ("pkt", B.shape, B.tobytes(), w, packetsize)  # copycheck: ok - cache key over a tiny bitmatrix, not payload
 
+        kernel = packet_kernel(packetsize)
+
         def build():
-            if pallas_packet_mxu_ok(w, packetsize):
+            if kernel == "packet_mxu_pallas":
                 return jax.jit(_packet_mxu_pallas_fn(
                     np.asarray(B, dtype=np.uint8), w, packetsize))
-            schedule = build_xor_schedule(B)
-            if pallas_packet_ok(w, packetsize):
-                return jax.jit(_packet_pallas_fn(schedule, w, packetsize))
             return jax.jit(functools.partial(
-                _packet_chain, schedule=schedule, w=w,
+                _packet_chain, schedule=build_xor_schedule(B), w=w,
                 packetsize=packetsize))
+        self._note_kernel(kernel)
         return self._chain_lru.get_or_build(key, build)
 
     def apply_packet_xor(self, B: np.ndarray, data: np.ndarray, w: int,
@@ -1344,6 +1237,7 @@ class JaxBackend:
             raise ValueError(
                 f"chunk length must be a multiple of {wbytes} for w={w}")
         padded, batch, L = self._padded(data, LENGTH_QUANTUM * wbytes)
+        self._note_kernel("bitplane_xla")
         out = _apply_byte_domain(self._device_matrix(B),
                                  jnp.asarray(padded), w)
         out = np.asarray(out)[:batch, :, :L]
@@ -1367,6 +1261,7 @@ class JaxBackend:
                 f"chunk length must be a multiple of {wbytes} for w={w}")
         dev, batch, L, done, sample, ledger, mesh = self._staged_put(
             data, LENGTH_QUANTUM * wbytes)
+        self._note_kernel("bitplane_xla")
         try:
             if mesh is not None:
                 out = self._mesh_apply_fn(mesh, w)(
@@ -1394,6 +1289,7 @@ class JaxBackend:
         device.  This is the codec-kernel boundary — the analog of the
         reference benchmark timing encode() over buffers in RAM
         (reference test/erasure-code/ceph_erasure_code_benchmark.cc:251)."""
+        self._note_kernel("bitplane_xla")
         return _apply_byte_domain(self._device_matrix(B), dev_data, w)
 
     def stage(self, data: np.ndarray, w: int):
@@ -1417,6 +1313,7 @@ class JaxBackend:
         lead = data.shape[:-2]
         data = data.reshape((-1,) + data.shape[-2:])
         padded, batch, L = self._padded(data, w * packetsize)
+        self._note_kernel("packet_bitplane_xla")
         out = _apply_packet_domain(self._device_matrix(B),
                                    jnp.asarray(padded), w, packetsize)
         out = np.asarray(out)[:batch, :, :L]

@@ -1,37 +1,22 @@
 """ctypes binding for the native GF kernels (native/gf_native.cc).
 
-Builds the shared library on demand with g++ (the image ships no
-pybind11; ctypes is the sanctioned binding route).  Falls back cleanly if
-no compiler is available — callers check ``available()``.
+Builds the shared library on demand with g++ for this host
+(utils/nativebuild.py; the image ships no pybind11, ctypes is the
+sanctioned binding route).  Falls back cleanly if no compiler is
+available — callers check ``available()``.
 """
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 from typing import Optional
 
 import numpy as np
 
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-_SRC = os.path.join(_ROOT, "native", "gf_native.cc")
-_SO = os.path.join(_ROOT, "native", "libceph_tpu_gf.so")
+from ..utils import nativebuild
 
 _lib: Optional[ctypes.CDLL] = None
 _lock = threading.Lock()
-
-
-def _build() -> bool:
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-             "-o", _SO, _SRC],
-            check=True, capture_output=True, timeout=120)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -39,14 +24,8 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO) or (
-                os.path.exists(_SRC) and
-                os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            if not _build():
-                return None
-        try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+        lib = nativebuild.load("gf_native.cc", "libceph_tpu_gf")
+        if lib is None:
             return None
         lib.gf8_init()
         u8p = ctypes.POINTER(ctypes.c_uint8)

@@ -54,6 +54,7 @@ from ..msg.messages import (MOSDECSubOpRead, MOSDECSubOpReadReply,
 from ..store.objectstore import GHObject, Transaction
 from ..utils import copytrack
 from ..utils import faults as faultlib
+from ..utils.log import derr_once
 from . import ecutil
 from .backend import OI_ATTR, Mutation, ObjectInfo, PGBackend, PGHost
 from .pglog import Eversion, LogEntry
@@ -310,11 +311,19 @@ class ECBackend(PGBackend):
 
         warm_dec = getattr(self.ec_impl, "prewarm_decode", None)
 
+        def failed(where: str, exc: Exception) -> None:
+            # the PG stays up; the evidence goes to the log and to
+            # dump_device (EncodeBatcher.note_prewarm_error)
+            if batcher is not None:
+                batcher.note_prewarm_error(where, exc)
+            else:
+                derr_once("tpu", f"prewarm {where}", exc)
+
         def work():
             try:
                 warm(chunk, batches=batches)
-            except Exception:
-                pass             # warms are best-effort
+            except Exception as e:
+                failed("activation.encode", e)
             if warm_dec is not None:
                 # decode-side activation warm (ISSUE 11): the common
                 # single-erasure recovery signatures (combined
@@ -326,8 +335,8 @@ class ECBackend(PGBackend):
                 # (EncodeBatcher._dec_min_bytes).
                 try:
                     warm_dec(chunk, batches=batches)
-                except Exception:
-                    pass
+                except Exception as e:
+                    failed("activation.decode", e)
 
         threading.Thread(target=work, name="ec-activate-prewarm",
                          daemon=True).start()
@@ -2327,7 +2336,11 @@ class ECBackend(PGBackend):
                         entry["data_crc"] = int(crcs[idx])
                 self.scrub_device_windows = getattr(
                     self, "scrub_device_windows", 0) + 1
-            except Exception:
+            except Exception as e:
+                if not isinstance(e, _HostCrcWindow):
+                    self.scrub_device_errors = getattr(
+                        self, "scrub_device_errors", 0) + 1
+                    derr_once("scrub", "deep-scrub device crc", e)
                 for entry, data, _h in window:
                     entry["data_crc"] = ecutil.chunk_crc(data)
             self.scrub_windows = getattr(self, "scrub_windows", 0) + 1

@@ -25,10 +25,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-try:                                    # jax >= 0.5
-    from jax import shard_map
-except ImportError:                     # jax 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.jax_engine import _matmul_mod2
@@ -164,7 +161,8 @@ def sharded_encode_gf8_fn(mesh: Mesh, coding_matrix: np.ndarray,
         # feature, not a per-write cost
         fn = shard_map(inner, mesh=mesh,
                        in_specs=(P("dp", None, "sp"),),
-                       out_specs=P("dp", None, "sp"))
+                       out_specs=P("dp", None, "sp"),
+                       check_vma=False)
         return jax.jit(fn)
 
     def local_encode(data):
@@ -176,7 +174,8 @@ def sharded_encode_gf8_fn(mesh: Mesh, coding_matrix: np.ndarray,
     fn = shard_map(
         local_encode, mesh=mesh,
         in_specs=(P("dp", None, "sp"),),
-        out_specs=(P("dp", None, "sp"), P()))
+        out_specs=(P("dp", None, "sp"), P()),
+        check_vma=False)
     return jax.jit(fn)
 
 
@@ -188,11 +187,17 @@ def sharded_rows_fn(mesh: Mesh, rows: np.ndarray, donate: bool = False):
     coding matrix) and the PR 11 ``decode_batch_async`` recovery-row
     apply (rows = stacked recovery rows); per-shard math is the same
     kernel, so chunks stay bit-exact vs single-chip.  ``donate`` is
-    only legal for square row sets (output bytes == input bytes)."""
+    only legal for square row sets (output bytes == input bytes).
+
+    ``check_vma=False``: on TPU the per-shard kernel is a
+    ``pallas_call``, whose ``out_shape`` carries no varying-axes
+    annotation, and shard_map's default check refuses to trace it.
+    The body has no collective for the check to protect."""
     from ..ops import jax_engine as je
     fn = shard_map(je.gf8_inner(rows), mesh=mesh,
                    in_specs=(P("dp", None, "sp"),),
-                   out_specs=P("dp", None, "sp"))
+                   out_specs=P("dp", None, "sp"),
+                   check_vma=False)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 

@@ -66,6 +66,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from ..utils.crc import crc32c
 from ..utils.finisher import Finisher
+from ..utils.log import derr_once
 from .blockstore import BLOCK, BitmapAllocator, BlockStore, _Extents
 from .kv import MemDB, LogDB, WriteBatch
 from .objectstore import (_TXN_TLS, GHObject, Transaction, check_ops)
@@ -165,6 +166,7 @@ class BlueStore(BlockStore):
         self.csum_batches = 0
         self.csum_blocks = 0
         self.csum_device_batches = 0
+        self.csum_device_errors = 0
 
     # -- lifecycle -----------------------------------------------------
     def mkfs(self) -> None:
@@ -760,8 +762,10 @@ class BlueStore(BlockStore):
                             blocks, backend=backend)
                         self.csum_device_batches += 1
                         return [int(c) for c in out]
-            except Exception:
-                pass                     # host loop serves
+            except Exception as e:
+                # host loop serves; the failure stays visible
+                self.csum_device_errors += 1
+                derr_once("store", "bluestore device csum", e)
         return [crc32c(b) for b in blocks]
 
     # -- read barrier ----------------------------------------------------
@@ -933,9 +937,18 @@ class BlueStore(BlockStore):
             "vectored_blocks": self.vectored_blocks,
             "vectored_runs": self.vectored_runs,
         }
-        out["csum"] = {
-            "batches": self.csum_batches,
-            "blocks": self.csum_blocks,
-            "device_batches": self.csum_device_batches,
-        }
+        out["csum"] = self._csum_stats()
+        return out
+
+    def _csum_stats(self) -> Dict:
+        return {"batches": self.csum_batches,
+                "blocks": self.csum_blocks,
+                "device_batches": self.csum_device_batches,
+                "device_errors": self.csum_device_errors}
+
+    def dump_store(self) -> dict:
+        """The base payload plus where the checksums ran: the device
+        route has to be provable over the admin-command path."""
+        out = super().dump_store()
+        out["csum"] = self._csum_stats()
         return out

@@ -191,8 +191,8 @@ def _split_argv(argv: List[str]) -> Tuple[List[str], List[str]]:
     return opts, words
 
 
-def _tell(cluster, target: str, cmd: dict, timeout: float
-          ) -> Tuple[int, str, dict]:
+def tell(cluster, target: str, cmd: dict, timeout: float
+         ) -> Tuple[int, str, dict]:
     """Direct daemon command (reference 'ceph tell osd.N ...' over
     MCommand): resolve the daemon's address from the osdmap, dial it,
     await the reply."""
@@ -228,16 +228,22 @@ def _tell(cluster, target: str, cmd: dict, timeout: float
                 return True
             return False
 
-    cluster.msgr.add_dispatcher(_Collector())
-    # lossy, like every client->daemon dial: a lossless session would
-    # leave the OSD waiting forever for this short-lived CLI process
-    # to reconnect
-    conn = cluster.msgr.connect_to(tuple(info["addr"]),
-                                   lossless=False,
-                                   peer_name=f"osd.{osd}")
-    conn.send_message(MCommand(tid=1, cmd=cmd))
-    if not got.wait(timeout):
-        return -110, f"osd.{osd} did not answer", {}
+    collector = _Collector()
+    cluster.msgr.add_dispatcher(collector)
+    try:
+        # lossy, like every client->daemon dial: a lossless session
+        # would leave the OSD waiting forever for this short-lived CLI
+        # process to reconnect
+        conn = cluster.msgr.connect_to(tuple(info["addr"]),
+                                       lossless=False,
+                                       peer_name=f"osd.{osd}")
+        conn.send_message(MCommand(tid=1, cmd=cmd))
+        if not got.wait(timeout):
+            return -110, f"osd.{osd} did not answer", {}
+    finally:
+        # a caller may tell again on this handle: a collector left in
+        # place would swallow the next reply
+        cluster.msgr.dispatchers.remove(collector)
     m = reply["msg"]
     return m.retcode, m.rs, m.out
 
@@ -264,8 +270,8 @@ def main(argv: List[str] = None) -> int:
 
     with connect(ns.mon) as cluster:
         if "_tell" in cmd:
-            retcode, rs, out = _tell(cluster, cmd.pop("_tell"), cmd,
-                                     ns.timeout)
+            retcode, rs, out = tell(cluster, cmd.pop("_tell"), cmd,
+                                    ns.timeout)
         else:
             retcode, rs, out = cluster.mon_command(cmd, ns.timeout)
     print_out(rs, out, ns.format == "json")

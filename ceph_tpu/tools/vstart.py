@@ -61,7 +61,11 @@ def main(argv: List[str] = None) -> int:
     ns = p.parse_args(argv)
 
     from ..cluster import Cluster, test_config
+    from ..utils import compile_cache
 
+    # this process owns the device: without the persistent cache every
+    # start recompiles every pool geometry
+    compile_cache.configure()
     conf = test_config(osd_backend=ns.osd_backend)
     cluster = Cluster(n_osds=ns.num_osds, data_dir=ns.data_dir,
                       conf=conf, n_mons=ns.num_mons, with_mgr=ns.mgr,

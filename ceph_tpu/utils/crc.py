@@ -3,21 +3,17 @@
 Python-native equivalent of the reference's crc32c facade (reference
 src/common/crc32c.h choosing intel-fast / aarch64 / sctp at runtime):
 ``crc32c(data, crc=0)`` dispatches to native/crc32c.cc (built on
-demand via g++/ctypes like the GF kernels) and falls back to a
-table-driven Python implementation when no compiler is present.
+demand for this host, utils/nativebuild.py, like the GF kernels) and
+falls back to a table-driven Python implementation when no compiler
+is present.
 """
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 from typing import Optional
 
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-_SRC = os.path.join(_ROOT, "native", "crc32c.cc")
-_SO = os.path.join(_ROOT, "native", "libceph_tpu_crc32c.so")
+from . import nativebuild
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -30,19 +26,8 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or (
-                os.path.exists(_SRC) and
-                os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            try:
-                subprocess.run(
-                    ["g++", "-O3", "-march=native", "-shared",
-                     "-fPIC", "-o", _SO, _SRC],
-                    check=True, capture_output=True, timeout=120)
-            except (OSError, subprocess.SubprocessError):
-                return None
-        try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+        lib = nativebuild.load("crc32c.cc", "libceph_tpu_crc32c")
+        if lib is None:
             return None
         lib.crc32c_init()
         lib.crc32c.restype = ctypes.c_uint32

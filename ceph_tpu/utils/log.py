@@ -18,6 +18,7 @@ from __future__ import annotations
 import logging
 import sys
 import threading
+import traceback
 from typing import Dict
 
 # the reference's subsystem table, trimmed to what exists here
@@ -95,6 +96,26 @@ def get_subsys_level(subsys: str) -> int:
         return int(conf.get("debug_default_level"))
     except Exception:
         return 1
+
+
+_once: set = set()
+_once_lock = threading.Lock()
+_ONCE_CAP = 256
+
+
+def derr_once(subsys: str, where: str, exc: BaseException) -> None:
+    """Log ``exc`` with its traceback at error level, once per distinct
+    message per process.  For failures a fallback path then absorbs
+    (device dispatch, prewarm, device CRC): they must be diagnosable
+    from the log alone, but a sick device fails every call the same
+    way."""
+    msg = f"{where}: {exc!r}"
+    with _once_lock:
+        if msg in _once or len(_once) >= _ONCE_CAP:
+            return
+        _once.add(msg)
+    Dout(subsys).derr("%s\n%s", msg, "".join(traceback.format_exception(
+        type(exc), exc, exc.__traceback__)))
 
 
 class Dout:

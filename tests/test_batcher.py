@@ -850,6 +850,57 @@ def test_pin_routed_twin_group_is_reason_coded(codec):
         b.stop()
 
 
+def test_kernel_compile_failure_is_recorded_with_its_message(
+        codec, monkeypatch):
+    """A kernel that cannot be built (on the chip: a Mosaic refusal at
+    some block shape) must not vanish into the twin: the write is still
+    served, but the exception's text reaches the flight recorder, the
+    error counter and ``dump_device`` — and prewarm records it before
+    the first client op meets it."""
+    from ceph_tpu.ops import jax_engine as je
+    from ceph_tpu.utils.flight_recorder import FlightRecorder
+    from ceph_tpu.utils.perf import PerfCountersCollection
+
+    def refuse(*_a, **_kw):
+        raise RuntimeError("mosaic refused block shape (1, 8, 524288)")
+    be = codec.core.backend
+    monkeypatch.setattr(type(be), "gf8_fast_path", lambda self: True)
+    monkeypatch.setattr(je, "gf8_inner", refuse)
+    monkeypatch.setattr(be, "_chain_lru", je.ChainLRU(8))
+    coll = PerfCountersCollection()
+    rec = FlightRecorder(capacity=64, name="osd.t4")
+    EncodeBatcher.reset_learning()
+    b = EncodeBatcher({"ec_tpu_queue_window_us": 1000,
+                       "ec_tpu_fallback_cpu": False,
+                       "ec_tpu_device_retry_ms": 0.0},
+                      perf_coll=coll, recorder=rec)
+    try:
+        sinfo = ecutil.StripeInfo(2, 8192)
+        data = os.urandom(2 * 8192)
+        out = {}
+        done = threading.Event()
+        b.submit(codec, sinfo, data,
+                 lambda c: (out.update(c or {}), done.set()))
+        assert done.wait(30)
+        twin = b.cpu_twin(codec, sinfo)
+        assert out == ecutil.encode(sinfo, twin, data)
+        assert coll.perf_dump()["ec_batcher"]["device_errors"] == 1
+        errs = [e for e in rec.dump() if e["kind"] == "device_error"]
+        assert errs and "mosaic refused" in errs[0]["exc"]
+        dump = b.device_dump()
+        assert "mosaic refused" in dump["last_device_error"]
+        assert dump["device_errors"] == 1
+        # the same failure met at prewarm is kept for dump_device
+        with pytest.raises(RuntimeError, match="mosaic refused"):
+            codec.prewarm_geometry(4096, batches=(2,))
+        b.note_prewarm_error("activation.encode",
+                             RuntimeError("mosaic refused"))
+        assert any("mosaic refused" in e["error"]
+                   for e in b.device_dump()["prewarm_errors"])
+    finally:
+        b.stop()
+
+
 def test_breaker_transitions_are_recorded_and_auto_dumped(codec,
                                                           capsys):
     """Opening the breaker records the device_error run and the

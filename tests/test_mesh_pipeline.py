@@ -201,6 +201,34 @@ def test_mesh_vs_single_chip_decode_identical(backend):
         assert np.array_equal(rec_mesh[e], shards[e])
 
 
+def test_pallas_kernel_traces_under_sharded_rows_fn(monkeypatch):
+    """On TPU ``gf8_inner`` returns the fused Pallas kernel, and
+    ``sharded_rows_fn`` wraps it in ``shard_map``.  Under the installed
+    JAX that wrapper refused to trace (``check_vma=True`` wants a
+    ``vma`` on the pallas ``out_shape``), which CPU routing never
+    reaches — so push the kernel through in interpret mode on the
+    virtual mesh and hold it to the jerasure oracle."""
+    from ceph_tpu.ops import jax_engine as je
+    from ceph_tpu.ops.matrix import matrix_to_bitmatrix
+    from ceph_tpu.parallel import mesh as pmesh
+
+    def pallas_inner(rows):
+        rows = np.asarray(rows, dtype=np.int64)
+        return je._gf_mxu_pallas_fn(matrix_to_bitmatrix(rows, 8),
+                                    rows.shape[1], 8, interpret=True)
+    monkeypatch.setattr(je, "gf8_inner", pallas_inner)
+    k, m, cs = 8, 4, 512
+    cpu = make_cpu(k, m)
+    mesh = pmesh.make_mesh(4)                    # 2 x 2, as on a v5e-4
+    rng = np.random.default_rng(21)
+    data = rng.integers(0, 256, (4, k, cs), dtype=np.uint8)
+    fn = pmesh.sharded_rows_fn(mesh, cpu.core.coding_matrix)
+    out = fn(pmesh.shard_batch(mesh, data))
+    assert len(out.sharding.device_set) == 4
+    ref = np.stack([cpu.core.encode(data[b]) for b in range(4)])
+    assert np.array_equal(np.asarray(out), ref)
+
+
 # ---------------------------------------------------------------------
 # per-device observability (PR 10 machinery, no schema change)
 # ---------------------------------------------------------------------

@@ -131,11 +131,6 @@ def test_no_data_exits_2(tmp_path, history):
     fresh.write_text("no metrics here\n")
     r = _run_cli(fresh, history)
     assert r.returncode == 2
-    # real committed history must parse end-to-end too
-    paths = perf_trend.default_history_paths()
-    assert paths, "BENCH_r0*.json history missing from the repo"
-    rounds = perf_trend.load_history(paths)
-    assert any(r2["records"] for r2 in rounds)
 
 
 def _scaling(mbps16, clients=None):

@@ -1,0 +1,89 @@
+"""The Pallas kernels through the real TPU compiler, without a TPU.
+
+libtpu can describe a v5e topology on a host that has no chip, and
+``jit(f).lower(...).compile()`` against its devices runs XLA:TPU and
+Mosaic for real.  Nothing executes, so this says nothing about results
+(the interpret-mode tests in test_tpu_plugin.py guard the arithmetic)
+— but a block shape Mosaic refuses, a VMEM overrun or a shard_map
+wrapper that does not trace fails HERE, in tier-1, instead of on the
+next chip run, where the batcher's twin would have hidden it.
+Shapes are the ones chip_smoke.py and the OSD batcher dispatch.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from ceph_tpu.ec import registry as ecreg
+from ceph_tpu.ops import jax_engine as je
+from ceph_tpu.ops.matrix import (matrix_to_bitmatrix,
+                                 reed_sol_vandermonde_coding_matrix)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no libtpu here: nothing to compile with
+        pytest.skip(f"no TPU compiler on this host: {e!r}")
+    return topo.devices
+
+
+def compile_for(fn, shape, sharding):
+    arg = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=sharding)
+    return jax.jit(fn).lower(arg).compile()
+
+
+RS_K8M4 = matrix_to_bitmatrix(
+    reed_sol_vandermonde_coding_matrix(8, 4, 8), 8)
+
+
+@pytest.mark.parametrize("shape", [
+    (128, 8, 4096),              # one 4 MiB object at the 4 KiB unit
+    (64, 8, 131072),             # 1 MiB stripes
+])
+def test_gf_mxu_kernel_compiles_for_v5e(v5e, shape):
+    compile_for(je._gf_mxu_pallas_fn(RS_K8M4, 8, 8), shape,
+                SingleDeviceSharding(v5e[0]))
+
+
+@pytest.mark.slow
+def test_gf_mxu_kernel_compiles_at_the_block_cap(v5e):
+    """4 MiB stripes: _pick_block_len hits its 1<<19 cap, the largest
+    VMEM block the kernel ever asks for (~15 s of compile)."""
+    compile_for(je._gf_mxu_pallas_fn(RS_K8M4, 8, 8), (8, 8, 524288),
+                SingleDeviceSharding(v5e[0]))
+
+
+def test_packet_mxu_kernel_compiles_for_v5e(v5e):
+    """cauchy_good k=10 m=4 at 4 MiB stripes (BASELINE config 3)."""
+    cg = ecreg.instance().factory(
+        "jerasure", {"k": "10", "m": "4", "technique": "cauchy_good"})
+    core = cg.core
+    compile_for(
+        je._packet_mxu_pallas_fn(np.asarray(core.bitmatrix, np.uint8),
+                                 core.w, core.packetsize),
+        (8, 10, cg.get_chunk_size(4 << 20)),
+        SingleDeviceSharding(v5e[0]))
+
+
+def test_sharded_rows_fn_compiles_for_a_v5e_2x2_mesh(v5e, monkeypatch):
+    """The production mesh dispatch with the kernel a TPU host picks:
+    shard_map around the pallas_call, with and without donation."""
+    from ceph_tpu.parallel import mesh as pmesh
+    monkeypatch.setattr(je, "gf8_kernel", lambda: "gf_mxu_pallas")
+    mesh = Mesh(np.array(v5e).reshape(2, 2), ("dp", "sp"))
+    sharding = NamedSharding(mesh, P("dp", None, "sp"))
+    for rows, donate in (
+            (reed_sol_vandermonde_coding_matrix(8, 4, 8), False),
+            (reed_sol_vandermonde_coding_matrix(2, 2, 8), True)):
+        fn = pmesh.sharded_rows_fn(mesh, rows, donate=donate)
+        arg = jax.ShapeDtypeStruct((1024, rows.shape[1], 4096),
+                                   jnp.uint8, sharding=sharding)
+        out = fn.lower(arg).compile().output_shardings
+        assert out.spec == P("dp", None, "sp")

@@ -215,30 +215,6 @@ def test_packet_static_path_forced_on_cpu(monkeypatch):
     assert np.array_equal(out[3], ref[:, 0])
 
 
-def test_packet_pallas_kernel_interpret():
-    """The pallas packet-XOR kernel (TPU fast path for cauchy-family
-    encode/decode) must match the XLA schedule chain bit-for-bit —
-    verified via pallas interpret mode so the CPU suite guards the
-    TPU kernel's logic."""
-    import jax.numpy as jnp
-
-    from ceph_tpu.ops.jax_engine import (_packet_chain, _packet_pallas_fn,
-                                         build_xor_schedule)
-    from ceph_tpu.ops.matrix import matrix_to_bitmatrix
-    from ceph_tpu.ops.matrix import reed_sol_vandermonde_coding_matrix
-    w, ps, k, m = 8, 128, 3, 2
-    B = matrix_to_bitmatrix(
-        reed_sol_vandermonde_coding_matrix(k, m, w), w)
-    sched = build_xor_schedule(B)
-    rng = np.random.default_rng(31)
-    data = rng.integers(0, 256, (2, k, 2 * w * ps), dtype=np.uint8)
-    ref = np.asarray(_packet_chain(jnp.asarray(data), sched, w, ps))
-    out = np.asarray(
-        _packet_pallas_fn(sched, w, ps, interpret=True)(
-            jnp.asarray(data)))
-    assert np.array_equal(out, ref)
-
-
 def test_packet_mxu_pallas_kernel_interpret():
     """The fused MXU packet kernel (the TPU fast path that replaced
     the XOR-schedule chain for cauchy-family encode AND per-signature
@@ -289,6 +265,41 @@ def test_gf_mxu_pallas_kernel_interpret():
             jnp.asarray(data)))
         ref = NumpyBackend().apply_matrix(mat, data, 8)
         assert np.array_equal(out, ref), (mat.shape, L)
+
+
+def test_kernel_builder_failure_raises_no_chain_fallback(monkeypatch):
+    """Kernel choice is by platform and geometry only.  Where the
+    platform says Pallas, a builder that raises (on the chip: Mosaic
+    refusing a block shape) reaches the caller — the codec does not
+    quietly serve the geometry from an XLA chain."""
+    from ceph_tpu.ops import jax_engine as je
+    from ceph_tpu.ops.jax_engine import JaxBackend
+
+    def refuse(*_a, **_kw):
+        raise RuntimeError("mosaic refused")
+    be = JaxBackend()
+    monkeypatch.setattr(JaxBackend, "gf8_fast_path", lambda self: True)
+    monkeypatch.setattr(je, "gf8_kernel", lambda: "gf_mxu_pallas")
+    monkeypatch.setattr(je, "packet_kernel",
+                        lambda ps: "packet_mxu_pallas")
+    monkeypatch.setattr(je, "_gf_mxu_pallas_fn", refuse)
+    monkeypatch.setattr(je, "_packet_mxu_pallas_fn", refuse)
+    reg = ecreg.instance()
+    rs = reg.factory("tpu", {"k": "3", "m": "2"})
+    rs.core.backend = be
+    data = np.zeros((2, 3, 256), dtype=np.uint8)
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        rs.encode_batch(data)
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        rs.encode_batch_async(data)
+    cg = reg.factory("tpu", {"k": "3", "m": "2",
+                             "technique": "cauchy_good",
+                             "packetsize": "128"})
+    cg.core.backend = be
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        cg.encode_batch(np.zeros((1, 3, cg.w * 128), dtype=np.uint8))
+    assert be.kernel_calls == {"gf_mxu_pallas": 2,
+                               "packet_mxu_pallas": 1}
 
 
 def test_gf8_decode_rows_lru(monkeypatch):

@@ -142,3 +142,24 @@ class TestOpTracker:
         t = OpTracker(slow_op_warn_threshold=0.0)
         t.create("slowpoke")
         assert len(t.slow_ops()) == 1
+
+
+def test_native_library_is_keyed_by_source_and_host_cpu(monkeypatch):
+    """The -march=native helpers build into a name that carries the
+    source text and this host's CPU, so a binary carried in from
+    another machine (or built from other source) has another name and
+    is never loaded; a checkout without one builds it."""
+    from ceph_tpu.utils import nativebuild
+
+    here = nativebuild.lib_path("crc32c.cc", "libceph_tpu_crc32c")
+    assert os.path.dirname(here).endswith(os.path.join("native", "build"))
+    monkeypatch.setattr(nativebuild, "_host_cpu",
+                        lambda: "x86_64\nmodel name: some other host")
+    elsewhere = nativebuild.lib_path("crc32c.cc", "libceph_tpu_crc32c")
+    assert elsewhere != here
+    monkeypatch.undo()
+    assert nativebuild.lib_path("gf_native.cc", "libceph_tpu_crc32c") \
+        != here                          # other source, other name
+    lib = nativebuild.load("crc32c.cc", "libceph_tpu_crc32c")
+    if lib is not None:                  # a compiler is present
+        assert os.path.exists(here)
