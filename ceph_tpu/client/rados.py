@@ -33,6 +33,7 @@ from ..osd.osdmap import OSDMap, PGid
 from ..utils.config import Config, default_config
 from ..utils.hops import HopAccum
 from ..utils.log import Dout
+from ..utils.tracer import section
 
 # reply code the OSD uses for "wrong primary / stale map, refresh and
 # resend" (reference: the client resends on a newer map rather than on
@@ -214,7 +215,10 @@ class Objecter(Dispatcher):
             op.snapid = snapid
             self.inflight[tid] = op
             self._inflight_bytes += nbytes
-        self._send_op(op)
+        # the wait for the window above is not the section's
+        with section("objecter.submit", op=f"{self.msgr.name}:{tid}",
+                     bytes=nbytes):
+            self._send_op(op)
         return completion
 
     def _route_pool(self, osdmap: OSDMap, op: _InflightOp) -> int:
@@ -327,16 +331,18 @@ class Objecter(Dispatcher):
             self.monc.subscribe_osdmap(msg.epoch)
             threading.Timer(0.05, self._send_op, args=(op,)).start()
             return True
-        with self.lock:
-            self._retire(msg.tid)
-        # final hop: the reply carried the op's cumulative ledger back;
-        # close it and fold the completed waterfall into the client view
-        msg.stamp_hop("client_complete")
-        if getattr(op, "is_write", True):
-            self.hops.observe_wire(msg.hops)
-        else:
-            self.hops_read.observe_wire(msg.hops)
-        op.completion._complete(msg)
+        with section("objecter.reply", op=f"{self.msgr.name}:{msg.tid}"):
+            with self.lock:
+                self._retire(msg.tid)
+            # final hop: the reply carried the op's cumulative ledger
+            # back; close it and fold the completed waterfall into the
+            # client view
+            msg.stamp_hop("client_complete")
+            if getattr(op, "is_write", True):
+                self.hops.observe_wire(msg.hops)
+            else:
+                self.hops_read.observe_wire(msg.hops)
+            op.completion._complete(msg)
         return True
 
     def trace_bundle(self) -> dict:

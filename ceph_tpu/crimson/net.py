@@ -33,6 +33,7 @@ from ..msg.messages import MAck
 from ..msg.messenger import (ACK_EVERY_BYTES, ACK_EVERY_MSGS, MAX_FRAME,
                              _IOV_BATCH, Connection, Messenger)
 from ..utils.encoding import DecodeError
+from ..utils.tracer import section
 from .reactor import Reactor
 
 # recv chunk per call; level-triggered readiness re-arms anything left
@@ -269,34 +270,47 @@ class CrimsonConnection(Connection):
                 return
             # stamped BEFORE encode so it rides the wire
             msg.stamp_hop("wire_sent")
-            for part in encode_frame_parts(
-                    msg, compressor=self.msgr.compressor,
-                    compress_min=self.msgr.compress_min,
-                    crc_data=self.msgr.conf["ms_crc_data"]):
-                self._wq.append(part if isinstance(part, memoryview)
-                                else memoryview(part))
-        try:
-            wq = self._wq
-            while wq:
-                n = sock.sendmsg([wq[i] for i in
-                                  range(min(len(wq), _IOV_BATCH))])
-                while n > 0 and wq:
-                    first = len(wq[0])
-                    if n >= first:
-                        n -= first
-                        wq.popleft()
-                    else:
-                        wq[0] = wq[0][n:]
-                        n = 0
-        except (BlockingIOError, InterruptedError):
-            pass
-        except (OSError, ConnectionError):
-            self._io_error(sock, gen)
+            with section("msgr.encode", type=type(msg).__name__):
+                for part in encode_frame_parts(
+                        msg, compressor=self.msgr.compressor,
+                        compress_min=self.msgr.compress_min,
+                        crc_data=self.msgr.conf["ms_crc_data"]):
+                    self._wq.append(part if isinstance(part, memoryview)
+                                    else memoryview(part))
+        if self._wq and not self._send_queued(sock, gen):
             return
         want = bool(self._wq)
         if want != self._wants_write:
             self._wants_write = want
             self.reactor.want_write(sock, want)
+
+    def _send_queued(self, sock, gen) -> bool:
+        """Push the write queue at the socket until it would block;
+        False when the socket died."""
+        sent = 0
+        with section("msgr.send", peer=self.peer_name) as sec:
+            try:
+                wq = self._wq
+                while wq:
+                    n = sock.sendmsg([wq[i] for i in
+                                      range(min(len(wq), _IOV_BATCH))])
+                    sent += n
+                    while n > 0 and wq:
+                        first = len(wq[0])
+                        if n >= first:
+                            n -= first
+                            wq.popleft()
+                        else:
+                            wq[0] = wq[0][n:]
+                            n = 0
+            except (BlockingIOError, InterruptedError):
+                pass
+            except (OSError, ConnectionError):
+                self._io_error(sock, gen)
+                return False
+            finally:
+                sec.set_metadata(bytes=sent)
+        return True
 
     # -- read pump ---------------------------------------------------------
     def _on_readable(self) -> None:
@@ -338,20 +352,25 @@ class CrimsonConnection(Connection):
             self._reactor.want_read(sock, True)
 
     def _recv_rounds(self, sock, gen) -> None:
-        try:
-            for _ in range(_RECV_ROUNDS):
-                chunk = sock.recv(_RECV_CHUNK)
-                if not chunk:
-                    self._io_error(sock, gen)
-                    return
-                self._rbuf += chunk
-                if len(chunk) < _RECV_CHUNK:
-                    break
-        except (BlockingIOError, InterruptedError):
-            pass
-        except (OSError, ConnectionError):
-            self._io_error(sock, gen)
-            return
+        got = 0
+        with section("msgr.recv", peer=self.peer_name) as sec:
+            try:
+                for _ in range(_RECV_ROUNDS):
+                    chunk = sock.recv(_RECV_CHUNK)
+                    if not chunk:
+                        self._io_error(sock, gen)
+                        return
+                    self._rbuf += chunk
+                    got += len(chunk)
+                    if len(chunk) < _RECV_CHUNK:
+                        break
+            except (BlockingIOError, InterruptedError):
+                pass
+            except (OSError, ConnectionError):
+                self._io_error(sock, gen)
+                return
+            finally:
+                sec.set_metadata(bytes=got)
         self._parse_frames(sock, gen)
 
     def _parse_frames(self, sock, gen) -> None:
@@ -381,7 +400,9 @@ class CrimsonConnection(Connection):
             view.release()
             del buf[:total]
             try:
-                msg = decode_frame_body(mtype, seq, head, payload, crc)
+                with section("msgr.decode", bytes=plen):
+                    msg = decode_frame_body(mtype, seq, head, payload,
+                                            crc)
                 msg.stamp_hop("recv")
             except DecodeError:
                 if self.msgr.conf["ms_die_on_bad_msg"]:

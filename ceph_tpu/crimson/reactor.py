@@ -44,6 +44,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ..utils.tracer import fn_name, section
+
 
 class Future:
     """Minimal completion token for reactor continuation chains.
@@ -186,6 +188,7 @@ class Reactor:
         # stats surfaced by tests / admin socket
         self.ticks = 0
         self.callbacks_run = 0
+        self.callbacks_failed = 0    # exceptions the loop swallowed
         self.xshard_in = 0           # mailbox items this reactor ran
         self.xshard_out = 0          # items this reactor sent away
         self.mailbox_hwm = 0         # max inbound depth seen at drain
@@ -267,6 +270,7 @@ class Reactor:
                     nxt.cancelled = holder[0].cancelled
                     holder[0] = nxt
 
+        _fire.__qualname__ = fn_name(fn)    # what a trace's fn= shows
         first = self.call_later(interval, _fire)
         holder.append(first)
 
@@ -365,7 +369,9 @@ class Reactor:
                 self.xshard_in += 1
                 if stats is not None:
                     stats.on_wait("xshard_handoff", now - t_enq)
-                self._run_submitted(fn, args, fut)
+                with section("reactor.mailbox", d=self._name,
+                             fn=fn_name(fn)):
+                    self._run_submitted(fn, args, fut)
 
     def future(self) -> Future:
         return Future(self)
@@ -469,8 +475,23 @@ class Reactor:
                 break
             except RuntimeError:
                 continue
-        return [{"ts": ts, "util": u, "loop_lag_s": lag}
-                for ts, u, lag in snap]
+        return [{"ts": ts, "util": u, "loop_lag_s": lag,
+                 "callbacks_failed": failed}
+                for ts, u, lag, failed in snap]
+
+    def _failed(self, sec, exc: Exception) -> None:
+        # the loop outlives every callback; what it swallowed is
+        # counted and named on the section that was open
+        self.callbacks_failed += 1
+        sec.set_metadata(error=type(exc).__name__)
+
+    def _call(self, name: str, fn: Callable, *args) -> None:
+        """One callback of the loop, as a section of its own."""
+        with section(name, d=self._name, fn=fn_name(fn)) as sec:
+            try:
+                fn(*args)
+            except Exception as e:  # noqa: BLE001
+                self._failed(sec, e)
 
     # ---------------------------------------------------------------- loop
     _UTIL_SAMPLE_TICKS = 64
@@ -518,24 +539,25 @@ class Reactor:
                 if ent is None:
                     continue
                 _sock, on_r, on_w = ent
-                try:
-                    if (mask & selectors.EVENT_READ) and on_r is not None:
-                        on_r()
-                    if (mask & selectors.EVENT_WRITE) and on_w is not None:
-                        # handler may have unregistered in on_r()
-                        if key.data in self._handlers:
-                            on_w()
-                except Exception:  # noqa: BLE001 — a conn dying must not
-                    pass           # take the whole reactor with it
+                with section("reactor.io", d=self._name,
+                             fn=fn_name(on_r or on_w)) as sec:
+                    try:
+                        if (mask & selectors.EVENT_READ) and \
+                                on_r is not None:
+                            on_r()
+                        if (mask & selectors.EVENT_WRITE) and \
+                                on_w is not None:
+                            # handler may have unregistered in on_r()
+                            if key.data in self._handlers:
+                                on_w()
+                    except Exception as e:  # noqa: BLE001 — a conn dying
+                        self._failed(sec, e)  # must not take the reactor
 
             self._drain_mailboxes()
             self._run_timers()
             self._drain_ready()
             for hook in self._tick_hooks:
-                try:
-                    hook()
-                except Exception:  # noqa: BLE001
-                    pass
+                self._call("reactor.tick_hook", hook)
             self.ticks += 1
             t_end = time.monotonic()
             busy = t_end - t_work
@@ -546,7 +568,7 @@ class Reactor:
                 if wall > 0:
                     self.util_samples.append(
                         (time.time(), min(1.0, win_busy / wall),
-                         self.loop_lag_s))
+                         self.loop_lag_s, self.callbacks_failed))
                 win_t0, win_busy = t_end, 0.0
         # drop whatever is left; the OSD is shutting down
         try:
@@ -568,10 +590,7 @@ class Reactor:
                 t = heapq.heappop(self._timers)
             if t.cancelled:
                 continue
-            try:
-                t.fn(*t.args)
-            except Exception:  # noqa: BLE001
-                pass
+            self._call("reactor.timer", t.fn, *t.args)
 
     def _drain_ready(self) -> None:
         # drain until empty so continuations scheduled by this tick's
@@ -586,10 +605,7 @@ class Reactor:
                 return
             for fn, args in batch:
                 self.callbacks_run += 1
-                try:
-                    fn(*args)
-                except Exception:  # noqa: BLE001
-                    pass
+                self._call("reactor.cb", fn, *args)
                 # timers must not wait out the whole drain: heartbeats
                 # and stats reports are reactor timers now, and under a
                 # write flood a single drain can run seconds of encode

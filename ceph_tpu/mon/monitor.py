@@ -52,6 +52,7 @@ from ..store.kv import KeyValueDB, LogDB, MemDB, WriteBatch
 from ..utils.config import Config, default_config
 from ..utils.lockdep import make_lock
 from ..utils.log import Dout
+from ..utils.tracer import section
 
 DEFAULT_STRIPE_UNIT = 4096      # reference osd_pool_erasure_code_stripe_unit
 REDIRECT_RETCODE = -301         # "ask the leader" (MonClient retries)
@@ -323,35 +324,36 @@ class Monitor(Dispatcher):
     # dispatch
     # ------------------------------------------------------------------
     def ms_dispatch(self, conn: Connection, msg) -> bool:
-        if isinstance(msg, MMonMon):
-            self.quorum.handle(msg)
-            return True
-        if isinstance(msg, MMonSubscribe):
-            self._handle_subscribe(conn, msg)
-        elif isinstance(msg, MMonCommand):
-            self._handle_command(conn, msg)
-        elif isinstance(msg, (MOSDBoot, MOSDFailure, MPGStats)):
-            # map-mutating / aggregate reports belong to the leader; a
-            # peon relays (reference mons forward to the leader via
-            # MRoute/MForward)
-            if not self.quorum.is_leader():
-                self._forward_to_leader(msg)
-                if isinstance(msg, MOSDBoot):
-                    # still remember the direct session for scrub etc.
-                    self._note_osd_conn(conn, msg)
+        with section("mon.dispatch", type=type(msg).__name__):
+            if isinstance(msg, MMonMon):
+                self.quorum.handle(msg)
                 return True
-            try:
-                if isinstance(msg, MOSDBoot):
-                    self._handle_boot(conn, msg)
-                elif isinstance(msg, MOSDFailure):
-                    self._handle_failure(conn, msg)
-                else:
-                    self._handle_pg_stats(conn, msg)
-            except Monitor.NoQuorum:
-                pass                     # senders re-announce
-        else:
-            return False
-        return True
+            if isinstance(msg, MMonSubscribe):
+                self._handle_subscribe(conn, msg)
+            elif isinstance(msg, MMonCommand):
+                self._handle_command(conn, msg)
+            elif isinstance(msg, (MOSDBoot, MOSDFailure, MPGStats)):
+                # map-mutating / aggregate reports belong to the leader; a
+                # peon relays (reference mons forward to the leader via
+                # MRoute/MForward)
+                if not self.quorum.is_leader():
+                    self._forward_to_leader(msg)
+                    if isinstance(msg, MOSDBoot):
+                        # still remember the direct session for scrub etc.
+                        self._note_osd_conn(conn, msg)
+                    return True
+                try:
+                    if isinstance(msg, MOSDBoot):
+                        self._handle_boot(conn, msg)
+                    elif isinstance(msg, MOSDFailure):
+                        self._handle_failure(conn, msg)
+                    else:
+                        self._handle_pg_stats(conn, msg)
+                except Monitor.NoQuorum:
+                    pass                     # senders re-announce
+            else:
+                return False
+            return True
 
     def _forward_to_leader(self, msg) -> None:
         addr = self.quorum.leader_addr()
@@ -568,7 +570,8 @@ class Monitor(Dispatcher):
         interval = self.conf["mon_tick_interval"]
         while not self._stop.wait(interval):
             try:
-                self._tick()
+                with section("mon.tick", d=self.msgr.name):
+                    self._tick()
             except Monitor.NoQuorum:
                 pass                     # aging retries next tick
             except Exception as e:

@@ -50,6 +50,7 @@ from ..utils.encoding import DecodeError
 from .message import (CRC_LEN, HEADER_LEN, Message, decode_frame_body,
                       decode_frame_header, encode_frame_parts)
 from .messages import MAck
+from ..utils.tracer import section
 
 # ack cadence: trim the peer's resend queue at least this often
 ACK_EVERY_MSGS = 32
@@ -468,10 +469,16 @@ class Connection:
                         raise ConnectionError("injected socket failure")
                     # stamped BEFORE encode so it rides the wire
                     msg.stamp_hop("wire_sent")
-                    _sendmsg_all(sock, encode_frame_parts(
-                        msg, compressor=self.msgr.compressor,
-                        compress_min=self.msgr.compress_min,
-                        crc_data=self.msgr.conf["ms_crc_data"]))
+                    with section("msgr.encode", d=self.msgr.name,
+                                 type=type(msg).__name__):
+                        parts = encode_frame_parts(
+                            msg, compressor=self.msgr.compressor,
+                            compress_min=self.msgr.compress_min,
+                            crc_data=self.msgr.conf["ms_crc_data"])
+                    with section("msgr.send", d=self.msgr.name,
+                                 peer=self.peer_name,
+                                 bytes=sum(map(len, parts))):
+                        _sendmsg_all(sock, parts)
                 except (OSError, ConnectionError):
                     self._socket_dead(sock, gen)
                     break
@@ -489,10 +496,16 @@ class Connection:
                     mtype, seq, plen = decode_frame_header(head)
                     if plen > MAX_FRAME:
                         raise DecodeError(f"oversized frame {plen}")
-                    payload = _read_exact(sock, plen)
-                    crc = _read_exact(sock, CRC_LEN)
-                    msg = decode_frame_body(mtype, seq, head, payload,
-                                            crc)
+                    # the header read above parks until a frame comes;
+                    # from here on its bytes are on their way
+                    with section("msgr.recv", d=self.msgr.name,
+                                 peer=self.peer_name, bytes=plen):
+                        payload = _read_exact(sock, plen)
+                        crc = _read_exact(sock, CRC_LEN)
+                    with section("msgr.decode", d=self.msgr.name,
+                                 bytes=plen):
+                        msg = decode_frame_body(mtype, seq, head,
+                                                payload, crc)
                     msg.stamp_hop("recv")
                 except (OSError, ConnectionError, DecodeError) as e:
                     if isinstance(e, DecodeError) and \
@@ -700,6 +713,7 @@ class Messenger:
     def _reconnect(self, conn: Connection) -> None:
         retry = self.conf["ms_connection_retry_interval"]
         max_backoff = self.conf["ms_max_backoff"]
+        attempt = 0
         try:
             while True:
                 with self.lock:
@@ -709,28 +723,37 @@ class Messenger:
                     if conn.state != "connecting":
                         return
                     in_seq = conn.in_seq
-                try:
-                    sock = socket.create_connection(conn.peer_addr,
-                                                    timeout=5.0)
-                    sock.setsockopt(socket.IPPROTO_TCP,
-                                    socket.TCP_NODELAY, 1)
-                    rcvbuf = self.conf["ms_tcp_rcvbuf"]
-                    if rcvbuf:
-                        sock.setsockopt(socket.SOL_SOCKET,
-                                        socket.SO_RCVBUF, rcvbuf)
-                    _send_banner(sock, self.name, self.nonce, in_seq,
-                                 conn.lossless)
-                    if self.auth_required:
-                        c_chal, a_chal = _auth_exchange(
-                            sock, self.auth_key, acceptor=False)
-                        sock = _secure_negotiate(
-                            sock, self.auth_key, c_chal, a_chal,
-                            acceptor=False,
-                            want_secure=self.secure_mode)
-                    peer_name, peer_nonce, peer_in_seq, _ = \
-                        _recv_banner(sock)
-                    sock.settimeout(None)
-                except (OSError, ConnectionError):
+                attempt += 1
+                # one attempt's work; the back-off sleep lies outside
+                with section("msgr.reconnect", d=self.name,
+                             peer=conn.intended_peer or
+                             "%s:%d" % tuple(conn.peer_addr[:2]),
+                             attempt=attempt) as sec:
+                    try:
+                        sock = socket.create_connection(conn.peer_addr,
+                                                        timeout=5.0)
+                        sock.setsockopt(socket.IPPROTO_TCP,
+                                        socket.TCP_NODELAY, 1)
+                        rcvbuf = self.conf["ms_tcp_rcvbuf"]
+                        if rcvbuf:
+                            sock.setsockopt(socket.SOL_SOCKET,
+                                            socket.SO_RCVBUF, rcvbuf)
+                        _send_banner(sock, self.name, self.nonce, in_seq,
+                                     conn.lossless)
+                        if self.auth_required:
+                            c_chal, a_chal = _auth_exchange(
+                                sock, self.auth_key, acceptor=False)
+                            sock = _secure_negotiate(
+                                sock, self.auth_key, c_chal, a_chal,
+                                acceptor=False,
+                                want_secure=self.secure_mode)
+                        peer_name, peer_nonce, peer_in_seq, _ = \
+                            _recv_banner(sock)
+                        sock.settimeout(None)
+                    except (OSError, ConnectionError) as e:
+                        sec.set_metadata(error=type(e).__name__)
+                        sock = None
+                if sock is None:
                     if not conn.lossless:
                         conn._close(reset=True)
                         return
@@ -873,14 +896,17 @@ class Messenger:
 
     # -- plumbing ----------------------------------------------------------
     def _dispatch(self, conn: Connection, msg: Message) -> None:
-        for d in self.dispatchers:
-            try:
-                if d.ms_dispatch(conn, msg):
+        with section("msgr.dispatch", d=self.name,
+                     type=type(msg).__name__, peer=conn.peer_name) as sec:
+            for d in self.dispatchers:
+                try:
+                    if d.ms_dispatch(conn, msg):
+                        return
+                except Exception as e:
+                    import traceback
+                    traceback.print_exc()
+                    sec.set_metadata(error=type(e).__name__)
                     return
-            except Exception:
-                import traceback
-                traceback.print_exc()
-                return
 
     def _conn_closed(self, conn: Connection) -> None:
         with self.lock:

@@ -39,6 +39,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..utils.tracer import section
+
 # Lane-friendly length quantum: last dim tiles of 128 on TPU.
 LENGTH_QUANTUM = 128
 
@@ -258,7 +260,8 @@ def _packet_mxu_pallas_fn(B: np.ndarray, w: int, packetsize: int,
     ps = packetsize
     Bconst = jnp.asarray(B, dtype=jnp.int8)
 
-    def fn(data):
+    # the closure's name is the XLA module's: jit_packet_mxu_pallas
+    def packet_mxu_pallas(data):
         batch, k_, L = data.shape
         sw = w * ps
         nw = L // sw
@@ -308,7 +311,7 @@ def _packet_mxu_pallas_fn(B: np.ndarray, w: int, packetsize: int,
             interpret=interpret,
         )(Bconst, xin)
         return out.reshape(batch, m_out, L)
-    return fn
+    return packet_mxu_pallas
 
 
 def _pick_block_len(L: int, cap: int = 1 << 19) -> int:
@@ -346,7 +349,8 @@ def _gf_mxu_pallas_fn(B: np.ndarray, k: int, w: int,
     Bconst = jnp.asarray(B[np.ix_(rowp, colp)], dtype=jnp.int8)
     TB = 16384
 
-    def fn(data):
+    # the closure's name is the XLA module's: jit_gf8_mxu_pallas
+    def gf8_mxu_pallas(data):
         batch, k_, L = data.shape
         # pad to a 128-multiple so the block length always divides L
         # (zeros are harmless: the code is GF-linear); callers that
@@ -385,7 +389,7 @@ def _gf_mxu_pallas_fn(B: np.ndarray, k: int, w: int,
             interpret=interpret,
         )(Bconst, data)
         return out[:, :, :L] if Lp != L else out
-    return fn
+    return gf8_mxu_pallas
 
 
 def gf8_kernel() -> str:
@@ -489,6 +493,26 @@ def _bucket_batch(b: int) -> int:
     if b <= 1:
         return 1
     return 1 << (b - 1).bit_length()
+
+
+def _fetch(out, batch: int, L: int) -> np.ndarray:
+    """Join a dispatched output and bring it to the host, trimmed."""
+    with section("dispatch.wait"):
+        out.block_until_ready()
+    with section("dispatch.d2h", bytes=out.nbytes):
+        return np.asarray(out)[:batch, :, :L]
+
+
+def _run_sync(kernel: str, fn, padded: np.ndarray, batch: int,
+              L: int) -> np.ndarray:
+    """The synchronous twin of _staged_put + AsyncBatch.wait: put,
+    call, join, fetch."""
+    with section("dispatch.h2d", bytes=padded.nbytes,
+                 batch=padded.shape[0]):
+        dev = jnp.asarray(padded)
+    with section("dispatch.call", kernel=kernel):
+        out = fn(dev)
+    return _fetch(out, batch, L)
 
 
 class _StageSlot:
@@ -731,12 +755,14 @@ class AsyncBatch:
         if led is not None:
             # split the join into its real phases: compute fence,
             # then the d2h materialisation, then the zero-copy trim
-            try:
-                self._dev.block_until_ready()
-            except Exception:
-                pass             # deleted/donated output == retired
+            with section("dispatch.wait"):
+                try:
+                    self._dev.block_until_ready()
+                except Exception:
+                    pass         # deleted/donated output == retired
             led["compute_done"] = time.time()
-            host = np.asarray(self._dev)
+            with section("dispatch.d2h", bytes=self._dev.nbytes):
+                host = np.asarray(self._dev)
             led["d2h_done"] = time.time()
             out = host[:self._batch, :, :self._L]
             out = out.reshape(self._lead + out.shape[-2:])
@@ -749,7 +775,7 @@ class AsyncBatch:
                                      bytes=led["bytes"] // n)
                                 for d in ids]
             return out
-        out = np.asarray(self._dev)[:self._batch, :, :self._L]
+        out = _fetch(self._dev, self._batch, self._L)
         return out.reshape(self._lead + out.shape[-2:])
 
 
@@ -927,7 +953,8 @@ class JaxBackend:
         if not self.bucket_shapes:
             ledger = {"stage_acquire": time.time()}
             ledger["h2d_start"] = ledger["stage_acquire"]
-            dev = jax.device_put(data)
+            with section("dispatch.h2d", bytes=data.nbytes, batch=batch):
+                dev = jax.device_put(data)
             ledger["h2d_done"] = time.time()
             return dev, batch, L, None, None, ledger, None
         mesh = self._resolve_mesh()
@@ -948,44 +975,50 @@ class JaxBackend:
                 # zero-stripes, stripped on deliver)
                 bb = _round_up(bb, int(mesh.shape["dp"]))
         shape = (bb, k, Lp)
-        slot = self.staging.acquire(shape)
+        # a full ring waits here for the oldest batch's fence
+        with section("dispatch.stage_acquire", batch=bb):
+            slot = self.staging.acquire(shape)
         # ledger origin: the slot is ours (ring fence retired).  The
         # interval ending at h2d_start is the host fill; h2d_done is
         # exact on fenced samples, dispatch-time otherwise.
         ledger = {"stage_acquire": time.time()}
         try:
-            host = slot.host
-            host[:batch, :, :L] = data  # copycheck: ok - staging fill into a REUSED persistent buffer (the one h2d copy)
-            if slot.max_l > L:
-                # stale columns from a longer previous batch: packet-layout
-                # kernels mix columns within a super-word window, so the
-                # pad region must stay zero (GF-linear => zeros are inert)
-                host[:, :, L:slot.max_l] = 0
-            slot.max_l = max(slot.max_l, L)
-            if mesh is not None and slot.max_b > batch:
-                # mesh dp-padding contract: rows past the live batch
-                # are zero-stripes (stale stripes from a fuller
-                # previous batch would still be trimmed on deliver,
-                # but the sharded layout promises zero padding rows)
-                host[batch:slot.max_b, :, :] = 0  # copycheck: ok - zeroing dp-padding rows of the REUSED staging buffer, not a payload copy
-            slot.max_b = max(slot.max_b, batch)
-            sample = None
-            ledger["h2d_start"] = time.time()
-            sharding = self._mesh_sharding if mesh is not None else None
-            if self.staging.should_sample():
-                t0 = time.monotonic()
-                dev = jax.device_put(host, sharding) \
-                    if sharding is not None else jax.device_put(host)
-                try:
-                    dev.block_until_ready()
-                    dt = time.monotonic() - t0
-                    self.staging.note_h2d(host.nbytes, dt)
-                    sample = (host.nbytes, dt)
-                except Exception:
-                    pass
-            else:
-                dev = jax.device_put(host, sharding) \
-                    if sharding is not None else jax.device_put(host)
+            # the fill of the staging slot and the transfer of all of
+            # it, padding included
+            with section("dispatch.h2d", bytes=slot.host.nbytes,
+                         batch=bb):
+                host = slot.host
+                host[:batch, :, :L] = data  # copycheck: ok - staging fill into a REUSED persistent buffer (the one h2d copy)
+                if slot.max_l > L:
+                    # stale columns from a longer previous batch: packet-layout
+                    # kernels mix columns within a super-word window, so the
+                    # pad region must stay zero (GF-linear => zeros are inert)
+                    host[:, :, L:slot.max_l] = 0
+                slot.max_l = max(slot.max_l, L)
+                if mesh is not None and slot.max_b > batch:
+                    # mesh dp-padding contract: rows past the live batch
+                    # are zero-stripes (stale stripes from a fuller
+                    # previous batch would still be trimmed on deliver,
+                    # but the sharded layout promises zero padding rows)
+                    host[batch:slot.max_b, :, :] = 0  # copycheck: ok - zeroing dp-padding rows of the REUSED staging buffer, not a payload copy
+                slot.max_b = max(slot.max_b, batch)
+                sample = None
+                ledger["h2d_start"] = time.time()
+                sharding = self._mesh_sharding if mesh is not None else None
+                if self.staging.should_sample():
+                    t0 = time.monotonic()
+                    dev = jax.device_put(host, sharding) \
+                        if sharding is not None else jax.device_put(host)
+                    try:
+                        dev.block_until_ready()
+                        dt = time.monotonic() - t0
+                        self.staging.note_h2d(host.nbytes, dt)
+                        sample = (host.nbytes, dt)
+                    except Exception:
+                        pass
+                else:
+                    dev = jax.device_put(host, sharding) \
+                        if sharding is not None else jax.device_put(host)
             ledger["h2d_done"] = time.time()
         except BaseException:
             # staging/h2d failed before a fence existed: return the
@@ -1059,8 +1092,7 @@ class JaxBackend:
         lead = data.shape[:-2]
         data = data.reshape((-1,) + data.shape[-2:])
         padded, batch, L = self._padded(data, LENGTH_QUANTUM)
-        out = self.gf8_fn(M)(jnp.asarray(padded))
-        out = np.asarray(out)[:batch, :, :L]
+        out = _run_sync(gf8_kernel(), self.gf8_fn(M), padded, batch, L)
         out = out.reshape(lead + out.shape[-2:])
         return out[0] if squeeze else out
 
@@ -1115,8 +1147,8 @@ class JaxBackend:
         lead = data.shape[:-2]
         data = data.reshape((-1,) + data.shape[-2:])
         padded, batch, L = self._padded(data, LENGTH_QUANTUM)
-        out = self.gf8_fn(rows)(jnp.asarray(padded))
-        out = np.asarray(out)[:batch, :, :L]
+        out = _run_sync(gf8_kernel(), self.gf8_fn(rows), padded, batch,
+                        L)
         out = out.reshape(lead + out.shape[-2:])
         return out[0] if squeeze else out
 
@@ -1149,8 +1181,9 @@ class JaxBackend:
         lead = data.shape[:-2]
         data = data.reshape((-1,) + data.shape[-2:])
         padded, batch, L = self._padded(data, w * packetsize)
-        out = self.packet_chain_fn(B, w, packetsize)(jnp.asarray(padded))
-        out = np.asarray(out)[:batch, :, :L]
+        out = _run_sync(packet_kernel(packetsize),
+                        self.packet_chain_fn(B, w, packetsize), padded,
+                        batch, L)
         out = out.reshape(lead + out.shape[-2:])
         return out[0] if squeeze else out
 
@@ -1170,9 +1203,11 @@ class JaxBackend:
         dev, batch, L, done, sample, ledger, mesh = self._staged_put(
             data, LENGTH_QUANTUM)
         try:
-            out = self.gf8_fn(M, donate=done is not None, mesh=mesh)(dev)
-            ledger["compute_start"] = time.time()
-            out.copy_to_host_async()
+            with section("dispatch.call", kernel=gf8_kernel()):
+                out = self.gf8_fn(M, donate=done is not None,
+                                  mesh=mesh)(dev)
+                ledger["compute_start"] = time.time()
+                out.copy_to_host_async()
         except BaseException:
             # kernel dispatch failed: no fence will ever retire, so
             # hand the slot back unfenced instead of leaking it
@@ -1208,10 +1243,11 @@ class JaxBackend:
         dev, batch, L, done, sample, ledger, mesh = self._staged_put(
             data, LENGTH_QUANTUM)
         try:
-            out = self.gf8_fn(rows, donate=done is not None,
-                              mesh=mesh)(dev)
-            ledger["compute_start"] = time.time()
-            out.copy_to_host_async()
+            with section("dispatch.call", kernel=gf8_kernel()):
+                out = self.gf8_fn(rows, donate=done is not None,
+                                  mesh=mesh)(dev)
+                ledger["compute_start"] = time.time()
+                out.copy_to_host_async()
         except BaseException:
             # kernel dispatch failed: no fence will ever retire, so
             # hand the slot back unfenced instead of leaking it
@@ -1238,9 +1274,10 @@ class JaxBackend:
                 f"chunk length must be a multiple of {wbytes} for w={w}")
         padded, batch, L = self._padded(data, LENGTH_QUANTUM * wbytes)
         self._note_kernel("bitplane_xla")
-        out = _apply_byte_domain(self._device_matrix(B),
-                                 jnp.asarray(padded), w)
-        out = np.asarray(out)[:batch, :, :L]
+        Bdev = self._device_matrix(B)
+        out = _run_sync("bitplane_xla",
+                        lambda dev: _apply_byte_domain(Bdev, dev, w),
+                        padded, batch, L)
         out = out.reshape(lead + out.shape[-2:])
         return out[0] if squeeze else out
 
@@ -1263,13 +1300,15 @@ class JaxBackend:
             data, LENGTH_QUANTUM * wbytes)
         self._note_kernel("bitplane_xla")
         try:
-            if mesh is not None:
-                out = self._mesh_apply_fn(mesh, w)(
-                    self._device_matrix_mesh(B, mesh), dev)
-            else:
-                out = _apply_byte_domain(self._device_matrix(B), dev, w)
-            ledger["compute_start"] = time.time()
-            out.copy_to_host_async()
+            with section("dispatch.call", kernel="bitplane_xla"):
+                if mesh is not None:
+                    out = self._mesh_apply_fn(mesh, w)(
+                        self._device_matrix_mesh(B, mesh), dev)
+                else:
+                    out = _apply_byte_domain(self._device_matrix(B), dev,
+                                             w)
+                ledger["compute_start"] = time.time()
+                out.copy_to_host_async()
         except BaseException:
             # kernel dispatch failed: no fence will ever retire, so
             # hand the slot back unfenced instead of leaking it
@@ -1314,8 +1353,10 @@ class JaxBackend:
         data = data.reshape((-1,) + data.shape[-2:])
         padded, batch, L = self._padded(data, w * packetsize)
         self._note_kernel("packet_bitplane_xla")
-        out = _apply_packet_domain(self._device_matrix(B),
-                                   jnp.asarray(padded), w, packetsize)
-        out = np.asarray(out)[:batch, :, :L]
+        Bdev = self._device_matrix(B)
+        out = _run_sync(
+            "packet_bitplane_xla",
+            lambda dev: _apply_packet_domain(Bdev, dev, w, packetsize),
+            padded, batch, L)
         out = out.reshape(lead + out.shape[-2:])
         return out[0] if squeeze else out

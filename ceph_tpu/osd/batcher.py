@@ -48,12 +48,14 @@ from ..utils import copytrack
 from ..utils import faults as faultlib
 from ..utils.device_ledger import DeviceLedgerAccum, overlap_stats
 from ..utils.log import derr_once
+from ..utils.tracer import section
 
 
 class _Req:
     """One queued encode.  ``data`` may be bytes, bytearray,
     memoryview or a uint8 ndarray — the caller hands over ownership
     and must not mutate the buffer until ``cb`` fires."""
+    lane = "enc"
 
     def __init__(self, ec_impl, sinfo: ecutil.StripeInfo, data,
                  cb: Callable[[Dict[int, bytes]], None], tracked=None):
@@ -76,6 +78,7 @@ class _Req:
 class _DecReq:
     """One queued reconstruction: rebuild ``want - have`` shard chunks
     from the equal-length chunk buffers in ``have``."""
+    lane = "dec"
 
     def __init__(self, ec_impl, sinfo: ecutil.StripeInfo,
                  have: Dict[int, bytes], want,
@@ -100,6 +103,7 @@ class _DeltaReq:
     dirty columns ride the device — the rider's ``cb`` receives
     {parity_shard_index: Δparity chunk bytes} to XOR into the
     stored parity chunks (store-level ``xor_write``)."""
+    lane = "delta"
 
     def __init__(self, ec_impl, sinfo: ecutil.StripeInfo, delta,
                  dirty_cols,
@@ -233,7 +237,10 @@ class EncodeBatcher:
     PREWARM_ERRORS_CAP = 64
 
     def __init__(self, conf=None, perf=None, perf_coll=None,
-                 recorder=None, contention=None):
+                 recorder=None, contention=None, daemon: str = ""):
+        # whose batcher this is: the d= keyword of its threads' sections
+        self.daemon = daemon
+
         def get(k, d):
             if conf is None:
                 return d
@@ -620,27 +627,29 @@ class EncodeBatcher:
         that receives batcher stage events.  Codecs without the
         batched async API don't benefit from coalescing — they encode
         inline."""
-        if self._stop or not hasattr(ec_impl, "encode_batch_async"):
-            cb(ecutil.encode(sinfo, ec_impl, data))
-            return
-        req = _Req(ec_impl, sinfo, data, cb, tracked)
-        if req.nstripes == 0:
-            cb({i: b"" for i in range(ec_impl.get_chunk_count())})
-            return
-        with self._cond:
-            if self._stop:
-                stopped = True       # raced shutdown: encode inline
-            else:
-                stopped = False
-                if not self._queues:
-                    self._first_enqueue = time.monotonic()
-                self._queues.setdefault(
-                    ("enc",) + _geometry_key(ec_impl, sinfo),
-                    []).append(req)
-                self._pending_stripes += req.nstripes
-                self._cond.notify()
-        if stopped:
-            cb(ecutil.encode(sinfo, ec_impl, data))
+        with section("batcher.submit", lane="enc",
+                     bytes=ecutil.nbytes_of(data)):
+            if self._stop or not hasattr(ec_impl, "encode_batch_async"):
+                cb(ecutil.encode(sinfo, ec_impl, data))
+                return
+            req = _Req(ec_impl, sinfo, data, cb, tracked)
+            if req.nstripes == 0:
+                cb({i: b"" for i in range(ec_impl.get_chunk_count())})
+                return
+            with self._cond:
+                if self._stop:
+                    stopped = True       # raced shutdown: encode inline
+                else:
+                    stopped = False
+                    if not self._queues:
+                        self._first_enqueue = time.monotonic()
+                    self._queues.setdefault(
+                        ("enc",) + _geometry_key(ec_impl, sinfo),
+                        []).append(req)
+                    self._pending_stripes += req.nstripes
+                    self._cond.notify()
+            if stopped:
+                cb(ecutil.encode(sinfo, ec_impl, data))
 
     def submit_decode(self, ec_impl, sinfo: ecutil.StripeInfo,
                       have: Dict[int, bytes], want,
@@ -657,38 +666,39 @@ class EncodeBatcher:
         object's recovery window separately on the submitting thread
         (reference src/osd/ECBackend.cc:414-481
         handle_recovery_read_complete)."""
-        missing = set(want) - set(have)
-        if not missing:
-            # everything wanted was read directly (e.g. a stray held
-            # the 'missing' shard): passthrough, like ecutil.decode
-            cb({s: (have[s] if isinstance(have[s], bytes)
-                    else memoryview(have[s]).cast("B"))
-                for s in want})
-            return
-        stopped = self._stop or not hasattr(ec_impl, "decode_batch")
-        req = None
-        if not stopped:
-            req = _DecReq(ec_impl, sinfo, have, want, cb)
-            if req.nstripes == 0:
-                cb({s: b"" for s in want})
+        with section("batcher.submit", lane="dec"):
+            missing = set(want) - set(have)
+            if not missing:
+                # everything wanted was read directly (e.g. a stray held
+                # the 'missing' shard): passthrough, like ecutil.decode
+                cb({s: (have[s] if isinstance(have[s], bytes)
+                        else memoryview(have[s]).cast("B"))
+                    for s in want})
                 return
-            key = ("dec", _geometry_key(ec_impl, sinfo),
-                   tuple(sorted(have)), tuple(sorted(missing)))
-            with self._cond:
-                if self._stop:
-                    stopped = True   # raced shutdown: decode inline
-                else:
-                    if not self._queues:
-                        self._first_enqueue = time.monotonic()
-                    self._queues.setdefault(key, []).append(req)
-                    self._pending_stripes += req.nstripes
-                    self._cond.notify()
-        if stopped:
-            try:
-                dec = ecutil.decode(sinfo, ec_impl, have, set(want))
-            except Exception:
-                dec = None
-            cb(dec)
+            stopped = self._stop or not hasattr(ec_impl, "decode_batch")
+            req = None
+            if not stopped:
+                req = _DecReq(ec_impl, sinfo, have, want, cb)
+                if req.nstripes == 0:
+                    cb({s: b"" for s in want})
+                    return
+                key = ("dec", _geometry_key(ec_impl, sinfo),
+                       tuple(sorted(have)), tuple(sorted(missing)))
+                with self._cond:
+                    if self._stop:
+                        stopped = True   # raced shutdown: decode inline
+                    else:
+                        if not self._queues:
+                            self._first_enqueue = time.monotonic()
+                        self._queues.setdefault(key, []).append(req)
+                        self._pending_stripes += req.nstripes
+                        self._cond.notify()
+            if stopped:
+                try:
+                    dec = ecutil.decode(sinfo, ec_impl, have, set(want))
+                except Exception:
+                    dec = None
+                cb(dec)
 
     def submit_delta(self, ec_impl, sinfo: ecutil.StripeInfo, delta,
                      dirty_cols,
@@ -706,33 +716,35 @@ class EncodeBatcher:
         signatures (a 4 KiB write always dirties one column), so hot
         small-write traffic lands on a handful of prewarmed compiled
         shapes — the same coalescing economics as recovery."""
-        cols = tuple(sorted(dirty_cols))
-        stopped = self._stop or \
-            not hasattr(ec_impl, "delta_encode_batch_async")
-        req = None
-        if not stopped:
-            req = _DeltaReq(ec_impl, sinfo, delta, cols, cb, tracked)
-            if req.nstripes == 0:
-                k = ec_impl.get_data_chunk_count()
-                m = ec_impl.get_coding_chunk_count()
-                cb({k + j: b"" for j in range(m)})
-                return
-            key = ("delta", _geometry_key(ec_impl, sinfo), cols)
-            with self._cond:
-                if self._stop:
-                    stopped = True   # raced shutdown: compute inline
-                else:
-                    if not self._queues:
-                        self._first_enqueue = time.monotonic()
-                    self._queues.setdefault(key, []).append(req)
-                    self._pending_stripes += req.nstripes
-                    self._cond.notify()
-        if stopped:
-            try:
-                out = self._delta_inline(ec_impl, sinfo, delta, cols)
-            except Exception:
-                out = None
-            cb(out)
+        with section("batcher.submit", lane="delta",
+                     bytes=ecutil.nbytes_of(delta)):
+            cols = tuple(sorted(dirty_cols))
+            stopped = self._stop or \
+                not hasattr(ec_impl, "delta_encode_batch_async")
+            req = None
+            if not stopped:
+                req = _DeltaReq(ec_impl, sinfo, delta, cols, cb, tracked)
+                if req.nstripes == 0:
+                    k = ec_impl.get_data_chunk_count()
+                    m = ec_impl.get_coding_chunk_count()
+                    cb({k + j: b"" for j in range(m)})
+                    return
+                key = ("delta", _geometry_key(ec_impl, sinfo), cols)
+                with self._cond:
+                    if self._stop:
+                        stopped = True   # raced shutdown: compute inline
+                    else:
+                        if not self._queues:
+                            self._first_enqueue = time.monotonic()
+                        self._queues.setdefault(key, []).append(req)
+                        self._pending_stripes += req.nstripes
+                        self._cond.notify()
+            if stopped:
+                try:
+                    out = self._delta_inline(ec_impl, sinfo, delta, cols)
+                except Exception:
+                    out = None
+                cb(out)
 
     def _delta_inline(self, ec_impl, sinfo: ecutil.StripeInfo,
                       delta, cols) -> Dict[int, memoryview]:
@@ -1029,36 +1041,38 @@ class EncodeBatcher:
             # fanout); the bounded queue's blocking put is the
             # throttle.
             groups = []
-            for key, reqs in queues.items():
-                if len(reqs) > self.group_reqs_hwm:
-                    self.group_reqs_hwm = len(reqs)
-                gstripes = sum(r.nstripes for r in reqs)
-                if gstripes > self.group_stripes_hwm:
-                    self.group_stripes_hwm = gstripes
-                if key[0] == "dec":
-                    # decode groups route + dispatch HERE like encode
-                    # groups (ISSUE 11): the async handle rides the
-                    # same bounded completion queue, so decode honors
-                    # ec_tpu_inflight_groups and pipelines its h2d
-                    # under the previous group's compute
-                    groups.append((key, reqs,
-                                   self._route_dec_group(key, reqs)))
-                    continue
-                if key[0] == "delta":
-                    # parity-delta groups route + dispatch like
-                    # decode groups: async handle on the bounded
-                    # completion queue, h2d pipelined under the
-                    # previous group's compute
-                    groups.append((key, reqs,
-                                   self._route_delta_group(key,
-                                                           reqs)))
-                    continue
-                to_cpu = self._route_to_cpu(key, reqs)
-                if not to_cpu and self._breaker_blocks():
-                    to_cpu = True
-                self._note_route(key, reqs, to_cpu)
-                groups.append((key, reqs, "cpu" if to_cpu
-                               else self._dispatch_group(reqs)))
+            with section("batcher.form", d=self.daemon,
+                         groups=len(queues), reqs=depth):
+                for key, reqs in queues.items():
+                    if len(reqs) > self.group_reqs_hwm:
+                        self.group_reqs_hwm = len(reqs)
+                    gstripes = sum(r.nstripes for r in reqs)
+                    if gstripes > self.group_stripes_hwm:
+                        self.group_stripes_hwm = gstripes
+                    if key[0] == "dec":
+                        # decode groups route + dispatch HERE like encode
+                        # groups (ISSUE 11): the async handle rides the
+                        # same bounded completion queue, so decode honors
+                        # ec_tpu_inflight_groups and pipelines its h2d
+                        # under the previous group's compute
+                        groups.append((key, reqs,
+                                       self._route_dec_group(key, reqs)))
+                        continue
+                    if key[0] == "delta":
+                        # parity-delta groups route + dispatch like
+                        # decode groups: async handle on the bounded
+                        # completion queue, h2d pipelined under the
+                        # previous group's compute
+                        groups.append((key, reqs,
+                                       self._route_delta_group(key,
+                                                               reqs)))
+                        continue
+                    to_cpu = self._route_to_cpu(key, reqs)
+                    if not to_cpu and self._breaker_blocks():
+                        to_cpu = True
+                    self._note_route(key, reqs, to_cpu)
+                    groups.append((key, reqs, "cpu" if to_cpu
+                                   else self._dispatch_group(reqs)))
             for key, reqs, handle in groups:
                 self._completions.put((key, reqs, handle,
                                        len(groups)))
@@ -1085,39 +1099,41 @@ class EncodeBatcher:
             if item is None:
                 return
             key, reqs, handle, ngroups = item
-            try:
-                if handle == "dec":
-                    self._complete_group_dec(key, reqs)
-                elif handle == "dec_cpu":
-                    self._complete_group_dec_twin(key, reqs)
-                elif isinstance(handle, tuple) and handle \
-                        and handle[0] == "decdev":
-                    self._complete_group_dec_dev(
-                        key, reqs, handle,
-                        trust_win=(ngroups == 1))
-                elif handle == "delta_cpu":
-                    self._complete_group_delta_twin(key, reqs)
-                elif isinstance(handle, tuple) and handle \
-                        and handle[0] == "deltadev":
-                    self._complete_group_delta_dev(
-                        key, reqs, handle,
-                        trust_win=(ngroups == 1))
-                elif handle == "cpu":
-                    self._complete_group_cpu(reqs)
-                else:
-                    # loss-direction learning runs on EVERY group
-                    # (raising the threshold is safe even when
-                    # sibling completions inflate dev_time — worst
-                    # case we conservatively route small batches to
-                    # the CPU twin); the win direction (lowering it)
-                    # only trusts single-group cycles
-                    self._complete_group(reqs, handle, learn=True,
-                                         trust_win=(ngroups == 1))
-            except Exception:
-                # fail every rider op that has not completed yet: a
-                # worker-level fault must surface as EIO on the
-                # affected ops, never as a hang
-                self._cb_error(reqs)
+            with section("batcher.complete", d=self.daemon,
+                         lane=reqs[0].lane, reqs=len(reqs)):
+                try:
+                    if handle == "dec":
+                        self._complete_group_dec(key, reqs)
+                    elif handle == "dec_cpu":
+                        self._complete_group_dec_twin(key, reqs)
+                    elif isinstance(handle, tuple) and handle \
+                            and handle[0] == "decdev":
+                        self._complete_group_dec_dev(
+                            key, reqs, handle,
+                            trust_win=(ngroups == 1))
+                    elif handle == "delta_cpu":
+                        self._complete_group_delta_twin(key, reqs)
+                    elif isinstance(handle, tuple) and handle \
+                            and handle[0] == "deltadev":
+                        self._complete_group_delta_dev(
+                            key, reqs, handle,
+                            trust_win=(ngroups == 1))
+                    elif handle == "cpu":
+                        self._complete_group_cpu(reqs)
+                    else:
+                        # loss-direction learning runs on EVERY group
+                        # (raising the threshold is safe even when
+                        # sibling completions inflate dev_time — worst
+                        # case we conservatively route small batches to
+                        # the CPU twin); the win direction (lowering it)
+                        # only trusts single-group cycles
+                        self._complete_group(reqs, handle, learn=True,
+                                             trust_win=(ngroups == 1))
+                except Exception:
+                    # fail every rider op that has not completed yet: a
+                    # worker-level fault must surface as EIO on the
+                    # affected ops, never as a hang
+                    self._cb_error(reqs)
 
     def _route_to_cpu(self, key: Tuple, reqs: List[_Req]) -> bool:
         """True when the learned crossover says this batch is too
@@ -1297,6 +1313,18 @@ class EncodeBatcher:
                 self.recorder.note("breaker", state="closed",
                                    crossover=int(
                                        cls._min_device_bytes))
+
+    def _deliver(self, r, out) -> None:
+        """Run one rider's continuation; a failing continuation
+        affects only its own op."""
+        with section("batcher.deliver", lane=r.lane,
+                     stripes=r.nstripes) as sec:
+            try:
+                r.done = True
+                r.cb(out)
+            except Exception as e:
+                sec.set_metadata(error=type(e).__name__)
+                self._cb_error()
 
     def _cb_error(self, reqs=None) -> None:
         """Report a continuation/encode failure.  During shutdown the
@@ -1506,11 +1534,7 @@ class EncodeBatcher:
         for r, chunks in zip(reqs, chunks_list):
             self.reqs_total += 1
             self.cpu_reqs += 1
-            try:
-                r.done = True
-                r.cb(chunks)
-            except Exception:
-                self._cb_error()
+            self._deliver(r, chunks)
 
     def _complete_group_dec(self, key: Tuple,
                             reqs: List[_DecReq]) -> None:
@@ -1615,11 +1639,7 @@ class EncodeBatcher:
                     self._cb_error()
                     dec = None
                 self.dec_reqs += 1
-                try:
-                    r.done = True
-                    r.cb(dec)
-                except Exception:
-                    self._cb_error()
+                self._deliver(r, dec)
             return
         self.dec_calls += 1
         self.dec_reqs += len(reqs)
@@ -1649,11 +1669,7 @@ class EncodeBatcher:
                     out[s] = h if isinstance(h, bytes) else \
                         memoryview(h).cast("B")
             off += r.nstripes
-            try:
-                r.done = True
-                r.cb(out)
-            except Exception:
-                self._cb_error()
+            self._deliver(r, out)
 
     # -- decode device pipeline (ISSUE 11 tentpole) --------------------
     def _dec_min_bytes(self) -> float:
@@ -1763,59 +1779,63 @@ class EncodeBatcher:
         ledger).  Returns (handles, t_disp, in_bytes) or None on
         dispatch failure."""
         t_form = time.monotonic()
-        self._account_queue_wait(reqs, t_form)
-        sinfo = reqs[0].sinfo
-        cs = sinfo.chunk_size
-        have_ids = key[2]
-        try:
-            present = {
-                s: (np.concatenate(
-                    [ecutil.as_stripe_array(r.have[s], r.nstripes,
-                                            1, cs)
-                     .reshape(r.nstripes, cs) for r in reqs], axis=0)
-                    if len(reqs) > 1 else
-                    ecutil.as_stripe_array(
-                        reqs[0].have[s], reqs[0].nstripes, 1, cs)
-                    .reshape(-1, cs))
-                for s in have_ids}
-            if len(reqs) > 1:
-                self._note_copy(sum(v.nbytes
-                                    for v in present.values()),
-                                "batcher.dec_batch_concat")
-        except Exception:
-            # malformed request payload: NOT a device fault (must not
-            # trip the breaker) — the twin path fails the bad rider
-            # per-request and still serves its group-mates
-            return None
-        nstripes = sum(r.nstripes for r in reqs)
-        in_bytes = sum(v.nbytes for v in present.values())
-        tile = max(1, self.max_stripes)
-        handles = err = None
-        delay = self.device_retry_s
-        for attempt in range(3):
+        waited = self._account_queue_wait(reqs, t_form)
+        with section("batcher.dispatch", lane=reqs[0].lane,
+                     reqs=len(reqs),
+                     stripes=sum(r.nstripes for r in reqs),
+                     queue_wait_us=waited * 1e6):
+            sinfo = reqs[0].sinfo
+            cs = sinfo.chunk_size
+            have_ids = key[2]
             try:
-                faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
-                handles = [
-                    reqs[0].ec_impl.decode_batch_async(
-                        {s: v[i:i + tile]
-                         for s, v in present.items()}, cs)
-                    for i in range(0, nstripes, tile)]
-                break
-            except Exception as e:
-                handles, err = None, e
-                if attempt < 2 and delay > 0:
-                    time.sleep(min(delay, 0.1))
-                    delay *= 2
-        if handles is None:
-            self._device_failure("dispatch", err)
-            return None
-        t_disp = time.monotonic()
-        EncodeBatcher._last_device_ts = t_disp
-        self.stage_seconds["batch_form"] += t_disp - t_form
-        if self.bperf is not None:
-            self.bperf.hinc("batch_stripes", nstripes)
-            self.bperf.inc("h2d_bytes", in_bytes)
-        return (handles, t_disp, in_bytes)
+                present = {
+                    s: (np.concatenate(
+                        [ecutil.as_stripe_array(r.have[s], r.nstripes,
+                                                1, cs)
+                         .reshape(r.nstripes, cs) for r in reqs], axis=0)
+                        if len(reqs) > 1 else
+                        ecutil.as_stripe_array(
+                            reqs[0].have[s], reqs[0].nstripes, 1, cs)
+                        .reshape(-1, cs))
+                    for s in have_ids}
+                if len(reqs) > 1:
+                    self._note_copy(sum(v.nbytes
+                                        for v in present.values()),
+                                    "batcher.dec_batch_concat")
+            except Exception:
+                # malformed request payload: NOT a device fault (must not
+                # trip the breaker) — the twin path fails the bad rider
+                # per-request and still serves its group-mates
+                return None
+            nstripes = sum(r.nstripes for r in reqs)
+            in_bytes = sum(v.nbytes for v in present.values())
+            tile = max(1, self.max_stripes)
+            handles = err = None
+            delay = self.device_retry_s
+            for attempt in range(3):
+                try:
+                    faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
+                    handles = [
+                        reqs[0].ec_impl.decode_batch_async(
+                            {s: v[i:i + tile]
+                             for s, v in present.items()}, cs)
+                        for i in range(0, nstripes, tile)]
+                    break
+                except Exception as e:
+                    handles, err = None, e
+                    if attempt < 2 and delay > 0:
+                        time.sleep(min(delay, 0.1))
+                        delay *= 2
+            if handles is None:
+                self._device_failure("dispatch", err)
+                return None
+            t_disp = time.monotonic()
+            EncodeBatcher._last_device_ts = t_disp
+            self.stage_seconds["batch_form"] += t_disp - t_form
+            if self.bperf is not None:
+                self.bperf.hinc("batch_stripes", nstripes)
+                self.bperf.inc("h2d_bytes", in_bytes)
+            return (handles, t_disp, in_bytes)
 
     def _complete_group_dec_twin(self, key: Tuple,
                                  reqs: List[_DecReq]) -> None:
@@ -1917,11 +1937,7 @@ class EncodeBatcher:
                     out[s] = hv if isinstance(hv, bytes) else \
                         memoryview(hv).cast("B")
             off += r.nstripes
-            try:
-                r.done = True
-                r.cb(out)
-            except Exception:
-                self._cb_error()
+            self._deliver(r, out)
 
     def _cpu_rate_dec(self, key: Tuple,
                       reqs: List[_DecReq]) -> float:
@@ -2093,51 +2109,55 @@ class EncodeBatcher:
         StagingPool staging, full seven-phase ledger).  Returns
         (handles, t_disp, in_bytes) or None on dispatch failure."""
         t_form = time.monotonic()
-        self._account_queue_wait(reqs, t_form)
-        cols = key[2]
-        try:
-            arrs = [r.as_array(len(cols)) for r in reqs]
-            if len(arrs) > 1:
-                batch = np.concatenate(arrs, axis=0)
-                self._note_copy(batch.nbytes,
-                                "batcher.delta_batch_concat")
-            else:
-                batch = np.asarray(arrs[0])
-        except Exception:
-            # malformed request payload: NOT a device fault (must not
-            # trip the breaker) — the twin path fails the bad rider
-            # per-request and still serves its group-mates
-            return None
-        in_bytes = batch.nbytes
-        tile = max(1, self.max_stripes)
-        handles = err = None
-        delay = self.device_retry_s
-        for attempt in range(3):
+        waited = self._account_queue_wait(reqs, t_form)
+        with section("batcher.dispatch", lane=reqs[0].lane,
+                     reqs=len(reqs),
+                     stripes=sum(r.nstripes for r in reqs),
+                     queue_wait_us=waited * 1e6):
+            cols = key[2]
             try:
-                faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
-                handles = [
-                    reqs[0].ec_impl.delta_encode_batch_async(
-                        batch[i:i + tile], cols)
-                    for i in range(0, batch.shape[0], tile)]
-                break
-            except Exception as e:
-                handles, err = None, e
-                if attempt < 2 and delay > 0:
-                    time.sleep(min(delay, 0.1))
-                    delay *= 2
-        if handles is None:
-            self._device_failure("dispatch", err)
-            return None
-        t_disp = time.monotonic()
-        EncodeBatcher._last_device_ts = t_disp
-        self.stage_seconds["batch_form"] += t_disp - t_form
-        if self.bperf is not None:
-            self.bperf.hinc("batch_stripes", batch.shape[0])
-            self.bperf.inc("h2d_bytes", in_bytes)
-        for r in reqs:
-            if r.tracked is not None:
-                r.tracked.mark_event("ec:delta_dispatched")
-        return (handles, t_disp, in_bytes)
+                arrs = [r.as_array(len(cols)) for r in reqs]
+                if len(arrs) > 1:
+                    batch = np.concatenate(arrs, axis=0)
+                    self._note_copy(batch.nbytes,
+                                    "batcher.delta_batch_concat")
+                else:
+                    batch = np.asarray(arrs[0])
+            except Exception:
+                # malformed request payload: NOT a device fault (must not
+                # trip the breaker) — the twin path fails the bad rider
+                # per-request and still serves its group-mates
+                return None
+            in_bytes = batch.nbytes
+            tile = max(1, self.max_stripes)
+            handles = err = None
+            delay = self.device_retry_s
+            for attempt in range(3):
+                try:
+                    faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
+                    handles = [
+                        reqs[0].ec_impl.delta_encode_batch_async(
+                            batch[i:i + tile], cols)
+                        for i in range(0, batch.shape[0], tile)]
+                    break
+                except Exception as e:
+                    handles, err = None, e
+                    if attempt < 2 and delay > 0:
+                        time.sleep(min(delay, 0.1))
+                        delay *= 2
+            if handles is None:
+                self._device_failure("dispatch", err)
+                return None
+            t_disp = time.monotonic()
+            EncodeBatcher._last_device_ts = t_disp
+            self.stage_seconds["batch_form"] += t_disp - t_form
+            if self.bperf is not None:
+                self.bperf.hinc("batch_stripes", batch.shape[0])
+                self.bperf.inc("h2d_bytes", in_bytes)
+            for r in reqs:
+                if r.tracked is not None:
+                    r.tracked.mark_event("ec:delta_dispatched")
+            return (handles, t_disp, in_bytes)
 
     def _complete_group_delta_twin(self, key: Tuple,
                                    reqs: List["_DeltaReq"]) -> None:
@@ -2176,11 +2196,7 @@ class EncodeBatcher:
                     out = None
                 self.delta_reqs += 1
                 self.delta_cpu_reqs += 1
-                try:
-                    r.done = True
-                    r.cb(out)
-                except Exception:
-                    self._cb_error()
+                self._deliver(r, out)
             return
         self.delta_calls += 1
         self.cpu_calls += 1
@@ -2298,11 +2314,7 @@ class EncodeBatcher:
                     copied += col.nbytes
                 out[k + j] = memoryview(col).cast("B")
             self.delta_reqs += 1
-            try:
-                r.done = True
-                r.cb(out)
-            except Exception:
-                self._cb_error()
+            self._deliver(r, out)
         if copied:
             self._note_copy(copied, "batcher.delta_shard_gather")
 
@@ -2511,59 +2523,63 @@ class EncodeBatcher:
         one dispatch is still ONE sharded GF matmul, and the ledger
         fans out per chip (AsyncBatch.ledgers)."""
         t_form = time.monotonic()
-        self._account_queue_wait(reqs, t_form)
-        try:
-            k = reqs[0].ec_impl.get_data_chunk_count()
-            arrs = [r.as_array(k) for r in reqs]
-            if len(arrs) > 1:
-                batch = np.concatenate(arrs, axis=0)
-                self._note_copy(batch.nbytes, "batcher.batch_concat")
-            else:
-                batch = arrs[0]
-        except Exception:
-            # malformed request payload/geometry: NOT a device fault
-            # (must not trip the breaker) — completion falls back to
-            # per-request CPU encode, which fails the bad rider with
-            # EIO and still serves its group-mates
-            return None
-        # tile oversized batches at max_stripes: bounds per-call
-        # device memory AND caps the largest compiled batch shape
-        # at bucket(max_stripes) — the shape prewarm() compiles —
-        # so a burst can never hit a never-seen (slow-compiling)
-        # shape mid-benchmark.  All tiles dispatch before any
-        # wait: h2d/MXU/d2h still overlap tile-to-tile.
-        tile = max(1, self.max_stripes)
-        handles = err = None
-        delay = self.device_retry_s
-        for attempt in range(3):
+        waited = self._account_queue_wait(reqs, t_form)
+        with section("batcher.dispatch", lane=reqs[0].lane,
+                     reqs=len(reqs),
+                     stripes=sum(r.nstripes for r in reqs),
+                     queue_wait_us=waited * 1e6):
             try:
-                faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
-                handles = [
-                    reqs[0].ec_impl.encode_batch_async(
-                        batch[i:i + tile])
-                    for i in range(0, batch.shape[0], tile)]
-                break
-            except Exception as e:
-                # classified device dispatch failure: transient until
-                # proven otherwise — retry with capped backoff before
-                # charging the breaker
-                handles, err = None, e
-                if attempt < 2 and delay > 0:
-                    time.sleep(min(delay, 0.1))
-                    delay *= 2
-        if handles is None:
-            self._device_failure("dispatch", err)
-            return None
-        t_disp = time.monotonic()
-        EncodeBatcher._last_device_ts = t_disp
-        self.stage_seconds["batch_form"] += t_disp - t_form
-        if self.bperf is not None:
-            self.bperf.hinc("batch_stripes", batch.shape[0])
-            self.bperf.inc("h2d_bytes", batch.nbytes)
-        for r in reqs:
-            if r.tracked is not None:
-                r.tracked.mark_event("ec:batch_dispatched")
-        return (arrs, handles, t_disp)
+                k = reqs[0].ec_impl.get_data_chunk_count()
+                arrs = [r.as_array(k) for r in reqs]
+                if len(arrs) > 1:
+                    batch = np.concatenate(arrs, axis=0)
+                    self._note_copy(batch.nbytes, "batcher.batch_concat")
+                else:
+                    batch = arrs[0]
+            except Exception:
+                # malformed request payload/geometry: NOT a device fault
+                # (must not trip the breaker) — completion falls back to
+                # per-request CPU encode, which fails the bad rider with
+                # EIO and still serves its group-mates
+                return None
+            # tile oversized batches at max_stripes: bounds per-call
+            # device memory AND caps the largest compiled batch shape
+            # at bucket(max_stripes) — the shape prewarm() compiles —
+            # so a burst can never hit a never-seen (slow-compiling)
+            # shape mid-benchmark.  All tiles dispatch before any
+            # wait: h2d/MXU/d2h still overlap tile-to-tile.
+            tile = max(1, self.max_stripes)
+            handles = err = None
+            delay = self.device_retry_s
+            for attempt in range(3):
+                try:
+                    faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
+                    handles = [
+                        reqs[0].ec_impl.encode_batch_async(
+                            batch[i:i + tile])
+                        for i in range(0, batch.shape[0], tile)]
+                    break
+                except Exception as e:
+                    # classified device dispatch failure: transient until
+                    # proven otherwise — retry with capped backoff before
+                    # charging the breaker
+                    handles, err = None, e
+                    if attempt < 2 and delay > 0:
+                        time.sleep(min(delay, 0.1))
+                        delay *= 2
+            if handles is None:
+                self._device_failure("dispatch", err)
+                return None
+            t_disp = time.monotonic()
+            EncodeBatcher._last_device_ts = t_disp
+            self.stage_seconds["batch_form"] += t_disp - t_form
+            if self.bperf is not None:
+                self.bperf.hinc("batch_stripes", batch.shape[0])
+                self.bperf.inc("h2d_bytes", batch.nbytes)
+            for r in reqs:
+                if r.tracked is not None:
+                    r.tracked.mark_event("ec:batch_dispatched")
+            return (arrs, handles, t_disp)
 
     def _publish_device_telemetry(self, ec_impl) -> None:
         """Refresh the ec_device staging/link gauges from the codec's
@@ -2734,12 +2750,16 @@ class EncodeBatcher:
         return {"ledgers": self.ledger_accum.recent(), "memory": mem}
 
     def _account_queue_wait(self, reqs: List[_Req],
-                            now: float) -> None:
+                            now: float) -> float:
+        """-> seconds the group's requests waited, summed."""
+        total = 0.0
         for r in reqs:
             w = max(0.0, now - r.t_enq)
+            total += w
             self.stage_seconds["queue_wait"] += w
             if self.bperf is not None:
                 self.bperf.hinc("queue_wait_us", w * 1e6)
+        return total
 
     def _complete_group(self, reqs: List[_Req], handle,
                         learn: bool = True,
@@ -2788,11 +2808,7 @@ class EncodeBatcher:
                 except Exception:
                     self._cb_error()
                     chunks = None
-                try:
-                    r.done = True
-                    r.cb(chunks)
-                except Exception:
-                    self._cb_error()
+                self._deliver(r, chunks)
             return
         if dev_time is not None and self.adaptive_cpu and learn:
             self._learn_crossover(reqs, dev_time,
@@ -2843,9 +2859,4 @@ class EncodeBatcher:
             p = parity[off:off + r.nstripes]
             off += r.nstripes
             out = self._shard_views(arr, p, k, m)
-            try:
-                r.done = True
-                r.cb(out)
-            except Exception:
-                # a failing continuation affects only its own op
-                self._cb_error()
+            self._deliver(r, out)

@@ -55,6 +55,7 @@ from ..store.objectstore import GHObject, Transaction
 from ..utils import copytrack
 from ..utils import faults as faultlib
 from ..utils.log import derr_once
+from ..utils.tracer import section
 from . import ecutil
 from .backend import OI_ATTR, Mutation, ObjectInfo, PGBackend, PGHost
 from .pglog import Eversion, LogEntry
@@ -86,6 +87,10 @@ class _WriteOp:
         self.at_version = at_version
         self.log_entries = log_entries
         self.on_all_commit = on_all_commit
+        # the client's reqid, for the op= keyword of this op's sections
+        cmsg = mutation.client_msg
+        self.reqid = f"{cmsg.client}:{cmsg.tid}" if cmsg is not None \
+            else ""
         self.to_read: Optional[Tuple[int, int]] = None   # aligned extent
         self.read_data: bytes = b""
         self.obj_info = None             # fetched once in _start_rmw
@@ -483,6 +488,10 @@ class ECBackend(PGBackend):
         self._complete_op(op)
 
     def _start_rmw(self, op: _WriteOp) -> None:
+        with section("ec.prepare", op=op.reqid, pg=self.host.pgid_str):
+            self._plan_write(op)
+
+    def _plan_write(self, op: _WriteOp) -> None:
         """Compute the WritePlan (reference get_write_plan,
         ECTransaction.h:40): which existing stripes must be read back
         before this mutation can be encoded.  For pipelined ops the
@@ -533,11 +542,13 @@ class ECBackend(PGBackend):
         op.to_read = (astart, existing_end - astart)
         if mut.tracked_op is not None:
             mut.tracked_op.mark_event("ec:rmw_read")
-        self.objects_read(
-            op.oid, astart, min(existing_end, op.committed_size)
-            - astart,
-            lambda res, data: self._rmw_read_done(op, res, data),
-            trace=(mut.trace_id, mut.parent_span_id))
+        with section("ec.rmw_read", op=op.reqid,
+                     bytes=existing_end - astart):
+            self.objects_read(
+                op.oid, astart, min(existing_end, op.committed_size)
+                - astart,
+                lambda res, data: self._rmw_read_done(op, res, data),
+                trace=(mut.trace_id, mut.parent_span_id))
 
     @staticmethod
     def _fully_covers(writes: List[Tuple[int, bytes]], lo: int,
@@ -602,12 +613,14 @@ class ECBackend(PGBackend):
             self.delta_dirty_census.get(len(cols), 0) + 1
         if mut.tracked_op is not None:
             mut.tracked_op.mark_event("ec:rmw_delta_read")
-        self._start_read(
-            op.oid, chunk_off, chunk_len,
-            {c: acting[c] for c in cols},
-            lambda received, errors:
-                self._delta_read_done(op, received, errors),
-            trace=(mut.trace_id, mut.parent_span_id))
+        with section("ec.rmw_read", op=op.reqid,
+                     bytes=chunk_len * len(cols)):
+            self._start_read(
+                op.oid, chunk_off, chunk_len,
+                {c: acting[c] for c in cols},
+                lambda received, errors:
+                    self._delta_read_done(op, received, errors),
+                trace=(mut.trace_id, mut.parent_span_id))
         return True
 
     def _dirty_columns(self, writes: List[Tuple[int, bytes]],
@@ -641,6 +654,11 @@ class ECBackend(PGBackend):
         GF delta-matmul per coalesced batch on the device)."""
         if not op.alive:
             return
+        with section("ec.prepare", op=op.reqid, pg=self.host.pgid_str):
+            self._delta_stage(op, received, errors)
+
+    def _delta_stage(self, op: _WriteOp, received: Dict[int, bytes],
+                     errors: Dict[int, int]) -> None:
         astart, alen, hi, cols, chunk_off, chunk_len = op.delta_pending
         batcher = getattr(self.host, "encode_batcher", None)
         if batcher is None or errors or \
@@ -704,11 +722,13 @@ class ECBackend(PGBackend):
         op.to_read = (astart, existing_end - astart)
         if mut.tracked_op is not None:
             mut.tracked_op.mark_event("ec:rmw_read")
-        self.objects_read(
-            op.oid, astart,
-            min(existing_end, op.committed_size) - astart,
-            lambda res, data: self._rmw_read_done(op, res, data),
-            trace=(mut.trace_id, mut.parent_span_id))
+        with section("ec.rmw_read", op=op.reqid,
+                     bytes=existing_end - astart):
+            self.objects_read(
+                op.oid, astart,
+                min(existing_end, op.committed_size) - astart,
+                lambda res, data: self._rmw_read_done(op, res, data),
+                trace=(mut.trace_id, mut.parent_span_id))
 
     def _delta_encode_done(self, op: _WriteOp,
                            dparity: Optional[Dict[int, bytes]]) -> None:
@@ -742,7 +762,8 @@ class ECBackend(PGBackend):
             self._fail_op(op, res)
             return
         op.read_data = data
-        self._reads_to_commit(op)
+        with section("ec.prepare", op=op.reqid, pg=self.host.pgid_str):
+            self._reads_to_commit(op)
 
     def _reads_to_commit(self, op: _WriteOp) -> None:
         """Encode + fan out per-shard sub-writes (reference
@@ -756,7 +777,9 @@ class ECBackend(PGBackend):
         synchronously on this thread instead."""
         mut = op.mutation
         if mut.delete or not mut.writes:
-            self._commit_fanout(op, self._generate_transactions(op))
+            with section("ec.fanout", op=op.reqid,
+                         pg=self.host.pgid_str):
+                self._commit_fanout(op, self._generate_transactions(op))
             return
         lo = min(off for off, _ in mut.writes)
         hi = max(off + len(d) for off, d in mut.writes)
@@ -862,16 +885,18 @@ class ECBackend(PGBackend):
                     break            # mid-op: later ops must wait
                 continue
             op.state = op.SENT
-            if op.delta_txn is not None:
-                txns = self._generate_transactions(
-                    op, delta_plan=op.delta_txn)
-            elif op.encoded is not None:
-                astart, hi, chunks = op.encoded
-                txns = self._generate_transactions(
-                    op, write_plan=(astart, hi, chunks))
-            else:
-                txns = self._generate_transactions(op)
-            self._commit_fanout(op, txns)
+            with section("ec.fanout", op=op.reqid,
+                         pg=self.host.pgid_str):
+                if op.delta_txn is not None:
+                    txns = self._generate_transactions(
+                        op, delta_plan=op.delta_txn)
+                elif op.encoded is not None:
+                    astart, hi, chunks = op.encoded
+                    txns = self._generate_transactions(
+                        op, write_plan=(astart, hi, chunks))
+                else:
+                    txns = self._generate_transactions(op)
+                self._commit_fanout(op, txns)
         while self._pipeline and \
                 self._pipeline[0].state == _WriteOp.DONE:
             self._untrack_pending(self._pipeline.popleft())
@@ -1033,28 +1058,33 @@ class ECBackend(PGBackend):
         while op.segs_sent in op.seg_ready:
             idx = op.segs_sent
             chunks = op.seg_ready.pop(idx)
-            if idx == 0:
-                self._register_commits(op, op.segs_total)
-                if op.mutation.tracked_op is not None:
-                    op.mutation.tracked_op.mark_event(
-                        "ec:sub_write_sent")
-            seg_chunk_off = op.seg_chunk_off0 + \
-                idx * (op.seg_width // self.k)
-            op.seg_hinfo = self._update_hinfo(
-                op.oid, chunks, seg_chunk_off, op.seg_is_append,
-                hinfo=op.seg_hinfo)
-            if idx == op.segs_total - 1:
-                txns = self._generate_transactions(
-                    op, write_plan=(op.seg_astart, op.seg_hi, chunks),
-                    hinfo=op.seg_hinfo, chunk_off=seg_chunk_off)
-                wire_entries = [e.to_dict() for e in op.log_entries]
-            else:
-                txns = self._segment_txns(op, seg_chunk_off, chunks)
-                wire_entries = []
-            self._fanout_txns(op, txns, wire_entries, seg=idx)
+            with section("ec.fanout", op=op.reqid,
+                         pg=self.host.pgid_str, seg=idx):
+                self._send_segment(op, idx, chunks)
             op.segs_sent += 1
         if op.segs_sent >= op.segs_total:
             op.state = op.SENT
+
+    def _send_segment(self, op: _WriteOp, idx: int,
+                      chunks: Dict[int, bytes]) -> None:
+        if idx == 0:
+            self._register_commits(op, op.segs_total)
+            if op.mutation.tracked_op is not None:
+                op.mutation.tracked_op.mark_event("ec:sub_write_sent")
+        seg_chunk_off = op.seg_chunk_off0 + \
+            idx * (op.seg_width // self.k)
+        op.seg_hinfo = self._update_hinfo(
+            op.oid, chunks, seg_chunk_off, op.seg_is_append,
+            hinfo=op.seg_hinfo)
+        if idx == op.segs_total - 1:
+            txns = self._generate_transactions(
+                op, write_plan=(op.seg_astart, op.seg_hi, chunks),
+                hinfo=op.seg_hinfo, chunk_off=seg_chunk_off)
+            wire_entries = [e.to_dict() for e in op.log_entries]
+        else:
+            txns = self._segment_txns(op, seg_chunk_off, chunks)
+            wire_entries = []
+        self._fanout_txns(op, txns, wire_entries, seg=idx)
 
     def _segment_txns(self, op: _WriteOp, chunk_off: int,
                       chunks: Dict[int, bytes]
@@ -1253,7 +1283,10 @@ class ECBackend(PGBackend):
         if hinfo is None or len(hinfo.crcs) != self.k + self.m:
             hinfo = ecutil.HashInfo(self.k + self.m)
         if is_append and hinfo.total_chunk_size == chunk_off:
-            hinfo.append(chunk_off, chunks)
+            with section("crc.host", blocks=len(chunks),
+                         bytes=sum(ecutil.nbytes_of(c)
+                                   for c in chunks.values())):
+                hinfo.append(chunk_off, chunks)
         else:
             hinfo.clear()               # overwrite: CRCs unknowable
         return hinfo
@@ -1263,10 +1296,13 @@ class ECBackend(PGBackend):
                          on_commit: Callable[[], None]) -> None:
         """Shard-side sub-write application (reference handle_sub_write,
         ECBackend.cc:915-989): log entries + data in one transaction."""
-        self.host.prepare_log_txn(txn, wire_entries)
-        txn.register_on_commit(
-            lambda: self.host.on_local_commit(on_commit))
-        self.host.store.queue_transactions([txn], op="client_write")
+        reqid = wire_entries[0].get("reqid") if wire_entries else None
+        with section("ec.sub_write", pg=self.host.pgid_str, shard=shard,
+                     op="%s:%d" % tuple(reqid) if reqid else ""):
+            self.host.prepare_log_txn(txn, wire_entries)
+            txn.register_on_commit(
+                lambda: self.host.on_local_commit(on_commit))
+            self.host.store.queue_transactions([txn], op="client_write")
 
     def _sub_write_committed(self, tid: int, shard: int,
                              seg: int = 0) -> None:
@@ -1294,8 +1330,10 @@ class ECBackend(PGBackend):
             # ordered sends over ordered channels make completions
             # arrive in submission order; clients observe per-object
             # commit order
-            op.on_all_commit(0)
-            self._complete_op(op)
+            with section("ec.commit", op=op.reqid,
+                         pg=self.host.pgid_str):
+                op.on_all_commit(0)
+                self._complete_op(op)
 
     # -- sub-write deadlines (osd_ec_subwrite_timeout_ms) --------------
     def _arm_subwrite_deadline(self, op: _WriteOp, attempt: int,
@@ -1431,7 +1469,7 @@ class ECBackend(PGBackend):
             return
         min_needed = need if need is not None else len(shards)
 
-        def reads_done(received: Dict[int, bytes],
+        def reconstruct(received: Dict[int, bytes],
                        errors: Dict[int, int]) -> None:
             if errors or len(received) < min_needed:
                 cb(-5, b"")
@@ -1457,29 +1495,31 @@ class ECBackend(PGBackend):
                     if lock is None:
                         import contextlib
                         lock = contextlib.nullcontext()
-                    with lock:
-                        if dec is None:
-                            cb(-5, b"")
-                            return
-                        try:
-                            if hop_msg is not None:
-                                hop_msg.stamp_hop("decode_complete")
-                            import numpy as np
-                            cs = self.sinfo.chunk_size
-                            total = len(dec[0])
-                            nst = total // cs if cs else 0
-                            shards = np.stack(
-                                [np.frombuffer(dec[i], dtype=np.uint8)
-                                 .reshape(nst, cs)
-                                 for i in range(self.k)], axis=1)
-                            data = shards.reshape(
-                                nst * self.sinfo.stripe_width
-                            ).tobytes()  # copycheck: ok - shard interleave -> client payload
-                        except Exception:
-                            cb(-5, b"")
-                            return
-                        lo = offset - astart
-                        cb(0, data[lo:lo + length])
+                    with section("ec.reconstruct", op=reqid,
+                                 pg=self.host.pgid_str):
+                        with lock:
+                            if dec is None:
+                                cb(-5, b"")
+                                return
+                            try:
+                                if hop_msg is not None:
+                                    hop_msg.stamp_hop("decode_complete")
+                                import numpy as np
+                                cs = self.sinfo.chunk_size
+                                total = len(dec[0])
+                                nst = total // cs if cs else 0
+                                shards = np.stack(
+                                    [np.frombuffer(dec[i], dtype=np.uint8)
+                                     .reshape(nst, cs)
+                                     for i in range(self.k)], axis=1)
+                                data = shards.reshape(
+                                    nst * self.sinfo.stripe_width
+                                ).tobytes()  # copycheck: ok - shard interleave -> client payload
+                            except Exception:
+                                cb(-5, b"")
+                                return
+                            lo = offset - astart
+                            cb(0, data[lo:lo + length])
 
                 batcher.submit_decode(self.ec_impl, self.sinfo,
                                       received, set(range(self.k)),
@@ -1527,6 +1567,15 @@ class ECBackend(PGBackend):
                 return
             lo = offset - astart
             cb(0, data[lo:lo + length])
+
+        reqid = f"{hop_msg.client}:{hop_msg.tid}" \
+            if hop_msg is not None else ""
+
+        def reads_done(received: Dict[int, bytes],
+                       errors: Dict[int, int]) -> None:
+            with section("ec.reconstruct", op=reqid,
+                         pg=self.host.pgid_str, bytes=length):
+                reconstruct(received, errors)
 
         if hop_msg is not None:
             hop_msg.stamp_hop("read_queued")
@@ -1627,6 +1676,12 @@ class ECBackend(PGBackend):
 
     def _local_chunk_read(self, oid: str, shard: int, off: int,
                           length: int) -> Tuple[bytes, int]:
+        with section("ec.sub_read", pg=self.host.pgid_str, shard=shard,
+                     bytes=length):
+            return self._chunk_read(oid, shard, off, length)
+
+    def _chunk_read(self, oid: str, shard: int, off: int,
+                    length: int) -> Tuple[bytes, int]:
         try:
             data = self.host.store.read(
                 self.host.coll_of(shard), GHObject(oid, shard), off,
@@ -1655,9 +1710,11 @@ class ECBackend(PGBackend):
             except (FileNotFoundError, KeyError, ValueError):
                 hinfo = None
             if hinfo is not None and \
-                    hinfo.total_chunk_size == len(data) and \
-                    ecutil.chunk_crc(data) != hinfo.crcs[shard]:
-                return b"", -5
+                    hinfo.total_chunk_size == len(data):
+                with section("crc.host", bytes=len(data), blocks=1):
+                    crc = ecutil.chunk_crc(data)
+                if crc != hinfo.crcs[shard]:
+                    return b"", -5
         return data, 0
 
     def _read_piece(self, rop: _ReadOp, shard: int, data: bytes,
@@ -2331,7 +2388,9 @@ class ECBackend(PGBackend):
                             int(parts[1 + nz.index(s)][idx])
                             if s else 0 for s in scales]
                 else:
-                    crcs = lin.crc_batch(chunks, backend=backend)
+                    with section("crc.device", blocks=len(chunks),
+                                 bytes=sum(lens)):
+                        crcs = lin.crc_batch(chunks, backend=backend)
                     for idx, (entry, _d, _h) in enumerate(window):
                         entry["data_crc"] = int(crcs[idx])
                 self.scrub_device_windows = getattr(

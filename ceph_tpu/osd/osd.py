@@ -51,6 +51,7 @@ from ..store.objectstore import ObjectStore
 from ..utils.config import Config, default_config
 from ..utils.lockdep import make_lock
 from ..utils.log import Dout
+from ..utils.tracer import section
 from .osdmap import OSDMap, PGid
 from .pg import PG, STATE_ACTIVE, STATE_PEERING
 
@@ -327,7 +328,8 @@ class OSD(Dispatcher):
         from .batcher import EncodeBatcher
         self.encode_batcher = EncodeBatcher(
             self.conf, perf=self.perf, perf_coll=self.perf_coll,
-            recorder=self.flight_recorder, contention=self.contention)
+            recorder=self.flight_recorder, contention=self.contention,
+            daemon=f"osd.{whoami}")
         # checksum offload: a deferred-checksum store (BlueStore)
         # folds its apply-batch CRCs through the codec backend's
         # GF-bitmatrix kernel when an accelerator is live; resolved
@@ -1001,7 +1003,9 @@ class OSD(Dispatcher):
             self.perf.inc("op_in_bytes",
                           sum(len(op.data or b"") for op in msg.ops))
         try:
-            pg.do_request(msg, conn)
+            with section("pg.do_op", op=f"{msg.client}:{msg.tid}",
+                         pg=str(pgid), trace_id=msg.trace_id):
+                pg.do_request(msg, conn)
         except Exception:
             import traceback
             traceback.print_exc()

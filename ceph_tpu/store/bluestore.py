@@ -67,6 +67,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 from ..utils.crc import crc32c
 from ..utils.finisher import Finisher
 from ..utils.log import derr_once
+from ..utils.tracer import section
 from .blockstore import BLOCK, BitmapAllocator, BlockStore, _Extents
 from .kv import MemDB, LogDB, WriteBatch
 from .objectstore import (_TXN_TLS, GHObject, Transaction, check_ops)
@@ -273,7 +274,9 @@ class BlueStore(BlockStore):
             self._wal_roll()
             seg = self._wal_segs[-1]
         fh = seg[2]
-        fh.write(_WAL_HDR.pack(len(record), crc32c(record), seq))
+        with section("crc.host", bytes=len(record), blocks=1):
+            crc = crc32c(record)
+        fh.write(_WAL_HDR.pack(len(record), crc, seq))
         fh.write(record)
         fh.flush()
         seg[3] = seq
@@ -301,9 +304,12 @@ class BlueStore(BlockStore):
                 with self._qcond:
                     top = self._wal_seq
                     fhs, self._wal_unsynced = self._wal_unsynced, []
-                for fh in fhs:
-                    fh.flush()
-                    os.fsync(fh.fileno())
+                # the leader's sync is the WAL's work; a follower's
+                # wait for it above is not
+                with section("store.wal", files=len(fhs)):
+                    for fh in fhs:
+                        fh.flush()
+                        os.fsync(fh.fileno())
             except BaseException:
                 with self._gc_cond:
                     self._gc_syncing = False
@@ -508,7 +514,8 @@ class BlueStore(BlockStore):
                 len(op[4]) for op in merged_ops
                 if op[0] in ("write", "xor_write"))
             self._txn_meta("journal_bytes", nbytes)
-            self._wal_write(seq, record, nbytes)
+            with section("store.wal", bytes=nbytes):
+                self._wal_write(seq, record, nbytes)
             self._stamp_txn("journal_append")
             p = _Pending(seq, txns, merged_ops)
             p.led = led
@@ -568,7 +575,13 @@ class BlueStore(BlockStore):
             self._qcond.notify_all()
 
     def _reactor_pump(self) -> None:
-        self._pump_once()
+        # a pump that finds the apply mutex taken must not re-arm: it
+        # would spin on the reactor (some 190,000 empty callbacks a
+        # second, each contending for the interpreter with the thread
+        # that is applying).  Whoever holds the mutex kicks this pump
+        # when it lets go (_pump_once).
+        if not self._pump_once():
+            return
         with self._qcond:
             more = self._ready_locked() and not self._stop
         r = self._reactor
@@ -622,9 +635,17 @@ class BlueStore(BlockStore):
             if not batch:
                 return False
             self._apply_batch(batch)
-            return True
         finally:
             self._apply_mutex.release()
+        r = self._reactor
+        if r is not None and not r.in_reactor():
+            # a thread that stole the apply from the reactor's pump:
+            # what became ready meanwhile is the pump's again
+            with self._qcond:
+                more = self._ready_locked() and not self._stop
+            if more:
+                self._kick_apply()
+        return True
 
     def _apply_batch(self, batch: List[_Pending]) -> None:
         t_dq = time.time()
@@ -635,29 +656,33 @@ class BlueStore(BlockStore):
         kvbatch = WriteBatch()
         dirty = False
         with self._lock:
-            for p in live:
-                prev = getattr(_TXN_TLS, "led", None)
-                _TXN_TLS.led = p.led
-                mark = len(kvbatch.ops)
-                try:
-                    dirty = self._apply_ops(p.ops, kvbatch) or dirty
-                except Exception:
-                    # commit was already acked at WAL durability; a
-                    # failed apply (csum EIO on an RMW base) cannot
-                    # unwind it.  Roll this entry's KV ops back so
-                    # the rest of the batch commits clean, and count
-                    # the casualty (reference BlueStore asserts here;
-                    # we degrade to a surfaced counter).
-                    del kvbatch.ops[mark:]
-                    self.apply_errors += 1
-                finally:
-                    _TXN_TLS.led = prev
-            self._wbuf_flush()
-            self._flush_dev(dirty)
+            with section("store.data_write", txns=len(live),
+                         ops=sum(len(p.ops) for p in live)):
+                for p in live:
+                    prev = getattr(_TXN_TLS, "led", None)
+                    _TXN_TLS.led = p.led
+                    mark = len(kvbatch.ops)
+                    try:
+                        dirty = self._apply_ops(p.ops, kvbatch) or dirty
+                    except Exception:
+                        # commit was already acked at WAL durability; a
+                        # failed apply (csum EIO on an RMW base) cannot
+                        # unwind it.  Roll this entry's KV ops back so
+                        # the rest of the batch commits clean, and
+                        # count the casualty (reference BlueStore
+                        # asserts here; we degrade to a surfaced
+                        # counter).
+                        del kvbatch.ops[mark:]
+                        self.apply_errors += 1
+                    finally:
+                        _TXN_TLS.led = prev
+                self._wbuf_flush()
+                self._flush_dev(dirty)
             t_dw = time.time()
-            kvbatch.set("alloc", self._alloc.state())
-            kvbatch.set(APPLIED_KEY, str(batch[-1].seq).encode())
-            self._db.submit(kvbatch, sync=bool(self.path))
+            with section("store.kv_commit", ops=len(kvbatch.ops)):
+                kvbatch.set("alloc", self._alloc.state())
+                kvbatch.set(APPLIED_KEY, str(batch[-1].seq).encode())
+                self._db.submit(kvbatch, sync=bool(self.path))
             t_kv = time.time()
         for p in live:
             for txn in p.txns:
@@ -758,15 +783,19 @@ class BlueStore(BlockStore):
                     import jax
                     if jax.default_backend() != "cpu":
                         from ..ops import crclinear
-                        out = crclinear.shared().crc_batch(
-                            blocks, backend=backend)
+                        with section("crc.device", blocks=len(blocks),
+                                     bytes=sum(map(len, blocks))):
+                            out = crclinear.shared().crc_batch(
+                                blocks, backend=backend)
                         self.csum_device_batches += 1
                         return [int(c) for c in out]
             except Exception as e:
                 # host loop serves; the failure stays visible
                 self.csum_device_errors += 1
                 derr_once("store", "bluestore device csum", e)
-        return [crc32c(b) for b in blocks]
+        with section("crc.host", blocks=len(blocks),
+                     bytes=sum(map(len, blocks))):
+            return [crc32c(b) for b in blocks]
 
     # -- read barrier ----------------------------------------------------
     def _wait_applied(self, seq: int) -> None:
@@ -830,7 +859,10 @@ class BlueStore(BlockStore):
     def read(self, coll: str, obj: GHObject, offset: int = 0,
              length: Optional[int] = None) -> bytes:
         self._barrier(coll, obj)
-        return super().read(coll, obj, offset, length)
+        with section("store.read") as sec:
+            data = super().read(coll, obj, offset, length)
+            sec.set_metadata(bytes=len(data))
+        return data
 
     def stat(self, coll: str, obj: GHObject):
         self._barrier(coll, obj)

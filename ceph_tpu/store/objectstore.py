@@ -28,6 +28,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Tuple
 from ..utils import faults as faultlib
 from ..utils import store_ledger
 from ..utils.encoding import Decoder, Encoder
+from ..utils.tracer import section
 
 #: thread-local current store-transaction ledger: backends stamp
 #: phases through _stamp_txn without any signature change to
@@ -519,7 +520,9 @@ class ObjectStore(abc.ABC):
         _TXN_TLS.led = led
         try:
             faultlib.registry().store_apply(txns)
-            self._do_queue_transactions(txns, on_commit)
+            with section("store.txn", op=op or "",
+                         ops=sum(len(t.ops) for t in txns)):
+                self._do_queue_transactions(txns, on_commit)
         except BaseException:
             # abort-path ledger hygiene: a txn that raises (check_ops
             # reject, fault-site error, mid-apply I/O error) leaves

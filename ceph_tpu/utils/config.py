@@ -20,6 +20,8 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from .locks import wait_acquire
+
 LEVEL_BASIC = "basic"
 LEVEL_ADVANCED = "advanced"
 LEVEL_DEV = "dev"
@@ -810,12 +812,19 @@ class Config:
 
     # -- access ------------------------------------------------------------
     def get(self, name: str) -> Any:
-        with self._lock:
+        lock = self._lock
+        if not lock.acquire(False):
+            # every daemon of a process reads its options through this
+            # one lock: a wait for it is a span of the profiler's trace
+            wait_acquire(lock, "config")
+        try:
             if name not in self.schema:
                 raise KeyError(f"unknown option {name!r}")
             for source in reversed(self.SOURCES):
                 if name in self._values[source]:
                     return self._values[source][name]
+        finally:
+            lock.release()
         raise AssertionError("unreachable: defaults always populated")
 
     def __getitem__(self, name: str) -> Any:

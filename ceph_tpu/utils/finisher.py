@@ -16,6 +16,8 @@ import time
 import traceback
 from typing import Callable, List, Optional, Tuple
 
+from .tracer import fn_name, section
+
 
 class Finisher:
     """Single consumer thread draining queued callbacks in order."""
@@ -70,7 +72,8 @@ class Finisher:
                 self._running = len(batch)
             for fn in batch:
                 try:
-                    fn()
+                    with section("finisher.cb", fn=fn_name(fn)):
+                        fn()
                 except Exception:       # callbacks must not kill the thread
                     traceback.print_exc()
                 finally:
@@ -141,6 +144,7 @@ class SafeTimer:
                     continue
                 heapq.heappop(self._heap)
             try:
-                fn()
+                with section("timer.cb", d=self.name, fn=fn_name(fn)):
+                    fn()
             except Exception:
                 traceback.print_exc()

@@ -20,11 +20,13 @@ counter.
 """
 from __future__ import annotations
 
+import re
 import threading
 import time
 from typing import List, Optional
 
 from . import lockdep
+from .tracer import section, tracing
 
 #: log-spaced bounds in MICROSECONDS for wait/hold histograms: lock
 #: handoffs live in the 1-100us range, stalls in the ms+ tail
@@ -32,6 +34,36 @@ US_BOUNDS: List[float] = [
     1.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
     1e3, 2.5e3, 5e3, 10e3, 25e3, 50e3, 100e3, 500e3, 1e6,
 ]
+
+
+#: a contended acquire shorter than this leaves no ``lock.wait``
+#: section: a handoff of a few microseconds, thousands of times a
+#: second, would cost the trace's reader more than it tells
+WAIT_SECTION_S = 50e-6
+_OWNER = re.compile(r"owner=(\d+)")     # in the repr of an RLock
+
+
+def wait_acquire(inner, site: str, holder: Optional[int] = None,
+                 timeout: float = -1) -> bool:
+    """The blocking half of an acquire whose ``inner.acquire(False)``
+    just failed: the wait is a ``lock.wait`` section of the profiler's
+    trace, naming the site and the thread that was holding the lock.
+    ``holder`` is the ident the lock noted when it was acquired; a
+    bare RLock whose holders cannot afford the note (``Config.get``:
+    the note would lengthen a critical section that 52 reactors queue
+    for) is asked for its owner here, on the waiter's time.  With no
+    session recording, this is the blocking acquire and nothing else."""
+    if not tracing():
+        return inner.acquire(True, timeout)
+    if holder is None:
+        owner = _OWNER.search(repr(inner))
+        holder = int(owner.group(1)) if owner else 0
+    if timeout < 0 and inner.acquire(True, WAIT_SECTION_S):
+        return True
+    name = next((t.name for t in threading.enumerate()
+                 if t.ident == holder), str(holder))
+    with section("lock.wait", site=site, holder=name):
+        return inner.acquire(True, timeout)
 
 
 class ContentionStats:
@@ -121,6 +153,7 @@ class TimedLock:
         self._inner = inner if inner is not None else lockdep.make_lock(name)
         self._local = threading.local()
         self._stats = None
+        self.holder = 0          # ident of the last thread to acquire
         self.bind(stats)
 
     def bind(self, stats: Optional[ContentionStats]) -> None:
@@ -132,11 +165,15 @@ class TimedLock:
 
     def acquire(self, blocking: bool = True, timeout: float = -1):
         st = self._stats
-        if st is None:
-            return self._inner.acquire(blocking, timeout)
         t0 = time.perf_counter()
-        got = self._inner.acquire(blocking, timeout)
+        got = self._inner.acquire(False)
+        if not got and blocking:
+            got = wait_acquire(self._inner, self.name, self.holder,
+                               timeout)
         if got:
+            self.holder = threading.get_ident()
+            if st is None:
+                return got
             loc = self._local
             depth = getattr(loc, "depth", 0)
             if depth == 0:

@@ -26,6 +26,8 @@ import sys
 import threading
 from typing import Dict, List, Optional, Tuple
 
+from .tracer import section
+
 _MAX_DEPTH = 48          # frames kept per stack (outermost dropped)
 _MAX_STACKS = 20_000     # distinct folded stacks kept (then "(other)")
 
@@ -80,32 +82,33 @@ class StackSampler:
     # -- sampling ------------------------------------------------------
     def sample_once(self) -> None:
         """One snapshot of every thread but our own."""
-        names = {t.ident: t.name for t in threading.enumerate()}
-        me = threading.get_ident()
-        frames = sys._current_frames()
-        folded: List[str] = []
-        for tid, frame in frames.items():
-            if tid == me:
-                continue
-            parts: List[str] = []
-            f = frame
-            while f is not None and len(parts) < _MAX_DEPTH:
-                code = f.f_code
-                parts.append(getattr(code, "co_qualname", code.co_name))
-                f = f.f_back
-            parts.reverse()
-            folded.append(names.get(tid, f"tid-{tid}")
-                          + ";" + ";".join(parts))
-        with self._lock:
-            self.samples += 1
-            d = self._folded
-            for key in folded:
-                if key in d:
-                    d[key] += 1
-                elif len(d) < _MAX_STACKS:
-                    d[key] = 1
-                else:
-                    d["(other)"] = d.get("(other)", 0) + 1
+        with section("sampler.pass"):
+            names = {t.ident: t.name for t in threading.enumerate()}
+            me = threading.get_ident()
+            frames = sys._current_frames()
+            folded: List[str] = []
+            for tid, frame in frames.items():
+                if tid == me:
+                    continue
+                parts: List[str] = []
+                f = frame
+                while f is not None and len(parts) < _MAX_DEPTH:
+                    code = f.f_code
+                    parts.append(getattr(code, "co_qualname", code.co_name))
+                    f = f.f_back
+                parts.reverse()
+                folded.append(names.get(tid, f"tid-{tid}")
+                              + ";" + ";".join(parts))
+            with self._lock:
+                self.samples += 1
+                d = self._folded
+                for key in folded:
+                    if key in d:
+                        d[key] += 1
+                    elif len(d) < _MAX_STACKS:
+                        d[key] = 1
+                    else:
+                        d["(other)"] = d.get("(other)", 0) + 1
 
     # -- output --------------------------------------------------------
     def dump_folded(self, prefix: Optional[str] = None) -> List[str]:

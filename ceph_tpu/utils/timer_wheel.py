@@ -26,6 +26,8 @@ import threading
 import time
 from typing import Callable, List, Optional
 
+from .tracer import fn_name, section
+
 
 class TimerHandle:
     """Cancellable handle returned by :meth:`TimerWheel.call_later`.
@@ -145,10 +147,11 @@ class TimerWheel:
                         cb(lag)
                     except Exception:
                         pass
-                try:
-                    fn()
-                except Exception:       # noqa: BLE001 - timer cbs must not kill the wheel
-                    pass
+                with section("timer.cb", fn=fn_name(fn)) as sec:
+                    try:
+                        fn()
+                    except Exception as e:  # noqa: BLE001 - timer cbs must not kill the wheel
+                        sec.set_metadata(error=type(e).__name__)
 
     # -- lifecycle ---------------------------------------------------
     def stop(self) -> None:

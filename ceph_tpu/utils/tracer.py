@@ -16,14 +16,88 @@ collector; here the collector is the admin surface).
 Sampling: ``Tracer.enabled`` plus ``sample_every`` — tracing every
 Nth op keeps the hot path cheap (id generation + two timestamps per
 span when on; one branch when off).
+
+``section(name, **meta)`` is the other span: bound to one thread,
+nesting, and on the clock of ``jax.profiler``.  It is a
+``jax.profiler.TraceAnnotation`` and nothing else, so it keeps no
+ring, lock, clock or option: with no profiler session active it
+records nothing, and inside one (``benchmark/run.py --trace 1``, an
+operator's ``jax.profiler.start_trace``) it lands on its thread's line
+of the same ``.xplane.pb`` as the device's ``XLA Ops``.  Rules of
+placement (PERF.md, "Spans and counters"): a section covers work,
+never parking (no ``select``, ``Condition.wait`` or idle queue
+``get`` inside one); names are fixed ``<layer>.<verb>`` strings, ids
+go in the keywords (``op=<reqid>`` ties one request's sections
+together across threads and daemons); none inside a per-stripe,
+per-block or per-byte loop.
 """
 from __future__ import annotations
 
 import random
+import sys
 import threading
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional
+
+
+class _NoSection:
+    """What ``section`` hands out in a process without JAX."""
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSection":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **meta) -> None:
+        return None
+
+
+_NO_SECTION = _NoSection()
+_annotation = None      # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def section(name: str, **meta):
+    """Context manager: a span of this thread in the profiler's trace.
+
+    ``with section("pg.do_op", op=reqid) as s: ...``; a keyword that is
+    known only at the end goes in with ``s.set_metadata(error=...)``.
+    JAX is never imported from here: a process that has not loaded it
+    (``rados_cli``, ``ceph_cli``) can have no profiler session either.
+    """
+    ta = _annotation
+    if ta is None:
+        ta = _bind()
+        if ta is None:
+            return _NO_SECTION
+    return ta(name, **meta)
+
+
+def _bind():
+    global _annotation
+    try:
+        _annotation = sys.modules["jax"].profiler.TraceAnnotation
+    except (KeyError, AttributeError):  # JAX absent, or still importing
+        pass
+    return _annotation
+
+
+def tracing() -> bool:
+    """Whether a profiler session is recording sections right now; for
+    a caller whose section costs more than the section (locks.py)."""
+    ta = _annotation or _bind()
+    return ta is not None and ta.is_enabled()
+
+
+def fn_name(fn) -> str:
+    """A callback's name for a section's ``fn=`` keyword."""
+    try:
+        return fn.__qualname__
+    except AttributeError:          # functools.partial, callable object
+        inner = getattr(fn, "func", None)
+        return fn_name(inner) if inner is not None else type(fn).__name__
 
 
 class Span:

@@ -234,3 +234,314 @@ def test_dump_traces_tell_command():
             assert isinstance(spans, list)
         finally:
             client.shutdown()
+
+
+# -- sections: the program's threads on the profiler's clock ------------------
+#
+# One CPU jax.profiler session over a small EC write, an overwrite, a
+# read and a degraded read on a 4-OSD bluestore cluster, then the
+# instruments that have no cluster path of their own.  The trace is
+# read with the benchmark's own reader (benchmark/harness/spans.py).
+
+import os
+import sys
+import threading
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: section -> (a section some instance of it must nest under, or None
+#: for "on top of its thread"; whether it carries the client's reqid)
+SECTIONS = {
+    "reactor.io": (None, False),
+    "reactor.mailbox": (None, False),
+    "reactor.timer": (None, False),
+    "reactor.cb": (None, False),
+    "reactor.tick_hook": (None, False),
+    "msgr.encode": ("reactor.cb", False),
+    "msgr.send": ("reactor.cb", False),
+    "msgr.recv": ("reactor.io", False),
+    "msgr.decode": ("reactor.io", False),
+    "msgr.dispatch": ("reactor.io", False),
+    "msgr.reconnect": (None, False),
+    "objecter.submit": (None, True),
+    "objecter.reply": ("msgr.dispatch", True),
+    "pg.do_op": ("reactor.", True),
+    "ec.prepare": ("pg.do_op", True),
+    "ec.rmw_read": ("ec.prepare", True),
+    "ec.fanout": ("reactor.", True),
+    "ec.sub_write": ("ec.fanout", True),
+    "ec.sub_read": ("msgr.dispatch", False),
+    "ec.commit": ("reactor.", True),
+    "ec.reconstruct": ("reactor.", True),
+    "batcher.submit": ("reactor.tick_hook", False),
+    "batcher.form": (None, False),
+    "batcher.dispatch": ("batcher.form", False),
+    "batcher.complete": (None, False),
+    "batcher.deliver": ("batcher.complete", False),
+    "dispatch.stage_acquire": ("batcher.dispatch", False),
+    "dispatch.h2d": ("batcher.dispatch", False),
+    "dispatch.call": ("batcher.dispatch", False),
+    "dispatch.wait": ("batcher.complete", False),
+    "dispatch.d2h": ("batcher.complete", False),
+    "store.txn": ("ec.sub_write", False),
+    "store.wal": ("store.txn", False),
+    "store.data_write": (None, False),
+    "store.kv_commit": (None, False),
+    "store.read": ("ec.sub_read", False),
+    "crc.host": ("ec.", False),
+    "lock.wait": (None, False),
+    "timer.cb": (None, False),
+    "finisher.cb": (None, False),
+    "sampler.pass": (None, False),
+    "mon.dispatch": ("msgr.dispatch", False),
+    "mon.tick": (None, False),
+}
+# crc.device opens only where jax.default_backend() is not "cpu"
+# (store/bluestore.py, osd/ecbackend.py deep scrub): no CPU case.
+
+
+def _drive_cluster():
+    conf = make_conf(osd_objectstore="bluestore")
+    with Cluster(n_osds=4, conf=conf, store_kind="bluestore") as cl:
+        for i in range(4):
+            cl.wait_for_osd_up(i, 20)
+        cl.create_ec_profile("secp", plugin="tpu", k="2", m="1")
+        cl.create_pool("secpool", "erasure", erasure_code_profile="secp")
+        ret, rs, _ = cl.mon_command({
+            "prefix": "osd pool set", "pool": "secpool",
+            "var": "allow_ec_overwrites", "val": "true"})
+        assert ret == 0, rs
+        r = cl.rados()
+        r.wait_for_epoch(cl.mon.osdmap.epoch, 10)
+        io = r.open_ioctx("secpool")
+        size = 256 << 10
+        names = [f"sec{i}" for i in range(4)]
+        for n in names:
+            io.write_full(n, bytes([len(n)]) * size)
+        cl.wait_for_clean(20)
+        deadline = time.monotonic() + 10
+        from ceph_tpu.client.rados import RadosError
+        while True:                   # flag propagation to the OSDs
+            try:
+                io.write(names[0], b"Z" * 4096, 8192)
+                break
+            except RadosError as e:
+                if e.errno != 95 or time.monotonic() > deadline:
+                    raise
+                time.sleep(0.2)
+        for n in names:
+            io.write(n, b"Y" * 4096, 4096)      # delta RMW
+            io.write(n, b"X" * 100, 70000)      # sub-chunk: reads back
+        for n in names:
+            assert len(io.read(n, length=size)) == size
+        cl.kill_osd(0, lose_data=True)
+        cl.wait_for_osd_down(0)
+        for n in names:                          # degraded: reconstructs
+            got = io.read(n, length=size)
+            assert got[4096:8192] == b"Y" * 4096
+        return {}
+
+
+def _drive_instruments():
+    """What no small cluster reaches by itself."""
+    from ceph_tpu.crimson.reactor import Reactor
+    from ceph_tpu.utils.config import Config
+    from ceph_tpu.utils.locks import ContentionStats, TimedLock
+    from ceph_tpu.utils.sampler import StackSampler
+    from ceph_tpu.utils.timer_wheel import TimerWheel
+    out = {}
+    # a contended and an uncontended TimedLock; the shared Config's lock
+    hot = TimedLock("pg_lock", stats=ContentionStats())
+    quiet = TimedLock("quiet_lock")
+    conf = Config()
+    held = threading.Event()
+    release = threading.Event()
+
+    def holder():
+        with hot, conf._lock:
+            held.set()
+            release.wait(5)
+    t = threading.Thread(target=holder, name="the-holder")
+    t.start()
+    assert held.wait(5)
+    threading.Timer(0.05, release.set).start()
+    with hot:
+        pass
+    t.join(5)
+    assert not t.is_alive()
+    held.clear()
+    release.clear()
+    t = threading.Thread(target=holder, name="the-holder")
+    t.start()
+    assert held.wait(5)
+    threading.Timer(0.05, release.set).start()
+    assert conf.get("osd_tick_interval") > 0
+    t.join(5)
+    assert not t.is_alive()
+    for _ in range(100):
+        with quiet:
+            pass
+    # a reactor callback that raises: counted, and named on its section
+    r = Reactor("lone-reactor")
+    r.start()
+    done = threading.Event()
+
+    def boom():
+        raise ValueError("swallowed by the loop")
+    r.call_soon(boom)
+    r.call_soon(done.set)
+    assert done.wait(5)
+    r.stop()
+    out["reactor"] = r
+    # a timer-wheel callback and one sampler pass
+    fired = threading.Event()
+    wheel = TimerWheel()
+    wheel.call_later(0.01, fired.set)
+    assert fired.wait(5)
+    wheel.stop()
+    StackSampler().sample_once()
+    # one encode straight through the plugin, marked by a section of
+    # the test's own around it
+    import numpy as np
+    from ceph_tpu.ec import registry as ecreg
+    from ceph_tpu.utils.tracer import section
+    codec = ecreg.instance().factory("tpu", {"k": "8", "m": "4"})
+    data = np.arange(16 * 8 * 4096, dtype=np.uint32).astype(np.uint8) \
+        .reshape(16, 8, 4096)
+    with section("batcher.dispatch", lane="linktest"):
+        parity = codec.encode_batch_async(data).wait()
+    assert parity.shape == (16, 4, 4096)
+    out["link_payload"] = data.nbytes
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    import jax
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    from harness import spans
+    from ceph_tpu.utils.tracer import section
+    log_dir = str(tmp_path_factory.mktemp("trace"))
+    with section("msgr.send", bytes=1):
+        pass                        # no session yet: leaves nothing
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        extras = _drive_instruments()
+        extras.update(_drive_cluster())
+    finally:
+        jax.profiler.stop_trace()
+    plain = spans.load(log_dir)
+    # every section with the names of the sections it nests under
+    seen = {}
+    for evs in spans.clipped(plain, *spans.window_of(plain)):
+        stack = []
+        for s, e, name, meta in evs:
+            while stack and stack[-1][0] <= s:
+                stack.pop()
+            # (names it nests under, its keywords, theirs)
+            seen.setdefault(name, []).append(
+                ([n for _, n, _ in stack], meta,
+                 [m for _, _, m in stack]))
+            stack.append((e, name, meta))
+    return {"plain": plain, "seen": seen, "spans": spans, **extras}
+
+
+@pytest.mark.parametrize("name", sorted(SECTIONS))
+def test_section_is_there_nests_and_names_its_op(traced, name):
+    parent, has_op = SECTIONS[name]
+    rows = traced["seen"].get(name)
+    assert rows, f"no {name} section in the trace; has " \
+        f"{sorted(traced['seen'])}"
+    if parent is not None:
+        assert any(any(a.startswith(parent) for a in anc)
+                   for anc, _, _ in rows), \
+            f"no {name} nests under {parent}: " \
+            f"{sorted({tuple(a) for a, _, _ in rows})[:5]}"
+    else:
+        assert any(not anc or anc[0].startswith("reactor.")
+                   for anc, _, _ in rows) or name == "lock.wait"
+    if has_op:
+        ops = {m.get("op") for _, m, _ in rows if m.get("op")}
+        assert ops and all(":" in op for op in ops), rows[:3]
+
+
+def test_sections_of_one_request_share_its_reqid_across_daemons(traced):
+    seen = traced["seen"]
+    submitted = {m["op"] for _, m, _ in seen["objecter.submit"]}
+    for name in ("pg.do_op", "ec.prepare", "ec.fanout", "ec.sub_write",
+                 "ec.commit", "objecter.reply"):
+        ops = {m.get("op") for _, m, _ in seen[name] if m.get("op")}
+        assert ops & submitted, name
+    # the outermost section of a thread loop says whose thread it is
+    assert {m.get("d") for _, m, _ in seen["reactor.io"]} >= \
+        {"crimson-osd1-r0"}
+    assert any(str(m.get("d", "")).startswith("osd.")
+               for _, m, _ in seen["batcher.form"])
+
+
+def test_lock_wait_only_under_contention_with_site_and_holder(traced):
+    waits = [m for _, m, _ in traced["seen"]["lock.wait"]]
+    sites = {m["site"] for m in waits}
+    assert "pg_lock" in sites and "config" in sites
+    assert "quiet_lock" not in sites
+    assert {m["holder"] for m in waits
+            if m["site"] in ("pg_lock", "config")} >= {"the-holder"}
+
+
+def test_reactor_counts_and_names_the_exceptions_it_swallows(traced):
+    r = traced["reactor"]
+    assert r.callbacks_failed == 1
+    errs = [m for _, m, _ in traced["seen"]["reactor.cb"]
+            if m.get("error")]
+    assert any(m["error"] == "ValueError" and m["d"] == "lone-reactor"
+               and m["fn"].endswith("boom") for m in errs)
+    r.util_samples.append((0.0, 0.5, 0.0, r.callbacks_failed))
+    assert r.util_dump()[-1]["callbacks_failed"] == 1
+
+
+def test_link_bytes_per_user_byte_is_k_plus_m_over_k_for_encode(traced):
+    """One encode of 16 stripes at k=8 m=4 through the plugin's async
+    path: 8 chunks in, 4 out, no padding at a batch of 16 and no CRC on
+    a CPU backend, so what crossed is exactly 1.5 times the payload."""
+    crossed = {"dispatch.h2d": 0, "dispatch.d2h": 0}
+    for name in crossed:
+        for _, meta, theirs in traced["seen"][name]:
+            if any(m.get("lane") == "linktest" for m in theirs):
+                crossed[name] += meta["bytes"]
+    assert crossed == {"dispatch.h2d": traced["link_payload"],
+                       "dispatch.d2h": traced["link_payload"] // 2}
+    assert sum(crossed.values()) / traced["link_payload"] == 1.5
+
+
+def test_section_records_nothing_and_costs_little_without_a_session():
+    import statistics
+    from jax.profiler import TraceAnnotation
+    from ceph_tpu.utils.tracer import section
+    assert not TraceAnnotation.is_enabled()
+    costs = []
+    for _ in range(200):
+        t0 = time.perf_counter()
+        for _ in range(100):
+            with section("msgr.send", bytes=4096, peer="osd.1"):
+                pass
+        costs.append((time.perf_counter() - t0) / 100)
+    assert statistics.median(costs) < 5e-6
+
+
+def test_no_section_of_before_the_session_is_in_the_trace(traced):
+    sends = [m for _, m, _ in traced["seen"]["msgr.send"]]
+    assert all(m.get("bytes") != 1 for m in sends)
+
+
+def test_section_without_jax_is_a_null_context():
+    import subprocess
+    code = ("import sys; from ceph_tpu.utils.tracer import section\n"
+            "from ceph_tpu.client import rados\n"
+            "with section('objecter.submit', op='c:1') as s:\n"
+            "    s.set_metadata(bytes=1)\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    subprocess.run([sys.executable, "-c", code], cwd=ROOT, check=True,
+                   timeout=60)
