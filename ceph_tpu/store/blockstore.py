@@ -10,8 +10,9 @@ into freshly allocated blocks (reference blob/extent COW) so crash
 consistency reduces to "data blocks written+synced BEFORE the one
 atomic KV commit that references them".
 
-Every data block carries a CRC32C in the extent map, verified on
-every read (reference BlueStore::_verify_csum on each blob read,
+Every data block carries a CRC32C in the extent map, and every block
+a read returns bytes of is verified against it before they leave the
+store (reference BlueStore::_verify_csum on each blob read,
 BlueStore.cc:10425,10446 — scrub is the backstop, the csum is the
 front line): a mismatch surfaces as EIO so the OSD read path retries
 over other replicas/shards and repair-via-recovery can re-home a good
@@ -49,10 +50,11 @@ import json
 import os
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Set
+from typing import Callable, Dict, List, Optional, Set, Tuple
 
-from ..utils.crc import crc32c
+from ..utils.crc import crc32c, crc32c_blocks
 from ..utils.finisher import Finisher
+from ..utils.tracer import section
 from .filestore import _BatchView, _objkey, _unobjkey
 from .kv import LogDB, WriteBatch
 from .objectstore import (GHObject, ObjectStat, ObjectStore,
@@ -157,6 +159,11 @@ class BlockStore(ObjectStore):
         self.compress_logical_bytes = 0
         self.compress_stored_bytes = 0
         self.csum_failures = 0
+        # how much of its objects the reads touched: blocks gathered
+        # and verified against blocks the objects read from have
+        self.read_calls = 0
+        self.read_blocks = 0
+        self.read_obj_blocks = 0
 
     def _compressor(self, alg: str):
         from ..compressor import registry as creg
@@ -236,6 +243,12 @@ class BlockStore(ObjectStore):
         self._dev.seek(phys * BLOCK)
         buf = self._dev.read(BLOCK)
         return buf.ljust(BLOCK, b"\x00")
+
+    def _read_run(self, phys: int, out: memoryview) -> None:
+        """Physically contiguous blocks from ``phys`` on, into the
+        zeroed ``out``: one seek and one read however many blocks."""
+        self._dev.seek(phys * BLOCK)
+        self._dev.readinto(out)
 
     def _write_block(self, phys: int, data: bytes) -> None:
         assert len(data) == BLOCK
@@ -354,7 +367,7 @@ class BlockStore(ObjectStore):
             return ext_cache[key]
 
         def read_in_txn(coll, obj) -> bytes:
-            return self._materialize(get_ext(coll, obj))
+            return self._verified(*self._gather(get_ext(coll, obj)))
 
         def put_ext(coll, obj, ext) -> None:
             ext_cache[self._xkey(coll, obj)] = ext
@@ -822,46 +835,85 @@ class BlockStore(ObjectStore):
             raise OSError(errno.EIO, "segment length mismatch")
         return raw
 
-    def _materialize(self, ext: _Extents) -> bytes:
-        """Full object bytes with every block CRC-verified (reference
-        _verify_csum on each read, BlueStore.cc:10425): rot surfaces
-        as EIO here instead of propagating silently — the OSD read
-        path turns it into a reconstructing/replica retry and scrub
-        repair re-homes a good copy."""
-        out = bytearray()
+    def _gather(self, ext: _Extents, offset: int = 0,
+                length: Optional[int] = None
+                ) -> Tuple[bytearray, List[int], int, int, int]:
+        """The logical blocks that hold ``[offset, offset + length)``
+        of the object, copied into one private buffer, with the CRCs
+        the extent map expects of them (caller holds the lock).  Only
+        those blocks are touched: holes stay zero, physically
+        contiguous blocks come in one read, a compressed segment is
+        decompressed only if the range reaches one of its members.
+        COW may hand a block's physical home to the next apply, so
+        nothing of the device outlives the lock but this copy.
+        -> (buffer, expected CRCs, first logical block, and where the
+        range starts and stops inside the buffer)."""
+        stop = None if length is None else offset + length
+        start, stop, _ = slice(offset, stop).indices(ext.size)
+        if stop <= start:               # nothing asked for, or past EOF
+            start = stop = 0
+        blocks = ext.blocks
+        lb0 = start // BLOCK
+        lb1 = max(lb0, min(-(-stop // BLOCK), len(blocks)))
+        buf = bytearray((lb1 - lb0) * BLOCK)
         seg_cache: Dict[str, bytes] = {}
-        for lb, phys in enumerate(ext.blocks):
-            if phys == -1:
-                out.extend(b"\x00" * BLOCK)
-                continue
-            sid = ext.seg_of(lb)
-            if sid is None:
-                blk = self._read_block(phys)
-            else:
-                if sid not in seg_cache:
-                    seg_cache[sid] = self._decompress_seg(
-                        ext.segs[sid])
-                i = lb - ext.segs[sid]["lb0"]
-                blk = seg_cache[sid][i * BLOCK:(i + 1) * BLOCK]
-            want = ext.crcs[lb] if lb < len(ext.crcs) else 0
-            if want and crc32c(blk) != want:
-                self.csum_failures += 1
-                raise OSError(errno.EIO,
-                              f"csum mismatch at logical block {lb}")
-            out.extend(blk)
-        return bytes(out[:ext.size])  # copycheck: ok - returns an immutable object image; read path, not apply
+        with memoryview(buf) as out:
+            lb = lb0
+            while lb < lb1:
+                phys = blocks[lb]
+                at = (lb - lb0) * BLOCK
+                run = 1
+                if phys >= 0:
+                    while lb + run < lb1 and \
+                            blocks[lb + run] == phys + run:
+                        run += 1
+                    self._read_run(phys, out[at:at + run * BLOCK])
+                elif phys <= -2:
+                    sid = ext.seg_of(lb)
+                    if sid not in seg_cache:
+                        seg_cache[sid] = self._decompress_seg(
+                            ext.segs[sid])
+                    i = lb - ext.segs[sid]["lb0"]
+                    out[at:at + BLOCK] = \
+                        seg_cache[sid][i * BLOCK:(i + 1) * BLOCK]
+                lb += run
+        self.read_calls += 1
+        self.read_blocks += lb1 - lb0
+        self.read_obj_blocks += len(blocks)
+        return (buf, ext.crcs[lb0:lb1], lb0,
+                start - lb0 * BLOCK, stop - lb0 * BLOCK)
 
-    def _read_object(self, coll: str, obj: GHObject) -> bytes:
-        return self._materialize(self._load_extents(coll, obj))
+    def _verified(self, buf: bytearray, want: List[int], lb0: int,
+                  start: int, stop: int) -> bytes:
+        """What _gather copied, checked in one native call against the
+        CRCs it noted (reference _verify_csum on each read,
+        BlueStore.cc:10425), then cut to the range.  Needs no lock.
+        Rot surfaces as EIO here instead of propagating silently — the
+        OSD read path turns it into a reconstructing/replica retry and
+        scrub repair re-homes a good copy.  An expected 0 is a hole, a
+        pre-csum map or a checksum still queued: not compared."""
+        got = crc32c_blocks(buf, BLOCK)
+        if got != want:
+            for i, (have, crc) in enumerate(zip(got, want)):
+                if crc and have != crc:
+                    self.csum_failures += 1
+                    raise OSError(errno.EIO, "csum mismatch at "
+                                  f"logical block {lb0 + i}")
+        with memoryview(buf) as out:
+            return bytes(out[start:stop])  # copycheck: ok - the one copy after the gather: an immutable image of the range; read path, not apply
 
     def read(self, coll: str, obj: GHObject, offset: int = 0,
              length: Optional[int] = None) -> bytes:
-        with self._lock:
-            self._check_obj(coll, obj)
-            data = self._read_object(coll, obj)
-        if length is None:
-            return data[offset:]
-        return data[offset:offset + length]
+        with section("store.read") as sec:
+            with self._lock:
+                self._check_obj(coll, obj)
+                ext = self._load_extents(coll, obj)
+                gathered = self._gather(ext, offset, length)
+            data = self._verified(*gathered)
+            sec.set_metadata(bytes=len(data),
+                             blocks=len(gathered[0]) // BLOCK,
+                             obj_blocks=len(ext.blocks))
+        return data
 
     def stat(self, coll: str, obj: GHObject) -> ObjectStat:
         with self._lock:
@@ -936,11 +988,19 @@ class BlockStore(ObjectStore):
             return {"block_size": BLOCK,
                     "blocks_used": self._alloc.used(),
                     "bytes_used": self._alloc.used() * BLOCK,
-                    "dev_bytes": os.path.getsize(
-                        os.path.join(self.path, "block.dev")),
+                    "dev_bytes": self._dev_bytes(),
                     "compress_logical_bytes":
                         self.compress_logical_bytes,
                     "compress_stored_bytes":
                         self.compress_stored_bytes,
-                    "csum_failures": self.csum_failures}
+                    "csum_failures": self.csum_failures,
+                    **self._read_stats()}
+
+    def _dev_bytes(self) -> int:
+        return os.path.getsize(os.path.join(self.path, "block.dev"))
+
+    def _read_stats(self) -> Dict[str, int]:
+        return {"read_calls": self.read_calls,
+                "read_blocks": self.read_blocks,
+                "read_obj_blocks": self.read_obj_blocks}
 

@@ -718,6 +718,17 @@ class BlueStore(BlockStore):
             return buf
         return super()._read_block(phys)
 
+    def _read_run(self, phys: int, out: memoryview) -> None:
+        super()._read_run(phys, out)
+        if not self._wbuf:
+            return
+        # a read inside an apply batch (clone, rename): some blocks
+        # have not reached the device yet
+        for i in range(len(out) // BLOCK):
+            buf = self._wbuf.get(phys + i)
+            if buf is not None:
+                out[i * BLOCK:(i + 1) * BLOCK] = buf
+
     def _wbuf_flush(self) -> None:
         """Land the apply batch's buffered blocks as sorted contiguous
         runs: one seek + one writelines per run instead of one
@@ -859,10 +870,7 @@ class BlueStore(BlockStore):
     def read(self, coll: str, obj: GHObject, offset: int = 0,
              length: Optional[int] = None) -> bytes:
         self._barrier(coll, obj)
-        with section("store.read") as sec:
-            data = super().read(coll, obj, offset, length)
-            sec.set_metadata(bytes=len(data))
-        return data
+        return super().read(coll, obj, offset, length)
 
     def stat(self, coll: str, obj: GHObject):
         self._barrier(coll, obj)
@@ -934,23 +942,13 @@ class BlueStore(BlockStore):
         return super().collection_list(coll, start_after, max_return)
 
     # -- introspection -------------------------------------------------
-    def usage(self) -> Dict:
+    def _dev_bytes(self) -> int:
         if self.path:
-            out = super().usage()
-        else:
-            with self._lock:
-                buf = self._dev.getbuffer()
-                dev_bytes = buf.nbytes
-                buf.release()
-                out = {"block_size": BLOCK,
-                       "blocks_used": self._alloc.used(),
-                       "bytes_used": self._alloc.used() * BLOCK,
-                       "dev_bytes": dev_bytes,
-                       "compress_logical_bytes":
-                           self.compress_logical_bytes,
-                       "compress_stored_bytes":
-                           self.compress_stored_bytes,
-                       "csum_failures": self.csum_failures}
+            return super()._dev_bytes()
+        return self._dev.seek(0, os.SEEK_END)    # every IO seeks first
+
+    def usage(self) -> Dict:
+        out = super().usage()
         with self._qcond:
             out["deferred_pending"] = len(self._pending)
         out["wal"] = {
@@ -976,7 +974,8 @@ class BlueStore(BlockStore):
         return {"batches": self.csum_batches,
                 "blocks": self.csum_blocks,
                 "device_batches": self.csum_device_batches,
-                "device_errors": self.csum_device_errors}
+                "device_errors": self.csum_device_errors,
+                **self._read_stats()}
 
     def dump_store(self) -> dict:
         """The base payload plus where the checksums ran: the device

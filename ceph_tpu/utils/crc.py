@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import ctypes
 import threading
-from typing import Optional
+from typing import List, Optional
 
 from . import nativebuild
 
@@ -34,6 +34,10 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.crc32c.argtypes = [ctypes.c_uint32,
                                ctypes.POINTER(ctypes.c_uint8),
                                ctypes.c_size_t]
+        lib.crc32c_blocks.restype = None
+        lib.crc32c_blocks.argtypes = [ctypes.POINTER(ctypes.c_uint8),
+                                      ctypes.c_size_t, ctypes.c_size_t,
+                                      ctypes.POINTER(ctypes.c_uint32)]
         _lib = lib
         return _lib
 
@@ -56,7 +60,7 @@ def _py_table() -> list:
     return _PY_TABLE
 
 
-def _py_crc32c(data: bytes, crc: int) -> int:
+def _py_crc32c(data, crc: int) -> int:
     tbl = _py_table()
     c = crc ^ 0xFFFFFFFF
     for b in data:
@@ -84,3 +88,29 @@ def crc32c(data, crc: int = 0) -> int:
     except (TypeError, ValueError, BufferError):
         buf = (ctypes.c_uint8 * n).from_buffer_copy(data)
     return lib.crc32c(crc, buf, n)
+
+
+def crc32c_blocks(buf, block_len: int) -> List[int]:
+    """The CRC32C of every ``block_len`` bytes of ``buf``, whose
+    length is a multiple of it, in ONE native call: the caller gives
+    up the interpreter lock once however many blocks there are.  A
+    writable ``buf`` is read in place, as by crc32c."""
+    mv = memoryview(buf).cast("B")
+    n, ragged = divmod(len(mv), block_len)
+    if ragged:
+        raise ValueError(f"{len(mv)} bytes are not whole blocks of "
+                         f"{block_len}")
+    if not n:
+        return []
+    # the loader's lock only until the library is there
+    lib = _lib if _lib is not None else _load()
+    if lib is None:
+        return [_py_crc32c(mv[i * block_len:(i + 1) * block_len], 0)
+                for i in range(n)]
+    try:
+        data = (ctypes.c_uint8 * len(mv)).from_buffer(mv)
+    except (TypeError, ValueError, BufferError):
+        data = (ctypes.c_uint8 * len(mv)).from_buffer_copy(mv)
+    out = (ctypes.c_uint32 * n)()
+    lib.crc32c_blocks(data, block_len, n, out)
+    return out[:]

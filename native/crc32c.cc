@@ -36,8 +36,8 @@ extern "C" void crc32c_init() {
   initialized = true;
 }
 
-extern "C" uint32_t crc32c(uint32_t crc, const uint8_t* data,
-                           size_t len) {
+static inline uint32_t crc_body(uint32_t crc, const uint8_t* data,
+                                size_t len) {
   crc = ~crc;
 #if defined(__SSE4_2__)
   while (len >= 8) {
@@ -65,4 +65,19 @@ extern "C" uint32_t crc32c(uint32_t crc, const uint8_t* data,
     crc = table[0][(crc ^ *data++) & 0xff] ^ (crc >> 8);
 #endif
   return ~crc;
+}
+
+extern "C" uint32_t crc32c(uint32_t crc, const uint8_t* data,
+                           size_t len) {
+  return crc_body(crc, data, len);
+}
+
+// n independent CRC32Cs, one per block_len bytes of a contiguous
+// buffer: a store read verifies all the blocks it gathered in one
+// call, so the caller drops and re-takes the interpreter lock once
+// and not once a block.
+extern "C" void crc32c_blocks(const uint8_t* data, size_t block_len,
+                              size_t n, uint32_t* out) {
+  for (size_t i = 0; i < n; i++)
+    out[i] = crc_body(0, data + i * block_len, block_len);
 }

@@ -12,8 +12,9 @@ import sys
 import pytest
 
 from ceph_tpu.tools import bench_sweep, ec_non_regression
+from ceph_tpu.utils import crc as crcmod
 from ceph_tpu.utils.crc import (available_native, crc32c,
-                                _py_crc32c)
+                                crc32c_blocks, _py_crc32c)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CORPUS = os.path.join(REPO, "corpus")
@@ -69,6 +70,71 @@ def test_native_crc_kernel_builds():
     """The image ships g++; the native kernel must actually build
     (the pure-python fallback is for compilerless environments)."""
     assert available_native()
+
+
+def _corpus_chunks():
+    out = []
+    for root, _dirs, files in sorted(os.walk(CORPUS)):
+        out += [os.path.join(root, f) for f in sorted(files)
+                if f.startswith("chunk.")]
+    assert out
+    return out
+
+
+@pytest.mark.parametrize("wrap", [bytearray, memoryview, bytes],
+                         ids=["bytearray", "memoryview", "bytes"])
+@pytest.mark.parametrize("native", [True, False],
+                         ids=["native", "python"])
+def test_crc32c_blocks_equals_crc32c_block_by_block(native, wrap,
+                                                    monkeypatch):
+    """One call over n blocks gives what n calls give, on every chunk
+    of the committed corpus, through the library and through the
+    fallback a host without a compiler takes, whatever buffer the
+    caller holds."""
+    if native:
+        assert available_native()
+    else:
+        monkeypatch.setattr(crcmod, "_lib", None)
+        monkeypatch.setattr(crcmod, "_tried", True)
+    # the fallback runs a byte at a time: a few chunks, small blocks
+    paths = _corpus_chunks() if native else _corpus_chunks()[:3]
+    for block_len in (4096, 512) if native else (512,):
+        for path in paths:
+            with open(path, "rb") as f:
+                blob = f.read()
+            blob = blob[:len(blob) // block_len * block_len]
+            if not native:
+                blob = blob[:8 * block_len]
+            want = [_py_crc32c(blob[i:i + block_len], 0) if not native
+                    else crc32c(blob[i:i + block_len])
+                    for i in range(0, len(blob), block_len)]
+            got = crc32c_blocks(wrap(blob), block_len)
+            assert list(got) == want, (path, block_len)
+    assert crc32c_blocks(wrap(b""), 4096) == []
+    assert crc32c_blocks(wrap(b"123456789"), 9) == [0xE3069283]
+    with pytest.raises(ValueError):
+        crc32c_blocks(wrap(b"x" * 10), 4)
+
+
+def test_crc32c_blocks_hot_path_takes_no_loader_lock(monkeypatch):
+    """Once the library is loaded a call does not pass through the
+    loader's lock, and it leaves no export on the caller's buffer."""
+    assert available_native()
+    buf = bytearray(os.urandom(8 * 4096))
+
+    class _NoLock:
+        def __enter__(self):
+            raise AssertionError("loader lock taken on the hot path")
+
+        def __exit__(self, *a):
+            return False
+    monkeypatch.setattr(crcmod, "_lock", _NoLock())
+    want = crc32c_blocks(buf, 4096)
+    buf[4096] ^= 1
+    got = crc32c_blocks(buf, 4096)
+    assert got[1] != want[1] and got[:1] + got[2:] == want[:1] + want[2:]
+    buf.extend(b"\x00" * 4096)          # BufferError under a live export
+    assert crc32c_blocks(buf, 4096)[:8] == got
 
 
 # -------------------------------------------------------------- sweep

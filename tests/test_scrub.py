@@ -364,3 +364,49 @@ def test_blockstore_bitrot_eio_and_repair(tmp_path):
             raise TimeoutError(f"repair never converged: {stat}")
         assert io.read("victim", len(payload)) == payload
         assert store.read(coll, gobj) == payload
+
+
+def test_rot_outside_a_ranged_read_is_left_to_full_reads_and_deep_scrub():
+    """A block store verifies the blocks a read returns bytes of
+    (reference _verify_csum: the blobs it read).  One rotten block at
+    the far end of a shard: a client read of the first stripe touches
+    neither the block nor the store's error counter; the read of the
+    whole object meets it (EIO, reconstructs from parity, bytes
+    exact); deep scrub, whose read is the full shard, localizes it."""
+    from ceph_tpu.cluster import test_config
+    conf = test_config(osd_objectstore="bluestore")
+    with Cluster(n_osds=3, conf=conf, store_kind="bluestore") as c:
+        for i in range(3):
+            c.wait_for_osd_up(i, 20)
+        c.create_ec_profile("rr", plugin="jerasure", k="2", m="1")
+        c.create_pool("rrp", "erasure", erasure_code_profile="rr")
+        io = c.rados().open_ioctx("rrp")
+        payload = os.urandom(64 << 10)       # 8 blocks a shard
+        io.write_full("far", payload)
+        c.wait_for_clean(20)
+        bad = None
+        for osd_id, store in c.stores.items():
+            for coll in store.list_collections():
+                for obj in store.collection_list(coll):
+                    if obj.oid == "far" and obj.shard == 0:
+                        bad = (store, coll, obj)
+        assert bad is not None
+        store, coll, obj = bad
+        store.flush()
+        with store._lock:
+            phys = store._load_extents(coll, obj).blocks[6]
+            store._dev.seek(phys * 4096 + 99)
+            b = store._dev.read(1)
+            store._dev.seek(phys * 4096 + 99)
+            store._dev.write(bytes([b[0] ^ 0x40]))
+        assert io.read("far", length=8192) == payload[:8192]
+        assert store.usage()["csum_failures"] == 0
+        assert io.read("far") == payload
+        assert store.usage()["csum_failures"] >= 1
+        pgid, _ = pg_stat_of(c, "far", "rrp")
+        ret, rs, _ = c.mon_command({"prefix": "pg deep-scrub",
+                                    "pgid": pgid})
+        assert ret == 0, rs
+        stat = wait_scrub_errors(
+            c, pgid, lambda s: s.get("num_scrub_errors", 0) > 0)
+        assert stat["inconsistent"].get("far") == [0]

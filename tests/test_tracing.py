@@ -482,6 +482,21 @@ def test_sections_of_one_request_share_its_reqid_across_daemons(traced):
                for _, m, _ in seen["batcher.form"])
 
 
+def test_store_read_names_its_blocks_and_its_objects_blocks(traced):
+    """``blocks`` is what the read gathered and verified, ``obj_blocks``
+    what the object has: a shard of a 256 KiB object at k=2 is 32
+    blocks, the sub-chunk overwrite's read-back wants one of them, a
+    client read of the whole object all 32."""
+    reads = [m for _, m, _ in traced["seen"]["store.read"]]
+    assert all({"bytes", "blocks", "obj_blocks"} <= set(m)
+               for m in reads), reads[:3]
+    assert all(0 <= m["blocks"] <= m["obj_blocks"] for m in reads)
+    assert all(m["bytes"] <= m["blocks"] * 4096 for m in reads)
+    shard = [m for m in reads if m["obj_blocks"] == 32]
+    assert any(m["blocks"] == 1 and m["bytes"] == 4096 for m in shard)
+    assert any(m["blocks"] == 32 for m in shard)
+
+
 def test_lock_wait_only_under_contention_with_site_and_holder(traced):
     waits = [m for _, m, _ in traced["seen"]["lock.wait"]]
     sites = {m["site"] for m in waits}
