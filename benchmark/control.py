@@ -60,8 +60,9 @@ def main(argv=None) -> int:
 if __name__ == "__main__":
     try:
         code = main()
-    except SystemExit:
-        raise
+    except SystemExit as e:
+        import run
+        code = run.refusal_code(e)
     except BaseException:
         import traceback
         traceback.print_exc()

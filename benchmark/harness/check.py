@@ -2,8 +2,9 @@
 
 Every number compared is a count of answers that differ from the plain
 reference, so every limit is 0.  The reference is the seeded byte model
-(``seeded.py``: what a read must return) and the numpy Reed-Solomon
-encoder (``reference.py``: what the k+m shards of an object must be),
+(``seeded.py``: what a read must return) and the numpy encoder of the
+configuration's erasure code (``references/<code>.py``, found by
+``spec.reference_name``: what the k+m shards of an object must be),
 neither of which shares code with ceph_tpu.  It is run on what the
 timed window itself wrote and read, once the window has closed, the
 load has drained and the device's peak memory has been read.
@@ -12,7 +13,7 @@ from collections import deque
 
 import numpy as np
 
-from . import reference
+from . import spec
 from .loadgen import op_ok
 
 READBACK_DEPTH = 16
@@ -105,11 +106,15 @@ def stored_shards(dep, model, sample):
     """-> (shards in the live stores that differ from the reference
     encoder's, shards that are in no live store)."""
     k, m, su = dep.k, dep.m, dep.stripe_unit
-    matrix = reference.vandermonde_coding_matrix(k, m)
+    encoder = spec.reference(spec.reference_name(dep.config))
+    profile = dep.config["pool"]["profile"]
     index = dep.shard_index([model.name(n) for n in sample])
     wrong = missing = 0
     for n in sample:
-        want = reference.shards_of(model.current(n), k, m, su, matrix)
+        obj = model.current(n)
+        # the last stripe of an object is stored padded with zeros
+        obj += bytes(-len(obj) % (k * su))
+        want = encoder.shards_of(obj, profile, su)
         found = 0
         for s in range(k + m):
             copies = index.get((model.name(n), s), [])

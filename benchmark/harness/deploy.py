@@ -8,8 +8,23 @@ deployments comes from ``configs/<name>.json``.
 import threading
 import time
 
+from . import spec
+
 PREWARM_THREAD = "ec-prewarm"       # osd/batcher.py names it so
 LANES = ("encode", "decode", "delta")
+
+
+def chain_columns(key: tuple, layout: dict) -> int:
+    """The chunks one call of a compiled chain takes in, read from its
+    cache key as ``chain_keys.json`` says for the key's prefix."""
+    def at(path):
+        v = key
+        for i in path:
+            v = v[i]
+        return v
+    n = at(layout["columns"])
+    n = n if isinstance(n, int) else len(n)
+    return n // at(layout["per"]) if "per" in layout else n
 
 
 class Deployment:
@@ -52,8 +67,24 @@ class Deployment:
                 raise RuntimeError(f"allow_ec_overwrites: {ret} {rs}")
         self.io = self.rad.open_ioctx(pool["name"])
         self.wait_maps()
+        self.check_stripe_width()
         self._poll("every PG active+clean", {"prefix": "health"},
                    lambda out: out.get("all_clean"), 180.0)
+
+    def check_stripe_width(self) -> None:
+        """The deployment is what the file states: the pool the program
+        made has the file's stripe unit, by the map every daemon holds
+        (the mon derives it from the profile and the code's alignment,
+        not from this file)."""
+        made = self.rad.objecter.osdmap.get_pool(
+            self.config["pool"]["name"]).stripe_width
+        stated = self.k * self.stripe_unit
+        if made != stated:
+            raise SystemExit(
+                f"benchmark: the pool's stripe_width is {made} bytes, "
+                f"and {self.config['name']} states k * stripe_unit = "
+                f"{self.k} * {self.stripe_unit} = {stated}: the "
+                f"deployment is not the one the file describes")
 
     def wait_maps(self, timeout: float = 120.0) -> None:
         """Every live daemon and the client hold the mon's newest map
@@ -81,10 +112,11 @@ class Deployment:
                     raise TimeoutError("ec-prewarm still compiling")
 
     def warm_cached_programs(self, traffic: dict) -> int:
-        """Run every GF program the warm-up load has built at every
-        batch size the batcher can form from this traffic, so that no
-        (erasure signature, batch bucket) pair meets its first call
-        inside the window; -> calls made.
+        """Run every GF program the warm-up load has built (the chains
+        whose key prefix ``chain_keys.json`` lists, byte-domain and
+        packet layout alike) at every batch size the batcher can form from
+        this traffic, so that no (erasure signature, batch bucket) pair
+        meets its first call inside the window; -> calls made.
 
         The batcher pads a group of n coalesced requests to the next
         power of two of its stripes, and jit compiles per padded shape;
@@ -96,20 +128,21 @@ class Deployment:
         import jax.numpy as jnp
         from ceph_tpu.ec.plugins.tpu import shared_backend
         from ceph_tpu.ops.jax_engine import _bucket_batch
+        layouts = spec.chain_keys()
         lru = getattr(shared_backend(), "_chain_lru", None)
         with lru._lock:
             chains = [(key, fn) for key, fn in lru._d.items()
-                      if key and key[0] in ("gf8", "gf8don")]
+                      if key and key[0] in layouts]
         width = self.k * self.stripe_unit
         buckets = set()
         for op in traffic["ops"]:
-            stripes = max(1, op["io_bytes"] // width)
+            stripes = max(1, -(-op["io_bytes"] // width))
             most = max(1, min(int(traffic["depth"]), 1024 // stripes))
             buckets |= {_bucket_batch(n * stripes)
                         for n in range(1, most + 1)}
         calls = 0
         for key, fn in chains:
-            cols = len(key[1][0])
+            cols = chain_columns(key, layouts[key[0]])
             for nb in sorted(buckets):
                 fn(jnp.zeros((nb, cols, self.stripe_unit),
                              dtype=jnp.uint8)).block_until_ready()

@@ -8,41 +8,62 @@ added); each returns the call that takes its patch out again.
 import numpy as np
 
 
+def _one_bit_off(fn, bit: int):
+    """The compiled program ``fn`` with one bit of its output flipped."""
+    def altered(x):
+        out = fn(x)
+        first = (0,) * out.ndim
+        return out.at[first].set(out[first] ^ bit)
+    return altered
+
+
+def _one_bit_off_host(out, bit: int):
+    """A lane's output on the host with one bit flipped."""
+    if isinstance(out, np.ndarray) and out.dtype == np.uint8 \
+            and out.ndim >= 3 and out.size:
+        out = np.array(out)
+        out.reshape(-1)[0] ^= bit
+    return out
+
+
 def _alter_kernel_output():
     """Every GF dispatch returns one flipped bit: stored parity and
-    reconstructed chunks are no longer the code's.  Planted at the two
-    places the lanes' outputs pass: the compiled kernel ``gf8_fn``
-    hands out (every w=8 dispatch on a TPU, the synchronous decode
-    path included) and ``AsyncBatch.wait`` (the async lanes, which is
-    all there is on a CPU).  Different bits, so that the two never
-    cancel."""
+    reconstructed chunks are no longer the code's.  Planted at the
+    places the lanes' outputs pass: the compiled kernels that
+    ``gf8_fn`` (every byte-domain w=8 dispatch on a TPU, the
+    synchronous decode path included) and ``packet_chain_fn`` (every
+    synchronous dispatch of a packet-layout code on a TPU) hand out,
+    ``apply_packet_chunks`` (the same off a TPU, where no chain is
+    compiled) and ``AsyncBatch.wait`` (the async lanes).  Different
+    bits, so that no two cancel."""
     from ceph_tpu.ops import jax_engine
     orig_wait = jax_engine.AsyncBatch.wait
     orig_fn = jax_engine.JaxBackend.gf8_fn
+    orig_pkt = jax_engine.JaxBackend.packet_chain_fn
+    orig_chunks = jax_engine.JaxBackend.apply_packet_chunks
 
     def wait(self):
-        out = orig_wait(self)
-        if isinstance(out, np.ndarray) and out.dtype == np.uint8 \
-                and out.ndim >= 3 and out.size:
-            out = np.array(out)
-            out.reshape(-1)[0] ^= 2
-        return out
+        return _one_bit_off_host(orig_wait(self), 2)
+
+    def apply_packet_chunks(self, *args, **kwargs):
+        return _one_bit_off_host(orig_chunks(self, *args, **kwargs), 8)
 
     def gf8_fn(self, *args, **kwargs):
-        fn = orig_fn(self, *args, **kwargs)
+        return _one_bit_off(orig_fn(self, *args, **kwargs), 1)
 
-        def altered(x):
-            out = fn(x)
-            first = (0,) * out.ndim
-            return out.at[first].set(out[first] ^ 1)
-        return altered
+    def packet_chain_fn(self, *args, **kwargs):
+        return _one_bit_off(orig_pkt(self, *args, **kwargs), 4)
 
     jax_engine.AsyncBatch.wait = wait
     jax_engine.JaxBackend.gf8_fn = gf8_fn
+    jax_engine.JaxBackend.packet_chain_fn = packet_chain_fn
+    jax_engine.JaxBackend.apply_packet_chunks = apply_packet_chunks
 
     def undo():
         jax_engine.AsyncBatch.wait = orig_wait
         jax_engine.JaxBackend.gf8_fn = orig_fn
+        jax_engine.JaxBackend.packet_chain_fn = orig_pkt
+        jax_engine.JaxBackend.apply_packet_chunks = orig_chunks
     return undo
 
 

@@ -9,10 +9,16 @@ found by the name BENCHMARK.json gives it:
     cells/<workload>.json    optional: state a cell sets up beyond its
                              configuration and traffic (an OSD lost)
     metrics/<metric>.py      one reader: SOURCE, LAYER, MOVES, read(ctx)
+    references/<code>.py     the plain reference of one erasure code:
+                             shards_of(obj, profile, stripe_unit)
     kernels/<family>.json    XLA module-name patterns of one program
                              family, and whether it is GF work
+    chain_keys.json          the cache-key prefixes of the compiled GF
+                             chains set-up warms, each with where its
+                             key states the chunks one call takes in
     peaks.json               published peaks by device_kind
 """
+import functools
 import importlib.util
 import json
 import os
@@ -62,23 +68,54 @@ class Cell:
         return [m for m in self.bench["per_layer"] if self._listed(m)]
 
 
+def _module(kind: str, path: str):
+    name = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str):
     """The module ``metrics/<name>.py``."""
     path = os.path.join(BENCH_DIR, "metrics", name + ".py")
     if not os.path.exists(path):
         raise SystemExit(f"per-layer metric {name!r} has no reader "
                          f"at {path}")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _module("metric", path)
+
+
+def reference_name(config: dict) -> str:
+    """A configuration's plain reference is ``<technique>_w<w>`` of its
+    pool's profile."""
+    profile = config["pool"]["profile"]
+    return f"{profile['technique']}_w{profile['w']}"
+
+
+@functools.lru_cache(maxsize=None)
+def reference(name: str):
+    """The module ``references/<name>.py``."""
+    d = os.path.join(BENCH_DIR, "references")
+    path = os.path.join(d, name + ".py")
+    if not os.path.exists(path):
+        have = sorted(f[:-3] for f in os.listdir(d) if f.endswith(".py"))
+        raise SystemExit(
+            f"erasure code reference {name!r} is not in "
+            f"benchmark/references (has {have}); add its plain "
+            f"reference there, there is no default")
+    return _module("reference", path)
 
 
 def kernel_families() -> list:
     d = os.path.join(BENCH_DIR, "kernels")
     return [_load(os.path.join(d, f)) for f in sorted(os.listdir(d))
             if f.endswith(".json")]
+
+
+def chain_keys() -> dict:
+    """{cache-key prefix: where the key states its columns}."""
+    return _load(os.path.join(BENCH_DIR, "chain_keys.json"))["prefixes"]
 
 
 def peaks(device_kind: str) -> dict:

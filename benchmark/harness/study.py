@@ -50,12 +50,17 @@ def ack_study(window) -> dict:
     acks = sorted(r[1] for r in window)
     if len(acks) < 2:
         return {"acks": len(acks)}
-    lat = statistics.median(r[1] - r[0] for r in window)
+    lats = sorted(r[1] - r[0] for r in window)
+    lat = statistics.median(lats)
     gaps = [b - a for a, b in zip(acks, acks[1:])]
     waves = 1 + sum(1 for g in gaps if g > lat / 4)
+    # the tail at several depths, for whoever next has to choose a
+    # tail statistic that a bound can hold (PERF.md, section 7)
+    tail = {f"p{q}": lats[min(len(lats) - 1, len(lats) * q // 100)]
+            for q in (75, 90, 95, 99)}
     return {"acks": len(acks), "waves": waves,
             "largest_ack_gap_s": max(gaps),
-            "median_latency_s": lat}
+            "median_latency_s": lat, "latency_s": tail}
 
 
 def ack_bins(window, t0: float, seconds: float, width: float = 0.5) -> list:

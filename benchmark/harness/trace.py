@@ -16,6 +16,7 @@ import shutil
 
 WINDOW_SPAN = "bench.window"
 CLIENT_SPANS = ("client.wait", "client.submit")
+UNSPANNED = "unspanned"
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -230,16 +231,14 @@ def _merged(intervals) -> list:
 
 def attribute_gaps(gaps: list, host: list) -> list:
     """Each gap goes, whole, to the shortest host span that covers its
-    middle; a span of the load generator's own (a caller waiting for
-    its reply) counts only where nothing else does.  -> the ten names
-    with the most idle seconds, [[name, seconds], ...]."""
+    middle.  A span of the load generator's own (a caller waiting for
+    its reply) explains nothing: a gap that only such a span covers,
+    or none, is ``unspanned``.  -> the ten names with the most idle
+    seconds, [[name, seconds], ...]."""
     other = sorted(h for h in host if h[2] not in CLIENT_SPANS)
     starts = [h[0] for h in other]
     # the longest span bounds how far back a covering span can start
     longest = max((e - s for s, e, _, _ in other), default=0.0)
-    waiting = _merged((s, e) for s, e, name, _ in host
-                      if name in CLIENT_SPANS)
-    wait_starts = [w[0] for w in waiting]
     out = {}
     for gs, ge in gaps:
         mid = (gs + ge) / 2
@@ -249,14 +248,7 @@ def attribute_gaps(gaps: list, host: list) -> list:
         for s, e, name, lname in other[lo:hi]:
             if e >= mid and (best is None or e - s < best[0]):
                 best = (e - s, name)
-        if best is not None:
-            key = best[1]
-        else:
-            i = bisect.bisect_right(wait_starts, mid) - 1
-            if i >= 0 and waiting[i][1] >= mid:
-                key = "client.wait (no other host span)"
-            else:
-                key = "unattributed"
+        key = best[1] if best is not None else UNSPANNED
         out[key] = out.get(key, 0.0) + (ge - gs) / 1e9
     return [[k, v] for k, v in
             sorted(out.items(), key=lambda kv: -kv[1])[:10]]

@@ -1,4 +1,5 @@
-"""The plain reference: what a k+m reed_sol_van w=8 pool must hold.
+"""The plain reference of ``technique=reed_sol_van w=8``: what a k+m
+pool on that code must hold.  (``harness/reference.py`` until PR 29.)
 
 Straight numpy, written from the public description (Plank, "A
 Tutorial on Reed-Solomon Coding for Fault-Tolerance in RAID-like
@@ -14,6 +15,8 @@ Imports nothing of ceph_tpu and takes nothing it made: no matrix, no
 table.  ``stripe_unit`` bytes of shard i in stripe s are object bytes
 ``[s*k*su + i*su, s*k*su + (i+1)*su)``.
 """
+import functools
+
 import numpy as np
 
 PRIM_POLY = 0x11D
@@ -61,6 +64,7 @@ def _mul_table() -> np.ndarray:
 MUL = _mul_table()
 
 
+@functools.lru_cache(maxsize=8)
 def vandermonde_coding_matrix(k: int, m: int) -> np.ndarray:
     """The systematic Vandermonde code's coding rows -> [m, k]."""
     rows, cols = k + m, k
@@ -108,8 +112,8 @@ def vandermonde_coding_matrix(k: int, m: int) -> np.ndarray:
     return np.array(d[cols:], dtype=np.uint8)
 
 
-def shards_of(obj: bytes, k: int, m: int, stripe_unit: int,
-              matrix: np.ndarray = None) -> list:
+def stripe_shards(obj: bytes, k: int, m: int, stripe_unit: int,
+                  matrix: np.ndarray = None) -> list:
     """All k+m shard byte strings of one object whose length is a
     whole number of stripes."""
     width = k * stripe_unit
@@ -131,3 +135,15 @@ def shards_of(obj: bytes, k: int, m: int, stripe_unit: int,
                 acc ^= MUL[c][data[i]]
         out.append(acc.tobytes())
     return out
+
+
+def shards_of(obj: bytes, profile: dict, stripe_unit: int) -> list:
+    """The entry point every module of ``references/`` has: the k+m
+    shard byte strings of an object that is a whole number of stripes,
+    for the pool profile of a configuration file."""
+    if profile.get("technique") != "reed_sol_van" or \
+            int(profile.get("w", 8)) != 8:
+        raise ValueError(f"reed_sol_van_w8 does not serve the profile "
+                         f"{profile}")
+    return stripe_shards(obj, int(profile["k"]), int(profile["m"]),
+                         stripe_unit)

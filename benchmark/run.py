@@ -175,7 +175,7 @@ def cut_window(dep, gen, seconds: float, compiles, settle: float,
                trace_dir) -> dict:
     """Cut [t0, t0+seconds) out of the running load; -> what was read
     at its edges.  Between the two edges the harness only sleeps."""
-    from harness import hostprof, study, trace
+    from harness import study, trace
     # the harness's own process settings (PERF.md, section 2): the
     # daemons' long-lived heaps leave the cyclic collector's sight
     gc.collect()
@@ -183,7 +183,6 @@ def cut_window(dep, gen, seconds: float, compiles, settle: float,
     w = {"gc": study.GcClock(), "snap_a": dep.snapshot()}
     if trace_dir:
         trace.start(trace_dir)
-        w["prof_a"] = hostprof.snapshot()
     # collecting, dumping and starting the profiler stalled every
     # thread for a moment: let the pipeline run level again
     time.sleep(settle)
@@ -201,7 +200,6 @@ def cut_window(dep, gen, seconds: float, compiles, settle: float,
     w["lowered_b"] = compiles.lowered
     # closed; the load runs on, and drains untimed
     if trace_dir:
-        w["prof_b"] = hostprof.snapshot()
         trace.stop()
     w["snap_b"] = dep.snapshot()
     time.sleep(0.25)
@@ -209,19 +207,17 @@ def cut_window(dep, gen, seconds: float, compiles, settle: float,
     return w
 
 
-def read_trace(args, w: dict, trace_dir: str, device: dict):
+def read_trace(args, trace_dir: str, device: dict):
     """-> (the reduced trace, the result's ``breakdown``)."""
-    from harness import hostprof, spec, trace
+    from harness import spec, trace
     planes = trace.load(trace_dir)
     if args.record_trace:
         trace.record(planes, args.record_trace, 1.5)
     red = trace.reduce(planes, spec.kernel_families())
     device["busy_s"] = red["busy_s"]
     device["window_s"] = red["window_s"]
-    shares = hostprof.busy_shares(w["prof_a"], w["prof_b"])
     return red, {"device_ops": red["device_ops"],
-                 "idle_gaps": hostprof.split_unspanned(
-                     red["idle_gaps"], shares)}
+                 "idle_gaps": red["idle_gaps"]}
 
 
 def read_metrics(cell, args, ctx: dict) -> dict:
@@ -238,13 +234,15 @@ def read_metrics(cell, args, ctx: dict) -> dict:
     return metrics
 
 
-def run_cell(args, plant=None) -> dict:
+def run_cell(args, plant=None, bench=None) -> dict:
     """One run; -> the result object.  ``plant(dep)`` is the control's
     and the fault tests' hook: it breaks the timed path once the set
-    is populated, before the load starts."""
+    is populated, before the load starts.  ``bench`` is a benchmark
+    description to take the cell from in place of BENCHMARK.json (a
+    test's rehearsal of a deployment that has no cell yet)."""
     from harness import check, deploy, loadgen, seeded, spec, study
     phases = Phases()
-    cell = spec.Cell(args.workload)
+    cell = spec.Cell(args.workload, bench=bench)
     if args.rehearsal:
         shrink_for_rehearsal(cell)
     seconds = float(args.seconds)
@@ -297,7 +295,7 @@ def run_cell(args, plant=None) -> dict:
            "trace": None}
     breakdown = None
     if args.trace and not args.rehearsal:
-        ctx["trace"], breakdown = read_trace(args, w, trace_dir, device)
+        ctx["trace"], breakdown = read_trace(args, trace_dir, device)
     result = {"correct": check.correct(numbers) and not gen.errors,
               "attempted": len(window),
               "failed": sum(1 for r in window
@@ -360,6 +358,16 @@ def parse(argv=None):
     return ap.parse_args(argv)
 
 
+def refusal_code(e: SystemExit) -> int:
+    """A refusal (no chip, an unknown name, a pool that is not the
+    file's) prints its message and no result line, and its code is not
+    0; the cluster's threads, stopped or not, do not hold the exit."""
+    if isinstance(e.code, int) or e.code is None:
+        return e.code or 0
+    log(str(e.code))
+    return 1
+
+
 def main(argv=None) -> int:
     args = parse(argv)
     if args.rehearsal:
@@ -372,8 +380,8 @@ def main(argv=None) -> int:
 if __name__ == "__main__":
     try:
         code = main()
-    except SystemExit:
-        raise
+    except SystemExit as e:
+        code = refusal_code(e)
     except BaseException:
         import traceback
         traceback.print_exc()

@@ -15,6 +15,7 @@ counterpart in a one-chip object store.  On the chip the same control
 is run at the cell's own size by ``benchmark/control.py``.
 """
 import argparse
+import copy
 import os
 import sys
 
@@ -28,20 +29,37 @@ import run  # noqa: E402
 from harness import faults, spec  # noqa: E402
 
 
+def bench_with_kept_mix() -> dict:
+    """BENCHMARK.json and, in memory only, the cell PR 29 took out as
+    too noisy to hold to a bound (PERF.md, sections 2 and 7).  Its mix
+    ``traffic/fio_randwrite_4k_qd32.json`` is kept for its return, and
+    is the only traffic on the parity-delta lane and on the generator's
+    overwrite class, so the harness's side of both stays driven."""
+    bench = copy.deepcopy(spec.benchmark())
+    bench["workloads"].append({
+        "name": "k4m2.randwrite_4k", "config": "ec_k4m2_7osd",
+        "traffic": "fio_randwrite_4k_qd32", "chips": 1})
+    for metric in bench["per_layer"]:
+        if "k8m4.write_4m" in metric.get("workloads", []):
+            metric["workloads"].append("k4m2.randwrite_4k")
+    return bench
+
+
+BENCH = bench_with_kept_mix()
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
 def drive(workload: str, fault=None, seed: int = 2147483659) -> dict:
     args = argparse.Namespace(workload=workload, seed=seed, seconds=2.0,
                               trace=0, rehearsal=True, record_trace=None)
     plant = faults.Planter(fault) if fault else None
     try:
-        result = run.run_cell(args, plant=plant)
+        result = run.run_cell(args, plant=plant, bench=BENCH)
     finally:
         if plant:
             plant.undo()
     result.pop("_info")
     return result
-
-
-CELLS = [w["name"] for w in spec.benchmark()["workloads"]]
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -55,7 +73,7 @@ def test_sound_run_is_correct(workload):
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_control_fault_is_caught(workload):
-    fault = faults.control_for(spec.Cell(workload))
+    fault = faults.control_for(spec.Cell(workload, bench=BENCH))
     result = drive(workload, fault)
     assert not result["correct"], (fault, result["compared"])
     assert any(v["value"] > v["limit"] for v in result["compared"].values())
