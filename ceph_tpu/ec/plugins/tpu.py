@@ -8,7 +8,12 @@ MXU (ceph_tpu/ops/jax_engine.py), bit-exact with the CPU `jerasure`
 plugin because both build identical coding matrices.
 
 All seven jerasure-compatible techniques are supported; every one reduces
-to a binary matrix, so they all ride the same TPU kernel.  On hosts
+to a binary matrix.  On a TPU the byte-layout w=8 codes (reed_sol_van,
+reed_sol_r6_op) ride the fused bit-plane kernel ``gf8_mxu_pallas``, the
+packet-layout codes (cauchy_orig, cauchy_good, liberation, blaum_roth,
+liber8tion) the fused packet kernel ``packet_mxu_pallas``, and both go
+through the OSD batcher's three lanes (encode, decode, delta) by the
+same staged asynchronous dispatch.  On hosts
 without a TPU (e.g. the monitor validating a profile, reference
 mon/OSDMonitor.cc:7371-7392) the same code runs on JAX's CPU backend
 with the XLA kernels — same results.  Which kernel serves is decided
@@ -125,19 +130,31 @@ class TpuCodecMixin:
         data = np.asarray(data, dtype=np.uint8)
         if data.ndim != 3 or data.shape[1] != self.k:
             raise ValueError(f"expected [batch, k={self.k}, L] input")
-        if self.core.gf8_encode_fast():
-            return self.core.backend.apply_gf8_matrix_async(
-                self.core.coding_matrix, data)
-        return self.core.backend.apply_bitmatrix_bytes_async(
-            self.core.bitmatrix, data, self.w)
+        return self._encode_async(data)
+
+    def _encode_async(self, block: np.ndarray):
+        """The pool's one encode program over a [B, k, L] block, by
+        the code's layout: what encode and delta dispatch."""
+        core = self.core
+        if core.layout == "packet":
+            return core.backend.apply_packet_async(
+                core.bitmatrix, block, core.w, core.packetsize)
+        if core.gf8_encode_fast():
+            return core.backend.apply_gf8_rows_async(
+                core.coding_matrix, block)
+        return core.backend.apply_bitmatrix_bytes_async(
+            core.bitmatrix, block, self.w)
 
     def decode_async_supported(self) -> bool:
         """True when this geometry can ride the async device decode
-        pipeline (combined recovery rows need a GF coding matrix;
-        the async staging path is byte-domain w=8)."""
+        pipeline: byte-domain w=8 with a GF coding matrix (combined
+        recovery rows through the GF kernel), or packet layout
+        (combined recovery rows in the bit domain through the packet
+        kernel)."""
         core = self.core
-        return (core.layout == "byte" and core.w == 8
-                and core.coding_matrix is not None)
+        return core.layout == "packet" or (
+            core.layout == "byte" and core.w == 8
+            and core.coding_matrix is not None)
 
     def decode_batch_async(self, present: Mapping[int, np.ndarray],
                            chunk_len: int) -> _DecodeHandle:
@@ -151,7 +168,7 @@ class TpuCodecMixin:
         the decode twin of encode_batch_async."""
         if not self.decode_async_supported():
             raise ValueError("async device decode needs a byte-domain "
-                             "w=8 GF coding matrix")
+                             "w=8 GF coding matrix or a packet layout")
         core = self.core
         n = self.k + self.m
         avail = sorted(i for i in present if i < n)
@@ -160,17 +177,20 @@ class TpuCodecMixin:
                 f"need {self.k} chunks, have {len(avail)}")
         erased = tuple(i for i in range(n) if i not in present)
         chosen = tuple(avail[:self.k])
-        rows_gf, _ = core._recovery_rows(chosen, erased)
+        rows_gf, rows_bits = core._recovery_rows(chosen, erased)
         stack = np.stack(
             [np.asarray(present[i], dtype=np.uint8)
              .reshape(-1, int(chunk_len)) for i in chosen], axis=1)
-        return _DecodeHandle(
-            core.backend.apply_gf8_rows_async(rows_gf, stack), erased)
+        if core.layout == "packet":
+            ab = core.backend.apply_packet_async(
+                rows_bits, stack, core.w, core.packetsize)
+        else:
+            ab = core.backend.apply_gf8_rows_async(rows_gf, stack)
+        return _DecodeHandle(ab, erased)
 
     def delta_async_supported(self) -> bool:
         """True when this geometry can ride the async device
-        parity-delta pipeline (same gate as device decode: byte-domain
-        w=8 with a GF coding matrix in hand)."""
+        parity-delta pipeline (same gate as device decode)."""
         return self.decode_async_supported()
 
     def delta_encode_batch_async(self, delta: np.ndarray, dirty_cols):
@@ -182,7 +202,9 @@ class TpuCodecMixin:
         The dirty columns are scattered into a zero [B, k, L] block
         and dispatched through the SAME per-pool compiled encode
         kernel as encode_batch_async — GF linearity makes the zero
-        columns inert, so M·pad(Δ) == M[:, dirty]·Δ bit for bit.  A
+        columns inert, so M·pad(Δ) == M[:, dirty]·Δ bit for bit (a
+        packet code is linear over GF(2) region by region, and a
+        chunk is a whole number of regions).  A
         per-dirty-signature kernel (M[:, dirty] baked into its own
         jit) would be cheaper per byte moved, but every fresh
         (signature, shape-bucket) pair pays a multi-second XLA
@@ -194,8 +216,7 @@ class TpuCodecMixin:
         already hot."""
         if not self.delta_async_supported():
             raise ValueError("async device delta needs a byte-domain "
-                             "w=8 GF coding matrix")
-        core = self.core
+                             "w=8 GF coding matrix or a packet layout")
         cols = [int(c) for c in dirty_cols]
         delta = np.asarray(delta, dtype=np.uint8)
         if delta.ndim != 3 or delta.shape[1] != len(cols):
@@ -204,8 +225,7 @@ class TpuCodecMixin:
         block = np.zeros((delta.shape[0], self.k, delta.shape[2]),
                          dtype=np.uint8)
         block[:, cols, :] = delta
-        return core.backend.apply_gf8_matrix_async(
-            core.coding_matrix, block)
+        return self._encode_async(block)
 
     def delta_encode_batch(self, delta: np.ndarray,
                            dirty_cols) -> np.ndarray:
@@ -213,6 +233,17 @@ class TpuCodecMixin:
         Δdata [B, D, L] -> Δparity [B, m, L] via CodecCore."""
         return self.core.delta_parity(
             np.asarray(delta, dtype=np.uint8), dirty_cols)
+
+    def _geometry(self, chunk_size: int) -> tuple:
+        """What one compiled program serves: the _PREWARMED_SHAPES key."""
+        return (type(self).__name__, self.k, self.m, self.w,
+                self.core.packetsize, int(chunk_size))
+
+    def _prewarm_rings(self, chunk_size: int, batches) -> None:
+        """The backend's staging rings for this geometry's shapes."""
+        self.core.backend.prewarm_geometry(
+            self.k, chunk_size, batches=batches, w=self.w,
+            packetsize=self.core.packetsize)
 
     def prewarm_delta(self, chunk_size: int, dirty_cols=None,
                       batches=(1,)) -> None:
@@ -227,11 +258,8 @@ class TpuCodecMixin:
         EncodeBatcher.note_prewarm_error)."""
         if not self.delta_async_supported():
             return
-        pre = getattr(self.core.backend, "prewarm_geometry", None)
-        if pre is not None:
-            pre(self.k, chunk_size, batches=batches, w=self.w)
-        key = ("delta", type(self).__name__, self.k, self.m, self.w,
-               int(chunk_size))
+        self._prewarm_rings(chunk_size, batches)
+        key = ("delta",) + self._geometry(chunk_size)
         if key in _PREWARMED_SHAPES:
             return
         z = np.zeros((1, 1, int(chunk_size)), dtype=np.uint8)
@@ -253,11 +281,8 @@ class TpuCodecMixin:
         for e in range(n):
             chosen = tuple(i for i in range(n) if i != e)[:self.k]
             core._recovery_rows(chosen, (e,))
-        pre = getattr(core.backend, "prewarm_geometry", None)
-        if pre is not None:
-            pre(self.k, chunk_size, batches=batches, w=self.w)
-        key = ("dec", type(self).__name__, self.k, self.m, self.w,
-               int(chunk_size))
+        self._prewarm_rings(chunk_size, batches)
+        key = ("dec",) + self._geometry(chunk_size)
         if key in _PREWARMED_SHAPES:
             return
         z = {i: np.zeros((1, int(chunk_size)), dtype=np.uint8)
@@ -274,13 +299,9 @@ class TpuCodecMixin:
         shape through the real async path.  Idempotent per
         (geometry, shape) process-wide; synchronous — callers (PG
         activation) run it on a background thread."""
-        backend = self.core.backend
-        pre = getattr(backend, "prewarm_geometry", None)
-        if pre is not None:
-            pre(self.k, chunk_size, batches=batches, w=self.w)
+        self._prewarm_rings(chunk_size, batches)
         for nb in batches:
-            key = (type(self).__name__, self.k, self.m, self.w,
-                   int(chunk_size), int(nb))
+            key = self._geometry(chunk_size) + (int(nb),)
             if key in _PREWARMED_SHAPES:
                 continue
             z = np.zeros((max(1, int(nb)), self.k, int(chunk_size)),

@@ -243,33 +243,39 @@ class CodecCore:
 
     def delta_parity(self, delta: np.ndarray,
                      dirty_cols) -> np.ndarray:
-        """Parity delta for a partial-stripe overwrite: GF(2^w)
-        linearity gives ``new_parity = old_parity XOR M[:,dirty]·Δdata``
+        """Parity delta for a partial-stripe overwrite: linearity
+        gives ``new_parity = old_parity XOR M[:,dirty]·Δdata``
         (Δdata = old XOR new), so only the dirty data columns ride the
         matmul.  delta uint8 [..., D, L] for D = len(dirty_cols) ->
-        Δparity uint8 [..., m, L].  Byte-domain GF-matrix geometries
-        only — the same eligibility gate as device decode."""
-        if self.layout != "byte" or self.coding_matrix is None:
-            raise ValueError("delta parity needs a byte-domain GF "
-                             "coding matrix")
+        Δparity uint8 [..., m, L].  A packet-layout code is linear
+        over GF(2) region by region, so the same holds for whole
+        chunks of whole regions (w*packetsize bytes)."""
         cols = list(dirty_cols)
         if delta.shape[-2] != len(cols):
             raise ValueError(f"expected {len(cols)} dirty columns")
         if delta.shape[-1] == 0:
             return np.zeros(delta.shape[:-2] + (self.m, 0),
                             dtype=np.uint8)
-        if self.gf8_encode_fast():
+        if self.gf8_encode_fast() or self.packet_static_fast():
             # compiled backends: scatter Δ into a zero [..., k, L]
             # block and reuse the per-pool encode kernel (zero
-            # columns are GF-inert) — a per-dirty-signature kernel
+            # columns are inert) — a per-dirty-signature kernel
             # would pay a fresh XLA compile for every (signature,
             # shape) pair the overwrite mix sprays at it
             block = np.zeros(
                 delta.shape[:-2] + (self.k, delta.shape[-1]),
                 dtype=np.uint8)
             block[..., cols, :] = delta
-            return self.backend.apply_gf8_matrix(self.coding_matrix,
-                                                 block)
+            return self.encode_batch(block)
+        if self.layout == "packet":
+            bitcols = [c * self.w + j for c in cols
+                       for j in range(self.w)]
+            return self._apply(
+                np.ascontiguousarray(self.bitmatrix[:, bitcols]), None,
+                delta)
+        if self.coding_matrix is None:
+            raise ValueError("delta parity needs a GF coding matrix "
+                             "or a packet-layout bit-matrix")
         sub = np.ascontiguousarray(self.coding_matrix[:, cols])
         return self._apply(matrix_to_bitmatrix(sub, self.w), sub,
                            delta)
@@ -328,75 +334,54 @@ class CodecCore:
         if chunk_len == 0:           # empty object: all chunks empty
             shape = next(iter(present.values())).shape
             return {e: np.zeros(shape, dtype=np.uint8) for e in erased}
-        chosen = avail[:self.k]
-        out: dict[int, np.ndarray] = {}
-        if self.coding_matrix is not None:
-            # combined recovery rows: ONE matrix maps the chosen k
-            # survivors straight to every erased chunk (data AND
-            # parity), so the whole reconstruction is a single apply
-            # — one device dispatch per batch instead of a decode
-            # apply chained into a re-encode apply
-            rows_gf, rows_bits = self._recovery_rows(tuple(chosen),
-                                                     tuple(erased))
-            stack = np.stack([present[i] for i in chosen], axis=-2)
-            if self.gf8_decode_fast():
-                dec = self.backend.apply_gf8_rows(rows_gf, stack)
-            else:
-                dec = self._apply(rows_bits, rows_gf, stack)
-            for idx, e in enumerate(erased):
-                out[e] = dec[..., idx, :]
-            return out
-        data_erased = [e for e in erased if e < self.k]
-        if data_erased:
-            rows_gf, rows_bits = self._decode_rows(tuple(chosen),
-                                                   tuple(data_erased))
-            stack = np.stack([present[i] for i in chosen], axis=-2)
-            if rows_gf is not None and self.gf8_decode_fast():
-                dec = self.backend.apply_gf8_rows(rows_gf, stack)
-            else:
-                dec = self._apply(rows_bits, rows_gf, stack)
-            for idx, e in enumerate(data_erased):
-                out[e] = dec[..., idx, :]
-        coding_erased = [e for e in erased if e >= self.k]
-        if coding_erased:
-            full = np.stack(
-                [present[i] if i in present else out[i]
-                 for i in range(self.k)], axis=-2)
-            enc_rows_bits = np.concatenate(
-                [self.bitmatrix[(e - self.k) * self.w:(e - self.k + 1) * self.w]
-                 for e in coding_erased], axis=0)
-            enc_rows_gf = None if self.coding_matrix is None else \
-                self.coding_matrix[[e - self.k for e in coding_erased]]
-            if enc_rows_gf is not None and self.gf8_decode_fast():
-                enc = self.backend.apply_gf8_rows(enc_rows_gf, full)
-            else:
-                enc = self._apply(enc_rows_bits, enc_rows_gf, full)
-            for idx, e in enumerate(coding_erased):
-                out[e] = enc[..., idx, :]
-        return out
+        # combined recovery rows: ONE matrix maps the chosen k
+        # survivors straight to every erased chunk (data AND parity),
+        # so the whole reconstruction is a single apply — one device
+        # dispatch per batch instead of a decode apply chained into a
+        # re-encode apply
+        chosen = tuple(avail[:self.k])
+        rows_gf, rows_bits = self._recovery_rows(chosen, tuple(erased))
+        stack = np.stack([present[i] for i in chosen], axis=-2)
+        if rows_gf is not None and self.gf8_decode_fast():
+            dec = self.backend.apply_gf8_rows(rows_gf, stack)
+        else:
+            dec = self._apply(rows_bits, rows_gf, stack)
+        return {e: dec[..., idx, :] for idx, e in enumerate(erased)}
 
     def _recovery_rows(self, chosen: tuple, erased: tuple):
-        """(GF rows, bit rows) mapping the chosen k survivors to EVERY
-        erased chunk id — data rows come straight from the inverse map
-        R (chosen -> data), parity row e >= k composes the encode row
-        through it: coding_matrix[e-k] · R over GF(2^w).  Cached per
-        erasure signature; this is the matrix the device decode
-        pipeline jit-caches per (geometry, erasure-set)."""
+        """(GF rows or None, bit rows) mapping the chosen k survivors
+        to EVERY erased chunk id — data rows come straight from the
+        inverse map R (chosen -> data), parity row e >= k composes the
+        encode row through it: coding_matrix[e-k] · R over GF(2^w),
+        or, for a code that has only its bit-matrix, bitmatrix[e-k's w
+        rows] · Rbits over GF(2).  Cached per erasure signature; this
+        is the matrix the device decode pipeline jit-caches per
+        (geometry, erasure-set)."""
         key = ("rec", chosen, erased)
         hit = self._decode_cache.get(key)
         if hit is not None:
             return hit
-        if self.coding_matrix is None:
-            raise ValueError("combined recovery rows need a GF "
-                             "coding matrix")
-        R = make_decoding_matrix(self.coding_matrix, self.w,
-                                 list(chosen))
-        f = gf(self.w)
-        rows = [R[e] if e < self.k else
-                f.matmul(self.coding_matrix[e - self.k][None, :], R)[0]
-                for e in erased]
-        rows_gf = np.stack(rows, axis=0).astype(np.int64)
-        rows_bits = matrix_to_bitmatrix(rows_gf, self.w)
+        w = self.w
+        if self.coding_matrix is not None:
+            R = make_decoding_matrix(self.coding_matrix, w,
+                                     list(chosen))
+            f = gf(w)
+            rows = [R[e] if e < self.k else
+                    f.matmul(self.coding_matrix[e - self.k][None, :],
+                             R)[0]
+                    for e in erased]
+            rows_gf = np.stack(rows, axis=0).astype(np.int64)
+            rows_bits = matrix_to_bitmatrix(rows_gf, w)
+        else:
+            rows_gf = None
+            _, Rbits = self._decode_rows(chosen, tuple(range(self.k)))
+            # [I; B] · Rbits over GF(2): chunk e's w rows of it
+            full = np.concatenate(
+                [Rbits, (self.bitmatrix.astype(np.int64)
+                         @ Rbits.astype(np.int64) & 1).astype(np.uint8)],
+                axis=0)
+            rows_bits = np.concatenate(
+                [full[e * w:(e + 1) * w] for e in erased], axis=0)
         self._decode_cache[key] = (rows_gf, rows_bits)
         return rows_gf, rows_bits
 

@@ -508,6 +508,7 @@ def _run_sync(kernel: str, fn, padded: np.ndarray, batch: int,
     """The synchronous twin of _staged_put + AsyncBatch.wait: put,
     call, join, fetch."""
     with section("dispatch.h2d", bytes=padded.nbytes,
+                 live_bytes=batch * padded.shape[1] * L,
                  batch=padded.shape[0]):
         dev = jnp.asarray(padded)
     with section("dispatch.call", kernel=kernel):
@@ -935,7 +936,8 @@ class JaxBackend:
         out[:batch, :, :L] = data
         return out, batch, L
 
-    def _staged_put(self, data: np.ndarray, quantum: int):
+    def _staged_put(self, data: np.ndarray, quantum: int,
+                    shard: bool = True):
         """Pad [batch, k, L] into a persistent staging slot and start
         its h2d.  Returns ``(dev, batch, L, done, sampled, ledger,
         mesh)``; the caller MUST invoke ``done(fence)`` with the device
@@ -947,17 +949,19 @@ class JaxBackend:
         AsyncBatch finalizes it.  ``mesh`` is the live Mesh when the
         batch was placed with the sharded (dp, None, sp) layout — the
         caller must then dispatch the matching sharded kernel — or
-        None for the single-chip layout (single-device host, or a
-        padded length the sp axis cannot shard cleanly)."""
+        None for the single-chip layout (single-device host, a
+        padded length the sp axis cannot shard cleanly, or a caller
+        with no sharded kernel: ``shard=False``)."""
         batch, k, L = data.shape
         if not self.bucket_shapes:
             ledger = {"stage_acquire": time.time()}
             ledger["h2d_start"] = ledger["stage_acquire"]
-            with section("dispatch.h2d", bytes=data.nbytes, batch=batch):
+            with section("dispatch.h2d", bytes=data.nbytes,
+                         live_bytes=data.nbytes, batch=batch):
                 dev = jax.device_put(data)
             ledger["h2d_done"] = time.time()
             return dev, batch, L, None, None, ledger, None
-        mesh = self._resolve_mesh()
+        mesh = self._resolve_mesh() if shard else None
         Lp = _round_up(L, quantum)
         bb = _bucket_batch(batch)
         if mesh is not None:
@@ -984,9 +988,9 @@ class JaxBackend:
         ledger = {"stage_acquire": time.time()}
         try:
             # the fill of the staging slot and the transfer of all of
-            # it, padding included
+            # it, padding included; live_bytes is the payload in it
             with section("dispatch.h2d", bytes=slot.host.nbytes,
-                         batch=bb):
+                         live_bytes=data.nbytes, batch=bb):
                 host = slot.host
                 host[:batch, :, :L] = data  # copycheck: ok - staging fill into a REUSED persistent buffer (the one h2d copy)
                 if slot.max_l > L:
@@ -1032,12 +1036,15 @@ class JaxBackend:
         return dev, batch, L, done, sample, ledger, mesh
 
     def prewarm_geometry(self, k: int, chunk_size: int,
-                         batches=(1,), w: int = 8) -> None:
+                         batches=(1,), w: int = 8,
+                         packetsize: int = 0) -> None:
         """Preallocate the staging rings a (k, chunk_size) geometry
         will dispatch, so the first client write after PG activation
         reuses warm buffers instead of paying fresh allocation.
         Idempotent and cheap (host-side only); executable compilation
-        is driven by the codec layer, which calls this first.
+        is driven by the codec layer, which calls this first.  A
+        packet-layout code (``packetsize`` > 0) stages whole regions
+        of w packets in the single-chip layout (apply_packet_async).
 
         This is also where mesh misconfiguration surfaces: a bad
         explicit ``ec_tpu_mesh_sp`` (doesn't divide the device count,
@@ -1046,11 +1053,11 @@ class JaxBackend:
         if not self.bucket_shapes:
             return
         wbytes = max(1, w // 8)
-        quantum = LENGTH_QUANTUM * wbytes
+        quantum = w * packetsize if packetsize else LENGTH_QUANTUM * wbytes
         Lp = _round_up(chunk_size, quantum)
         mesh = self._resolve_mesh(strict=True)
         dp = 1
-        if mesh is not None:
+        if mesh is not None and not packetsize:
             sp = int(mesh.shape["sp"])
             if Lp % (sp * wbytes):
                 if self._mesh_conf[1]:
@@ -1060,8 +1067,8 @@ class JaxBackend:
                         f"must hold a whole number of {wbytes}-byte "
                         f"words) — pick an sp dividing "
                         f"{Lp // wbytes}")
-                mesh = None      # auto sp that can't shard this
-                                 # geometry: single-chip rings serve
+                # auto sp that can't shard this geometry: single-chip
+                # rings serve
             else:
                 dp = int(mesh.shape["dp"])
         for nb in batches:
@@ -1187,25 +1194,24 @@ class JaxBackend:
         out = out.reshape(lead + out.shape[-2:])
         return out[0] if squeeze else out
 
-    def apply_gf8_matrix_async(self, M: np.ndarray,
-                               data: np.ndarray) -> "AsyncBatch":
-        """Non-blocking XOR-chain apply (double-buffering entry; same
-        contract as apply_bitmatrix_bytes_async)."""
-        if not self.gf8_fast_path():
-            from .matrix import matrix_to_bitmatrix
-            return self.apply_bitmatrix_bytes_async(
-                matrix_to_bitmatrix(M, 8), data, 8)
+    def _staged_call(self, data: np.ndarray, quantum: int, kernel: str,
+                     call, shard: bool = True) -> "AsyncBatch":
+        """The one staged, asynchronous dispatch every lane entry
+        below goes through: fill a staging slot and start its h2d
+        (_staged_put), run ``call(dev, mesh, donate)`` inside
+        ``dispatch.call``, start the d2h copy, hand the slot its fence
+        and return the handle with the seven-phase ledger and the
+        fenced h2d sample."""
         squeeze = data.ndim == 2
         if squeeze:
             data = data[None]
         lead = data.shape[:-2] if not squeeze else ()
         data = data.reshape((-1,) + data.shape[-2:])
         dev, batch, L, done, sample, ledger, mesh = self._staged_put(
-            data, LENGTH_QUANTUM)
+            data, quantum, shard)
         try:
-            with section("dispatch.call", kernel=gf8_kernel()):
-                out = self.gf8_fn(M, donate=done is not None,
-                                  mesh=mesh)(dev)
+            with section("dispatch.call", kernel=kernel):
+                out = call(dev, mesh, done is not None)
                 ledger["compute_start"] = time.time()
                 out.copy_to_host_async()
         except BaseException:
@@ -1223,11 +1229,13 @@ class JaxBackend:
 
     def apply_gf8_rows_async(self, rows: np.ndarray,
                              data: np.ndarray) -> "AsyncBatch":
-        """Non-blocking apply_gf8_rows — the decode twin of
-        apply_gf8_matrix_async.  Per-erasure-signature inverse rows
-        ride the same staging rings, signature-cached kernels, and
-        device-phase ledger as encode, so the OSD batcher can pipeline
-        recovery decode groups exactly like encode groups.  Donation
+        """Non-blocking apply_gf8_rows, for a pool's coding matrix
+        (encode, delta) and per-erasure-signature inverse rows
+        (decode) alike: both ride the same staging rings,
+        signature-cached kernels, and device-phase ledger, so the OSD
+        batcher can pipeline recovery decode groups exactly like
+        encode groups (double buffering: submitting the next batch
+        before waiting overlaps transfers with compute).  Donation
         is legal only for square row sets (gf8_fn enforces it), which
         decode hits whenever len(erased) == k."""
         if not self.gf8_fast_path():
@@ -1235,31 +1243,25 @@ class JaxBackend:
             return self.apply_bitmatrix_bytes_async(
                 matrix_to_bitmatrix(np.asarray(rows, dtype=np.int64),
                                     8), data, 8)
-        squeeze = data.ndim == 2
-        if squeeze:
-            data = data[None]
-        lead = data.shape[:-2] if not squeeze else ()
-        data = data.reshape((-1,) + data.shape[-2:])
-        dev, batch, L, done, sample, ledger, mesh = self._staged_put(
-            data, LENGTH_QUANTUM)
-        try:
-            with section("dispatch.call", kernel=gf8_kernel()):
-                out = self.gf8_fn(rows, donate=done is not None,
-                                  mesh=mesh)(dev)
-                ledger["compute_start"] = time.time()
-                out.copy_to_host_async()
-        except BaseException:
-            # kernel dispatch failed: no fence will ever retire, so
-            # hand the slot back unfenced instead of leaking it
-            if done is not None:
-                done(None)
-            raise
-        if done is not None:
-            done(out)
-        ab = AsyncBatch(out, batch, L, lead, ledger)
-        if sample is not None:
-            ab.h2d_bytes, ab.h2d_seconds = sample
-        return ab
+        return self._staged_call(
+            data, LENGTH_QUANTUM, gf8_kernel(),
+            lambda dev, mesh, donate:
+                self.gf8_fn(rows, donate=donate, mesh=mesh)(dev))
+
+    def apply_packet_async(self, B: np.ndarray, data: np.ndarray, w: int,
+                           packetsize: int) -> "AsyncBatch":
+        """Non-blocking apply_packet_xor — the packet-layout twin of
+        apply_gf8_rows_async, for encode (the pool's coding
+        bit-matrix), decode (per-signature recovery rows) and delta
+        alike.  The staging quantum is a whole region of w packets;
+        the program is looked up through packet_chain_fn on every
+        call, under its one cache key.  There is no sharded packet
+        apply: on a mesh the batch takes the single-chip layout."""
+        return self._staged_call(
+            data, w * packetsize, packet_kernel(packetsize),
+            lambda dev, mesh, donate:
+                self.packet_chain_fn(B, w, packetsize)(dev),
+            shard=False)
 
     def apply_bitmatrix_bytes(self, B: np.ndarray, data: np.ndarray,
                               w: int) -> np.ndarray:
@@ -1287,40 +1289,19 @@ class JaxBackend:
         MXU matmul, and the parity d2h copy, returning a handle.  Calling
         this for batch i+1 before AsyncBatch.wait() on batch i overlaps
         transfers with compute (double buffering)."""
-        squeeze = data.ndim == 2
-        if squeeze:
-            data = data[None]
-        lead = data.shape[:-2] if not squeeze else ()
-        data = data.reshape((-1,) + data.shape[-2:])
         wbytes = max(1, w // 8)
         if data.shape[-1] % wbytes:
             raise ValueError(
                 f"chunk length must be a multiple of {wbytes} for w={w}")
-        dev, batch, L, done, sample, ledger, mesh = self._staged_put(
-            data, LENGTH_QUANTUM * wbytes)
         self._note_kernel("bitplane_xla")
-        try:
-            with section("dispatch.call", kernel="bitplane_xla"):
-                if mesh is not None:
-                    out = self._mesh_apply_fn(mesh, w)(
-                        self._device_matrix_mesh(B, mesh), dev)
-                else:
-                    out = _apply_byte_domain(self._device_matrix(B), dev,
-                                             w)
-                ledger["compute_start"] = time.time()
-                out.copy_to_host_async()
-        except BaseException:
-            # kernel dispatch failed: no fence will ever retire, so
-            # hand the slot back unfenced instead of leaking it
-            if done is not None:
-                done(None)
-            raise
-        if done is not None:
-            done(out)
-        ab = AsyncBatch(out, batch, L, lead, ledger)
-        if sample is not None:
-            ab.h2d_bytes, ab.h2d_seconds = sample
-        return ab
+
+        def call(dev, mesh, donate):
+            if mesh is not None:
+                return self._mesh_apply_fn(mesh, w)(
+                    self._device_matrix_mesh(B, mesh), dev)
+            return _apply_byte_domain(self._device_matrix(B), dev, w)
+        return self._staged_call(data, LENGTH_QUANTUM * wbytes,
+                                 "bitplane_xla", call)
 
     def apply_bitmatrix_bytes_device(self, B: np.ndarray, dev_data, w: int):
         """Device-resident apply: input is already a device array (padded

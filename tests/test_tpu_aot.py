@@ -72,6 +72,24 @@ def test_packet_mxu_kernel_compiles_for_v5e(v5e):
         SingleDeviceSharding(v5e[0]))
 
 
+@pytest.mark.parametrize("rows,batch", [(32, 8), (32, 128), (16, 8)])
+def test_packet_mxu_kernel_compiles_at_the_served_shapes(v5e, rows, batch):
+    """cauchy_k10m4.write_4m's dispatches: cauchy_good k=10 m=4
+    packetsize=2048 at the 64 KiB chunk (4 regions), one object (7
+    stripes staged as 8) and the largest group the load forms (128);
+    ``rows`` 32 is the encode bit-matrix, 16 the recovery rows of two
+    lost chunks."""
+    cg = ecreg.instance().factory(
+        "jerasure", {"k": "10", "m": "4", "technique": "cauchy_good",
+                     "packetsize": "2048"})
+    bits = np.asarray(cg.core.bitmatrix, np.uint8)
+    if rows != bits.shape[0]:
+        _, bits = cg.core._recovery_rows(tuple(range(2, 12)), (0, 1))
+    assert bits.shape == (rows, 80)
+    compile_for(je._packet_mxu_pallas_fn(bits, 8, 2048),
+                (batch, 10, 65536), SingleDeviceSharding(v5e[0]))
+
+
 def test_sharded_rows_fn_compiles_for_a_v5e_2x2_mesh(v5e, monkeypatch):
     """The production mesh dispatch with the kernel a TPU host picks:
     shard_map around the pallas_call, with and without donation."""

@@ -412,6 +412,13 @@ def _drive_instruments():
         parity = codec.encode_batch_async(data).wait()
     assert parity.shape == (16, 4, 4096)
     out["link_payload"] = data.nbytes
+    # and one of a packet-layout code: 7 stripes, staged as 8
+    cauchy = ecreg.instance().factory("tpu", {
+        "technique": "cauchy_good", "k": "4", "m": "3",
+        "packetsize": "512"})
+    with section("batcher.dispatch", lane="packettest"):
+        parity = cauchy.encode_batch_async(data[:7, :4]).wait()
+    assert parity.shape == (7, 3, 4096)
     return out
 
 
@@ -529,6 +536,29 @@ def test_link_bytes_per_user_byte_is_k_plus_m_over_k_for_encode(traced):
     assert crossed == {"dispatch.h2d": traced["link_payload"],
                        "dispatch.d2h": traced["link_payload"] // 2}
     assert sum(crossed.values()) / traced["link_payload"] == 1.5
+
+
+def test_a_packet_dispatch_opens_the_byte_dispatchs_sections(traced):
+    """The same sections with the same keywords, the kernel's name
+    apart: 7 stripes of [4, 4096] staged in a slot of 8."""
+    under = {}
+    for name in ("dispatch.stage_acquire", "dispatch.h2d", "dispatch.call",
+                 "dispatch.wait", "dispatch.d2h"):
+        under[name] = [meta for _, meta, theirs in traced["seen"][name]
+                       if any(m.get("lane") == "packettest"
+                              for m in theirs)]
+        assert len(under[name]) == 1, name
+    assert under["dispatch.call"][0]["kernel"] == "packet_xor_chain"
+    assert under["dispatch.stage_acquire"][0]["batch"] == 8
+    assert under["dispatch.h2d"][0] == {
+        "bytes": 8 * 4 * 4096, "live_bytes": 7 * 4 * 4096, "batch": 8}
+    assert under["dispatch.d2h"][0]["bytes"] == 8 * 3 * 4096
+
+
+def test_every_h2d_section_says_how_much_of_it_is_payload(traced):
+    h2d = [m for _, m, _ in traced["seen"]["dispatch.h2d"]]
+    assert len(h2d) > 2
+    assert all(0 < m["live_bytes"] <= m["bytes"] for m in h2d), h2d[:3]
 
 
 def test_section_records_nothing_and_costs_little_without_a_session():
