@@ -330,15 +330,6 @@ class OSD(Dispatcher):
             self.conf, perf=self.perf, perf_coll=self.perf_coll,
             recorder=self.flight_recorder, contention=self.contention,
             daemon=f"osd.{whoami}")
-        # checksum offload: a deferred-checksum store (BlueStore)
-        # folds its apply-batch CRCs through the codec backend's
-        # GF-bitmatrix kernel when an accelerator is live; resolved
-        # per batch because the batcher only learns its backend on
-        # first device dispatch
-        if hasattr(self.store, "attach_device_batcher"):
-            self.store.attach_device_batcher(
-                lambda: getattr(self.encode_batcher,
-                                "_last_backend", None))
         # timer-wheel fire lag rides the batcher's ec_device
         # subsystem (one device-machinery surface); tick-scale lag is
         # normal, so only fires a full revolution late (a wedged

@@ -7,7 +7,7 @@
 - blockstore: raw block space + bitmap allocator + KV metadata with
   copy-on-write overwrites (reference os/bluestore/, synchronous)
 - bluestore: async BlockStore subclass — WAL group commit, deferred
-  apply off the PG-lock path, device-batched checksums (reference
+  apply off the PG-lock path, one checksum call per entry (reference
   os/bluestore/ transaction pipeline)
 - kv: KeyValueDB abstraction, MemDB/LogDB backends (reference
   src/kv/KeyValueDB.h)

@@ -230,7 +230,7 @@ class BlockStore(ObjectStore):
         """Stamp the per-logical-block CRC of freshly written content.
         Synchronous base: compute inline, one host call per block.
         BlueStore overrides to queue the block and fold all CRCs of an
-        apply batch through one GF-bitmatrix pass (_crc_fold)."""
+        apply entry in one native call (_crc_fold)."""
         ext.crcs[lb] = crc32c(blk)
 
     def _crc_fold(self) -> None:

@@ -29,13 +29,12 @@ IO_DONE → KV_QUEUED → KV_COMMITTING → deferred apply):
   WORK-STEALS the apply when the driver is busy or gone, so progress
   never depends on the background driver (and a crimson reactor
   reading its own pending write cannot deadlock).
-* **Checksums on the device batcher** — the per-block CRC32C stamps
-  of an apply batch are queued and folded through ONE batched
-  GF-bitmatrix pass (ops/crclinear, the same [32, 8·BLOCK] bitmatrix
-  matmul the EC kernels run), device-routed through the codec backend
-  when an accelerator is live (``attach_device_batcher``), host loop
-  otherwise — mirroring the deep-scrub offload gate in
-  osd/ecbackend.py.  Verification on read is inherited unchanged.
+* **Checksums once per apply entry** — the per-block CRC32C stamps
+  of a transaction's writes are queued and folded in ONE native call
+  over the joined blocks (utils/crc.py ``crc32c_blocks``, the host's
+  crc32c instruction), before the extent maps are dumped into the KV
+  batch: one hand-off of the interpreter however many blocks.
+  Verification on read is inherited unchanged.
 
 Ledger contract (utils/store_ledger.py): the queueing thread stamps
 ``journal_append`` / ``journal_fsync``; ownership of the ledger then
@@ -64,9 +63,8 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
-from ..utils.crc import crc32c
+from ..utils.crc import crc32c, crc32c_blocks
 from ..utils.finisher import Finisher
-from ..utils.log import derr_once
 from ..utils.tracer import section
 from .blockstore import BLOCK, BitmapAllocator, BlockStore, _Extents
 from .kv import MemDB, LogDB, WriteBatch
@@ -152,7 +150,6 @@ class BlueStore(BlockStore):
         self._wbuf: Dict[int, bytes] = {}
         # deferred-checksum queue (apply-entry scope, under _lock)
         self._crcq: List[Tuple[_Extents, int, bytes]] = []
-        self._csum_backend_fn: Optional[Callable] = None
         # counters (surfaced via usage() and the store_ladder bench)
         self.wal_records = 0
         self.wal_bytes = 0
@@ -166,8 +163,6 @@ class BlueStore(BlockStore):
         self.vectored_runs = 0
         self.csum_batches = 0
         self.csum_blocks = 0
-        self.csum_device_batches = 0
-        self.csum_device_errors = 0
 
     # -- lifecycle -----------------------------------------------------
     def mkfs(self) -> None:
@@ -756,12 +751,6 @@ class BlueStore(BlockStore):
         super()._flush_dev(dirty)
 
     # -- batched checksums ---------------------------------------------
-    def attach_device_batcher(self, backend_fn: Callable) -> None:
-        """OSD wiring: ``backend_fn()`` -> the live codec backend (or
-        None).  Resolved per batch, because the EncodeBatcher only
-        learns its backend after the first device dispatch."""
-        self._csum_backend_fn = backend_fn
-
     def _crc_block(self, ext: _Extents, lb: int, blk: bytes) -> None:
         # defer: placeholder 0 means "unknown" to every reader, so
         # intra-batch RMW/materialize reads stay correct pre-fold
@@ -778,35 +767,19 @@ class BlueStore(BlockStore):
             ext.crcs[lb] = int(c)
 
     def _crc_batch(self, blocks: List[bytes]) -> List[int]:
-        """One batched CRC pass over an apply batch's blocks.  Device
-        route only when an accelerator is live AND a codec backend
-        with the bitmatrix kernel is attached (the deep-scrub gate,
-        osd/ecbackend.py); a plain-CPU host loop is strictly faster
-        than the bitplane matmul, so that is the fallback."""
+        """The CRC32C of every block of an apply entry: one native
+        call over the joined bytes (every caller of _crc_block hands
+        over exactly BLOCK bytes; crc32c_blocks raises on a ragged
+        join)."""
         self.csum_batches += 1
         self.csum_blocks += len(blocks)
-        fn = self._csum_backend_fn
-        if fn is not None and len(blocks) > 1:
-            try:
-                backend = fn()
-                if backend is not None and \
-                        hasattr(backend, "apply_bitmatrix_bytes"):
-                    import jax
-                    if jax.default_backend() != "cpu":
-                        from ..ops import crclinear
-                        with section("crc.device", blocks=len(blocks),
-                                     bytes=sum(map(len, blocks))):
-                            out = crclinear.shared().crc_batch(
-                                blocks, backend=backend)
-                        self.csum_device_batches += 1
-                        return [int(c) for c in out]
-            except Exception as e:
-                # host loop serves; the failure stays visible
-                self.csum_device_errors += 1
-                derr_once("store", "bluestore device csum", e)
         with section("crc.host", blocks=len(blocks),
-                     bytes=sum(map(len, blocks))):
-            return [crc32c(b) for b in blocks]
+                     bytes=len(blocks) * BLOCK):
+            if len(blocks) == 1:
+                return [crc32c(blocks[0])]
+            return crc32c_blocks(
+                b"".join(blocks),  # copycheck: ok - the one contiguous image the single native CRC call reads: a memcpy of the entry's blocks for one hand-off of the interpreter in place of one per block
+                BLOCK)
 
     # -- read barrier ----------------------------------------------------
     def _wait_applied(self, seq: int) -> None:
@@ -973,13 +946,11 @@ class BlueStore(BlockStore):
     def _csum_stats(self) -> Dict:
         return {"batches": self.csum_batches,
                 "blocks": self.csum_blocks,
-                "device_batches": self.csum_device_batches,
-                "device_errors": self.csum_device_errors,
                 **self._read_stats()}
 
     def dump_store(self) -> dict:
-        """The base payload plus where the checksums ran: the device
-        route has to be provable over the admin-command path."""
+        """The base payload plus the checksum folds and the read
+        verify's counters."""
         out = super().dump_store()
         out["csum"] = self._csum_stats()
         return out

@@ -478,8 +478,7 @@ def phase_cluster(seed: int, n_osds: int = 13, k: int = 8, m: int = 4,
     for d in device:             # one shared backend: same everywhere
         kernels.update(d["kernels"])
     csum = {key: sum(s["csum"][key] for s in store)
-            for key in ("batches", "blocks", "device_batches",
-                        "device_errors")}
+            for key in ("batches", "blocks")}
     mesh_devices = max(p.get("ec_device", {}).get("mesh_devices", 0)
                        for p in perf)
     twin_verdicts = {key: val for key, val in dev.items()
@@ -512,8 +511,6 @@ def phase_cluster(seed: int, n_osds: int = 13, k: int = 8, m: int = 4,
         "no routing verdict sent a group to the twin":
             not twin_verdicts,
         "no prewarm or device error recorded": not errors,
-        "bluestore csum device_errors == 0":
-            csum["device_errors"] == 0,
         "deep-scrub device_errors == 0": scrub["device_errors"] == 0,
     }
     for lane, v in lanes.items():
@@ -522,8 +519,6 @@ def phase_cluster(seed: int, n_osds: int = 13, k: int = 8, m: int = 4,
         checks[f"{lane}: twin requests == 0"] = v["twin_reqs"] == 0
     if on_tpu:
         # these routes switch to the device only off-CPU
-        checks["bluestore csum_device_batches > 0"] = \
-            csum["device_batches"] > 0
         checks["deep-scrub device windows > 0"] = \
             scrub["device_windows"] > 0
         checks["served w=8 dispatches rode gf_mxu_pallas only"] = \

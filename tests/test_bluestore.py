@@ -11,6 +11,8 @@ allocator blocks, against BOTH the synchronous BlockStore and the
 async BlueStore (reference store_test.cc + the deferred-replay cases
 of bluestore_types tests).
 """
+import os
+import random
 import threading
 import time
 
@@ -18,7 +20,8 @@ import pytest
 
 from ceph_tpu.store import (BlockStore, BlueStore, GHObject,
                             Transaction)
-from ceph_tpu.store.blockstore import _Extents
+from ceph_tpu.store.blockstore import BLOCK, _Extents
+from ceph_tpu.utils.crc import crc32c
 from ceph_tpu.utils.store_ledger import PHASE_ORDER, charge
 
 C = "1.0s0"
@@ -544,5 +547,109 @@ def test_backpressure_bounds_deferred_queue(tmp_path):
         s.flush()
         for i in range(20):
             assert s.read(C, obj(f"b{i}")) == b"q" * 4096
+    finally:
+        s.umount()
+
+
+# ------------------------------------------------------------ checksums
+def _seeded_blocks(n, seed=31):
+    rng = random.Random(seed * 1000 + n)
+    return [rng.randbytes(BLOCK) for _ in range(n)]
+
+
+@pytest.mark.parametrize("n", [1, 2, 7, 112, 128])
+def test_crc_batch_is_the_per_block_loop_bit_for_bit(n):
+    """Whatever the batch (112 and 128 are a shard's blocks in the two
+    write cells), the one call gives what a loop over crc32c gives."""
+    s = BlueStore("", start_applier=False)
+    blocks = _seeded_blocks(n)
+    assert s._crc_batch(blocks) == [crc32c(b) for b in blocks]
+    assert (s.csum_batches, s.csum_blocks) == (1, n)
+
+
+def test_crc_batch_refuses_a_ragged_block():
+    s = BlueStore("", start_applier=False)
+    with pytest.raises(ValueError):
+        s._crc_batch([b"\x01" * BLOCK, b"\x02" * (BLOCK - 1)])
+
+
+def test_fold_is_one_native_call_and_never_the_device_route(monkeypatch):
+    """A 128-block write folds through ONE crc32c_blocks call and no
+    per-block crc32c, and nothing of a bluestore write reaches
+    ops/crclinear (its ``shared`` raises here)."""
+    from ceph_tpu.ops import crclinear
+    from ceph_tpu.store import bluestore as bs_mod
+
+    def no_device_route(*a, **kw):
+        raise AssertionError("a bluestore write reached ops/crclinear")
+    monkeypatch.setattr(crclinear, "shared", no_device_route)
+    calls = {"crc32c": 0, "crc32c_blocks": []}
+    real_one, real_many = bs_mod.crc32c, bs_mod.crc32c_blocks
+
+    def one(data, crc=0):
+        calls["crc32c"] += 1
+        return real_one(data, crc)
+
+    def many(buf, block_len):
+        calls["crc32c_blocks"].append((len(buf), block_len))
+        return real_many(buf, block_len)
+    monkeypatch.setattr(bs_mod, "crc32c", one)
+    monkeypatch.setattr(bs_mod, "crc32c_blocks", many)
+    s = BlueStore("", start_applier=False)
+    s.mount()
+    try:
+        s.queue_transactions([Transaction().create_collection(C)])
+        payload = b"".join(_seeded_blocks(128))
+        s.queue_transactions([Transaction().write(C, obj("f"), 0,
+                                                  payload)])
+        s.flush()
+        assert calls == {"crc32c": 0,
+                         "crc32c_blocks": [(128 * BLOCK, BLOCK)]}
+        assert (s.csum_batches, s.csum_blocks) == (1, 128)
+        assert set(s.dump_store()["csum"]) >= {"batches", "blocks"}
+        assert not any("device" in key for key in s.dump_store()["csum"])
+        # the stamps are the per-block values, none left at the
+        # placeholder, and the read verifies against them
+        ext = s._load_extents(C, obj("f"))
+        assert [ext.crcs[lb] for lb in range(128)] == \
+            [real_one(payload[lb * BLOCK:(lb + 1) * BLOCK])
+             for lb in range(128)]
+        assert s.read(C, obj("f")) == payload
+        assert s.usage()["csum_failures"] == 0
+    finally:
+        s.umount()
+
+
+def test_bluestore_csum_detects_bitrot_after_a_batched_fold(tmp_path):
+    """test_store.py::test_blockstore_csum_detects_bitrot on bluestore:
+    a multi-block write reads back clean, and one flipped bit in one
+    of its blocks on the raw device is EIO, not silent corruption."""
+    path = str(tmp_path / "bs")
+    s = BlueStore(path)
+    s.mkfs()
+    s.mount()
+    try:
+        s.queue_transactions([Transaction().create_collection(C)])
+        payload = b"".join(_seeded_blocks(7))
+        s.queue_transactions([Transaction().write(C, obj("rot"), 0,
+                                                  payload)])
+        s.flush()
+        assert s.read(C, obj("rot")) == payload
+        assert s.usage()["csum_failures"] == 0
+        ext = s._load_extents(C, obj("rot"))
+        assert all(ext.crcs[lb] for lb in range(7))
+        phys = ext.blocks[5]
+        assert phys >= 0
+        with open(os.path.join(path, "block.dev"), "r+b") as f:
+            f.seek(phys * BLOCK + 17)
+            b = f.read(1)
+            f.seek(phys * BLOCK + 17)
+            f.write(bytes([b[0] ^ 0x04]))
+        with pytest.raises(OSError):
+            s.read(C, obj("rot"))
+        assert s.usage()["csum_failures"] >= 1
+        # the blocks before the rotten one still verify
+        assert s.read(C, obj("rot"), 0, 5 * BLOCK) == \
+            payload[:5 * BLOCK]
     finally:
         s.umount()

@@ -296,8 +296,8 @@ SECTIONS = {
     "mon.dispatch": ("msgr.dispatch", False),
     "mon.tick": (None, False),
 }
-# crc.device opens only where jax.default_backend() is not "cpu"
-# (store/bluestore.py, osd/ecbackend.py deep scrub): no CPU case.
+# crc.device opens only in deep scrub's device windows, where
+# jax.default_backend() is not "cpu" (osd/ecbackend.py): no CPU case.
 
 
 def _drive_cluster():
@@ -419,6 +419,23 @@ def _drive_instruments():
     with section("batcher.dispatch", lane="packettest"):
         parity = cauchy.encode_batch_async(data[:7, :4]).wait()
     assert parity.shape == (7, 3, 4096)
+    # a bluestore in RAM mode with no applier: this thread folds its
+    # own writes (7 blocks, then 1) under a section of the test's own
+    from ceph_tpu.store import BlueStore, GHObject, Transaction
+    store = BlueStore("", start_applier=False)
+    store.mount()
+    try:
+        store.queue_transactions(
+            [Transaction().create_collection("9.0s0")])
+        with section("store.txn", op="foldtest:1"):
+            for name, nblocks in (("seven", 7), ("one", 1)):
+                store.queue_transactions([Transaction().write(
+                    "9.0s0", GHObject(name, 0), 0,
+                    bytes(range(256)) * 16 * nblocks)])
+                store.flush()
+        out["folds"] = store.csum_batches
+    finally:
+        store.umount()
     return out
 
 
@@ -502,6 +519,23 @@ def test_store_read_names_its_blocks_and_its_objects_blocks(traced):
     shard = [m for m in reads if m["obj_blocks"] == 32]
     assert any(m["blocks"] == 1 and m["bytes"] == 4096 for m in shard)
     assert any(m["blocks"] == 32 for m in shard)
+
+
+def test_a_bluestore_fold_is_one_host_crc_section_and_no_dispatch(traced):
+    """Two writes, two folds, two ``crc.host`` sections on the store's
+    thread with the batch each one call covered; nothing of the write
+    opens ``crc.device`` or stages a byte for the device."""
+    def under_the_store(name):
+        return [meta for _, meta, theirs in traced["seen"].get(name, [])
+                if any(m.get("op") == "foldtest:1" for m in theirs)]
+    assert traced["folds"] == 2
+    assert under_the_store("crc.host") == [
+        {"blocks": 7, "bytes": 7 * 4096}, {"blocks": 1, "bytes": 4096}]
+    assert len(under_the_store("store.data_write")) == 2
+    for name in ("crc.device", "dispatch.h2d", "dispatch.call",
+                 "dispatch.d2h"):
+        assert under_the_store(name) == [], name
+    assert "crc.device" not in traced["seen"]
 
 
 def test_lock_wait_only_under_contention_with_site_and_holder(traced):
