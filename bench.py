@@ -278,7 +278,7 @@ def bench_encode_rs(k, m, stripe_bytes, batch, n_bufs=6, cycles=8):
     if value < baseline:
         # the OSD batcher's learned CPU/device crossover routes batches
         # this size to the CPU twin in production (osd/batcher.py
-        # _route_to_cpu), so the deployed path never pays this loss —
+        # _route), so the deployed path never pays this loss —
         # print the routing verdict so the number reads as a decision
         extra = ("; production routing: adaptive crossover sends "
                  "batches this size to the CPU twin — device loses "

@@ -24,6 +24,12 @@ Mechanics:
   submission order (per-PG FIFO holds: the PG pipeline admits one
   encode per PG at a time, and one collector drains batches serially).
 
+Reconstruction (``submit_decode``) and parity-delta (``submit_delta``)
+requests ride the same collector: encode, decode and delta are three
+rows of ONE lane table (``_Lane``), and the routing ladder, its
+learner, the dispatch, the join and the twin completion are written
+once over it.
+
 Locking: ``submit`` takes only the batcher lock; continuations take
 the owning PG's lock while the batcher lock is dropped — no ordering
 cycle with the op workers (which take PG lock then ``submit``).
@@ -179,6 +185,320 @@ def _geometry_key(ec_impl, sinfo: ecutil.StripeInfo) -> Tuple:
             sinfo.chunk_size)
 
 
+def _cat(parts):
+    """The tiles of one group as one stack: arrays along the stripe
+    axis, a decode's {shard: [B, cs]} map shard by shard."""
+    if len(parts) == 1:
+        return parts[0]
+    if isinstance(parts[0], dict):
+        return {s: np.concatenate([p[s] for p in parts], axis=0)
+                for s in parts[0]}
+    return np.concatenate(parts, axis=0)
+
+
+def _nbytes(stack) -> int:
+    if isinstance(stack, dict):
+        return sum(v.nbytes for v in stack.values())
+    return stack.nbytes
+
+
+def _column_views(cols) -> Tuple[Dict[int, memoryview], int]:
+    """{shard: [n, chunk] column of a stack} -> ({shard: 1-D byte
+    memoryview}, bytes copied).
+
+    The column gathers are the ONE unavoidable copy on the output
+    side — a [nstripes, shards, chunk] stack interleaves shards, so
+    each shard's chunks must be made contiguous exactly once.  The
+    views then ride by reference through the sub-write transactions,
+    the wire iovecs and the store with no further bytes()/tobytes()
+    round trips.  memoryview compares by content, so callers that
+    check chunks against reference encodes with == still work."""
+    out: Dict[int, memoryview] = {}
+    copied = 0
+    for shard, src in cols.items():
+        col = np.ascontiguousarray(src)
+        if col is not src:
+            copied += col.nbytes
+        out[shard] = memoryview(col).cast("B")
+    return out, copied
+
+
+class _Lane:
+    """What one of the batcher's three lanes (encode, decode,
+    parity-delta) is, as far as the lanes differ: names, where the
+    learned crossover lives, how a group's bytes are reckoned, and the
+    hooks that stack a group's requests, launch one tile on the
+    plug-in, split a result back to the riders and run the group off
+    the device.  The ladder, the learner, the dispatch, the join and
+    the twin completion are written once in EncodeBatcher, over the
+    three instances _ENC, _DEC and _DELTA.
+
+    Each lane defines ``width(key, reqs)`` (columns of one stripe of a
+    request's array, where ``form`` is this one),
+    ``launch(key, reqs, stack, lo, hi)`` (one tile of the stack on the
+    plug-in's async entry), ``twin_call(impl, key, reqs, stack)`` (the
+    whole stack in ONE batched call off the async path),
+    ``single(b, key, r)`` (the per-request fallback behind it) and
+    ``split(b, key, reqs, result)`` (yield each rider's output, in
+    request order)."""
+    name = ""            # queue-key tag, request.lane, section lane=
+    prefix = ""          # <prefix>route_<reason> counters, <prefix>route
+                         # recorder events, ec_<prefix>batch_* OSD counters
+    group = ""           # the ledgers' "group" tag; the device-failure
+                         # kind of the lane's synchronous device call
+    # instance counters: calls, requests, requests on the twin,
+    # requests that shared a call
+    counters: Tuple[str, str, str, str] = ("", "", "", "")
+    # class attribute that holds the learned crossover.  Decode and
+    # delta keep their own (recovery moves k survivor chunks IN per
+    # erased chunk OUT, a delta D dirty columns IN per m parity
+    # columns OUT: neither has encode's k-in/m-out economics), but
+    # while it reads 0 they judge by encode's — the device and the
+    # link are the same hardware, so encode's measurement beats
+    # flying blind on the first rebuild window (_min_bytes)
+    crossover = "_min_device_bytes"
+    concat_site = ""     # copytrack site of the multi-request concat
+    # the plug-in's async entry and the method that says whether this
+    # geometry can ride it (None: submit() queues no other codec)
+    async_entry = ""
+    async_probe: Optional[str] = None
+    # handle of a group whose plug-in lacks the async entry
+    without_async = "twin"
+    # a failed device dispatch or join falls the WHOLE group to the
+    # batched twin (zero client errors); encode alone falls to
+    # per-request _cpu_encode, uncounted
+    fails_to_twin = True
+    # the plug-in's own synchronous batched call stands in for a twin
+    # that cannot be built, and serves without_async="sync"
+    sync_call = False
+    # tracked.mark_event names, after a device dispatch / at a twin
+    # group's start (decode requests carry no tracker)
+    dispatch_event: Optional[str] = None
+    twin_event: Optional[str] = None
+    # -- what each lane's twin completion books today.  The lanes
+    # disagree in ways that look like oversights (ROADMAP.md queue 3
+    # lists them); they are kept as they were, stated here once.
+    twin_books = True    # queue wait, concat copy, stage seconds,
+                         # ec_batcher counters, cpu_calls, ledger size
+    twin_is_call = True  # a twin group counts in <calls>
+    twin_perf: Tuple[str, ...] = ()     # ec_<prefix>batch_<x> bumped
+    # instance counters one rider of the per-request fallback bumps
+    single_counts: Tuple[str, ...] = ()
+
+    def geometry(self, key: Tuple) -> Tuple:
+        return key[1]
+
+    def bucket(self, key: Tuple) -> Tuple:
+        """Key of the lane's _cpu_bps / _dev_bps entry: one per
+        geometry (not per erasure or dirty signature — the GF
+        matmul's bytes/s is nearly independent of it: compute and
+        input scale together)."""
+        return (self.name, self.geometry(key))
+
+    def route_fields(self, key: Tuple) -> dict:
+        """Lane-specific fields of the recorder's route event."""
+        return {}
+
+    def has_async(self, impl) -> bool:
+        if self.async_probe is None:
+            return True
+        sup = getattr(impl, self.async_probe, None)
+        if sup is None or not hasattr(impl, self.async_entry):
+            return False
+        try:
+            return bool(sup())
+        except Exception:
+            return False
+
+    def group_bytes(self, reqs) -> int:
+        """Input bytes the router judges the group by."""
+        return sum(r.nbytes for r in reqs)
+
+    def form(self, key: Tuple, reqs):
+        """Stack the group's requests ([B, width, chunk]): views for
+        one, one concat for more.  Raises on a malformed payload."""
+        n = self.width(key, reqs)
+        return _cat([r.as_array(n) for r in reqs])
+
+    def probe(self, b, twin, key: Tuple, reqs, stack) -> None:
+        """What _cpu_rate times."""
+        self.twin_call(twin, key, reqs, stack)
+
+
+class _EncLane(_Lane):
+    name, prefix, group = "enc", "", "encode"
+    counters = ("calls", "reqs_total", "cpu_reqs", "reqs_coalesced")
+    concat_site = "batcher.batch_concat"
+    async_entry = "encode_batch_async"
+    fails_to_twin = False
+    dispatch_event = twin_event = "ec:batch_dispatched"
+    twin_is_call = False             # calls counts device calls only
+    twin_perf = ("coalesced",)
+    single_counts = ("reqs_total", "cpu_reqs", "cpu_calls")
+
+    def geometry(self, key):
+        return key[1:]               # the queue key is ("enc",) + it
+
+    bucket = geometry                # encode's buckets carry no tag
+
+    def width(self, key, reqs):
+        return reqs[0].ec_impl.get_data_chunk_count()
+
+    def launch(self, key, reqs, stack, lo, hi):
+        return reqs[0].ec_impl.encode_batch_async(stack[lo:hi])
+
+    def twin_call(self, impl, key, reqs, stack):
+        return impl.encode_batch(stack)
+
+    def probe(self, b, twin, key, reqs, stack):
+        # encode's rate is the rate of its per-request fallback: the
+        # twin's batched call AND the shard gathers behind it
+        b._cpu_encode(reqs[0])
+
+    def single(self, b, key, r):
+        return b._cpu_encode(r)
+
+    def split(self, b, key, reqs, result):
+        """The full {shard: bytes} chunk map per rider: its own data
+        columns and its rows of the [B, m, chunk] parity."""
+        impl = reqs[0].ec_impl
+        k = impl.get_data_chunk_count()
+        m = impl.get_coding_chunk_count()
+        off = 0
+        for r in reqs:
+            arr, p = r.as_array(k), result[off:off + r.nstripes]
+            off += r.nstripes
+            cols = {i: arr[:, i] for i in range(k)}
+            cols.update({k + j: p[:, j] for j in range(m)})
+            out, copied = _column_views(cols)
+            if copied:
+                b._note_copy(copied, "batcher.shard_gather")
+            yield out
+
+
+class _DecLane(_Lane):
+    name, prefix, group = "dec", "dec_", "decode"
+    counters = ("dec_calls", "dec_reqs", "dec_cpu_reqs",
+                "dec_coalesced")
+    crossover = "_dec_min_device_bytes"
+    concat_site = "batcher.dec_batch_concat"
+    async_entry = "decode_batch_async"
+    async_probe = "decode_async_supported"
+    # without it the group is routed at completion time, and a
+    # device-bound one runs the plug-in's fenced decode_batch on its
+    # own ec-dec-dev thread (_serve_sync)
+    without_async = "sync"
+    sync_call = True
+    twin_books = False
+    twin_perf = ("calls", "coalesced")
+    single_counts = ("dec_reqs",)
+
+    def group_bytes(self, reqs):
+        cs = reqs[0].sinfo.chunk_size
+        return sum(r.nstripes * cs * len(r.have) for r in reqs)
+
+    def form(self, key, reqs):
+        """One [B, cs] stack per surviving shard id (key[2])."""
+        cs = reqs[0].sinfo.chunk_size
+        return {s: _cat([ecutil.as_stripe_array(r.have[s], r.nstripes,
+                                                1, cs)
+                         .reshape(r.nstripes, cs) for r in reqs])
+                for s in key[2]}
+
+    def launch(self, key, reqs, stack, lo, hi):
+        return reqs[0].ec_impl.decode_batch_async(
+            {s: v[lo:hi] for s, v in stack.items()},
+            reqs[0].sinfo.chunk_size)
+
+    def twin_call(self, impl, key, reqs, stack):
+        return impl.decode_batch(stack, reqs[0].sinfo.chunk_size)
+
+    def single(self, b, key, r):
+        return ecutil.decode(r.sinfo, r.ec_impl, r.have, set(r.want))
+
+    def split(self, b, key, reqs, result):
+        """{wanted shard: bytes} per rider: reconstructed shards (the
+        erasure signature key[3]) from the batched result, wanted
+        shards that were read directly passed through — the contract
+        of ecutil.decode."""
+        missing = key[3]
+        off = 0
+        for r in reqs:
+            out = {}
+            for s in r.want:
+                if s in missing:
+                    # row slice of a [B, cs] batch result; the
+                    # memoryview rides downstream by reference
+                    out[s] = memoryview(np.ascontiguousarray(
+                        result[s][off:off + r.nstripes])).cast("B")
+                else:
+                    h = r.have[s]
+                    out[s] = h if isinstance(h, bytes) else \
+                        memoryview(h).cast("B")
+            off += r.nstripes
+            yield out
+
+
+class _DeltaLane(_Lane):
+    name, prefix, group = "delta", "delta_", "delta"
+    counters = ("delta_calls", "delta_reqs", "delta_cpu_reqs",
+                "delta_coalesced")
+    crossover = "_delta_min_device_bytes"
+    concat_site = "batcher.delta_batch_concat"
+    async_entry = "delta_encode_batch_async"
+    async_probe = "delta_async_supported"
+    dispatch_event = "ec:delta_dispatched"
+    single_counts = ("delta_reqs", "delta_cpu_reqs")
+
+    def route_fields(self, key):
+        return {"dirty_cols": len(key[2])}
+
+    def width(self, key, reqs):
+        return len(key[2])           # the dirty-column signature
+
+    def launch(self, key, reqs, stack, lo, hi):
+        return reqs[0].ec_impl.delta_encode_batch_async(
+            stack[lo:hi], key[2])
+
+    def twin_call(self, impl, key, reqs, stack):
+        return impl.core.delta_parity(
+            np.asarray(stack, dtype=np.uint8), key[2])
+
+    def single(self, b, key, r):
+        return b._delta_inline(r.ec_impl, r.sinfo, r.delta, key[2])
+
+    def split(self, b, key, reqs, result):
+        """{parity_shard: Δparity bytes} per rider from a [B, m,
+        chunk] stack, to XOR into the stored parity (xor_write)."""
+        k = reqs[0].ec_impl.get_data_chunk_count()
+        off = copied = 0
+        for r in reqs:
+            p = result[off:off + r.nstripes]
+            off += r.nstripes
+            out, n = _column_views(
+                {k + j: p[:, j] for j in range(p.shape[1])})
+            copied += n
+            yield out
+        if copied:
+            b._note_copy(copied, "batcher.delta_shard_gather")
+
+
+# the ladder's verdicts (_route, _breaker_blocks)
+_ROUTE_REASONS = (
+    ("device", "batches over the crossover -> device"),
+    ("pin", "batches under the operator/calibration pin -> twin "
+            "(deterministic)"),
+    ("learned", "batches under the LEARNED crossover -> twin"),
+    ("idle_probe", "idle-device re-probes forced to the device"),
+    ("tick_probe", "1-in-N periodic probes forced to the device"),
+    ("breaker_open", "batches the open breaker routed to the twin"),
+    ("breaker_probe", "re-admission probes through the open breaker"))
+
+_ENC, _DEC, _DELTA = _EncLane(), _DecLane(), _DeltaLane()
+_LANES: Dict[str, _Lane] = {lane.name: lane
+                            for lane in (_ENC, _DEC, _DELTA)}
+
+
 class EncodeBatcher:
     """Per-OSD encode coalescer (one collector thread).
 
@@ -194,12 +514,9 @@ class EncodeBatcher:
                                              # close resets TO this)
     _dec_min_device_bytes: float = 0.0       # decode-side crossover;
                                              # 0 = not yet learned ->
-                                             # seeded from the encode
-                                             # EWMA (_dec_min_bytes)
-    _delta_min_device_bytes: float = 0.0     # parity-delta crossover;
-                                             # 0 = not yet learned ->
-                                             # seeded like the decode
-                                             # side (_delta_min_bytes)
+                                             # encode's (_min_bytes)
+    _delta_min_device_bytes: float = 0.0     # parity-delta crossover,
+                                             # seeded like decode's
     _probe_tick: int = 0                     # shared probe cadence
     _warmed: set = set()                     # geometries prewarmed
     _h2d_bps: float = 0.0                    # warm link rate EWMA, shared
@@ -405,25 +722,6 @@ class EncodeBatcher:
         if perf_coll is not None:
             dp = perf_coll.create("ec_device")
             if "route_device" not in dp._types:
-                for reason, desc in (
-                        ("device", "batches over the crossover -> "
-                                   "device"),
-                        ("pin", "batches under the operator/"
-                                "calibration pin -> twin "
-                                "(deterministic)"),
-                        ("learned", "batches under the LEARNED "
-                                    "crossover -> twin"),
-                        ("idle_probe", "idle-device re-probes forced "
-                                       "to the device"),
-                        ("tick_probe", "1-in-N periodic probes "
-                                       "forced to the device"),
-                        ("breaker_open", "batches the open breaker "
-                                         "routed to the twin"),
-                        ("breaker_probe", "re-admission probes "
-                                          "through the open "
-                                          "breaker")):
-                    dp.add(f"route_{reason}",
-                           description="routing verdicts: " + desc)
                 from ..utils.perf import TYPE_U64
                 for g, desc in (
                         ("staging_hits", "stagings served from a "
@@ -457,68 +755,15 @@ class EncodeBatcher:
                     [100, 500, 1000, 5000, 10000, 25000, 50000,
                      100000, 500000],
                     "timer-wheel fire lag vs requested deadline (us)")
-            if "dec_route_device" not in dp._types:
-                # decode-route verdicts, mirroring route_* for the
-                # read/recovery side (registered under their own
-                # guard: dperf instances created by older sessions
-                # predate these counters)
-                for reason, desc in (
-                        ("device", "decode batches over the "
-                                   "crossover -> device"),
-                        ("learned", "decode batches under the "
-                                    "LEARNED crossover -> twin"),
-                        ("breaker_open", "decode batches the open "
-                                         "breaker routed to the "
-                                         "twin"),
-                        ("breaker_probe", "decode re-admission "
-                                          "probes through the open "
-                                          "breaker")):
-                    dp.add(f"dec_route_{reason}",
-                           description="decode routing verdicts: "
-                                       + desc)
-            if "dec_route_pin" not in dp._types:
-                # the full reason ladder for the collect-time decode
-                # router (ISSUE 11): decode groups now route BEFORE
-                # dispatch like encode groups, so the pin and the
-                # probe taxes apply to them too
-                for reason, desc in (
-                        ("pin", "decode batches under the operator/"
-                                "calibration pin -> twin "
-                                "(deterministic)"),
-                        ("idle_probe", "idle-device decode re-probes "
-                                       "forced to the device"),
-                        ("tick_probe", "1-in-N periodic decode "
-                                       "probes forced to the "
-                                       "device")):
-                    dp.add(f"dec_route_{reason}",
-                           description="decode routing verdicts: "
-                                       + desc)
-            if "delta_route_device" not in dp._types:
-                # parity-delta RMW routing verdicts (own guard: dperf
-                # instances created by older sessions predate these).
-                # Same reason ladder as encode/decode — the delta
-                # matmul rides the same device and crossover machinery
-                for reason, desc in (
-                        ("device", "delta batches over the "
-                                   "crossover -> device"),
-                        ("pin", "delta batches under the operator/"
-                                "calibration pin -> twin "
-                                "(deterministic)"),
-                        ("learned", "delta batches under the LEARNED "
-                                    "crossover -> twin"),
-                        ("idle_probe", "idle-device delta re-probes "
-                                       "forced to the device"),
-                        ("tick_probe", "1-in-N periodic delta probes "
-                                       "forced to the device"),
-                        ("breaker_open", "delta batches the open "
-                                         "breaker routed to the "
-                                         "twin"),
-                        ("breaker_probe", "delta re-admission probes "
-                                          "through the open "
-                                          "breaker")):
-                    dp.add(f"delta_route_{reason}",
-                           description="parity-delta routing "
-                                       "verdicts: " + desc)
+            # routing verdicts BY REASON, one ladder's worth per lane
+            # (each under its own guard: dperf instances created by
+            # older sessions predate the later lanes' counters)
+            for lane in _LANES.values():
+                for reason, desc in _ROUTE_REASONS:
+                    name = f"{lane.prefix}route_{reason}"
+                    if name not in dp._types:
+                        dp.add(name, description=f"{lane.group} "
+                               f"routing verdicts: {desc}")
             if "staging_host_bytes_now" not in dp._types:
                 # memory-accounting + overlap gauges (ISSUE 10),
                 # registered under their own guard: dperf instances
@@ -636,19 +881,8 @@ class EncodeBatcher:
             if req.nstripes == 0:
                 cb({i: b"" for i in range(ec_impl.get_chunk_count())})
                 return
-            with self._cond:
-                if self._stop:
-                    stopped = True       # raced shutdown: encode inline
-                else:
-                    stopped = False
-                    if not self._queues:
-                        self._first_enqueue = time.monotonic()
-                    self._queues.setdefault(
-                        ("enc",) + _geometry_key(ec_impl, sinfo),
-                        []).append(req)
-                    self._pending_stripes += req.nstripes
-                    self._cond.notify()
-            if stopped:
+            if not self._enqueue(
+                    ("enc",) + _geometry_key(ec_impl, sinfo), req):
                 cb(ecutil.encode(sinfo, ec_impl, data))
 
     def submit_decode(self, ec_impl, sinfo: ecutil.StripeInfo,
@@ -675,25 +909,16 @@ class EncodeBatcher:
                         else memoryview(have[s]).cast("B"))
                     for s in want})
                 return
-            stopped = self._stop or not hasattr(ec_impl, "decode_batch")
-            req = None
-            if not stopped:
+            queued = not self._stop and hasattr(ec_impl, "decode_batch")
+            if queued:
                 req = _DecReq(ec_impl, sinfo, have, want, cb)
                 if req.nstripes == 0:
                     cb({s: b"" for s in want})
                     return
-                key = ("dec", _geometry_key(ec_impl, sinfo),
-                       tuple(sorted(have)), tuple(sorted(missing)))
-                with self._cond:
-                    if self._stop:
-                        stopped = True   # raced shutdown: decode inline
-                    else:
-                        if not self._queues:
-                            self._first_enqueue = time.monotonic()
-                        self._queues.setdefault(key, []).append(req)
-                        self._pending_stripes += req.nstripes
-                        self._cond.notify()
-            if stopped:
+                queued = self._enqueue(
+                    ("dec", _geometry_key(ec_impl, sinfo),
+                     tuple(sorted(have)), tuple(sorted(missing))), req)
+            if not queued:
                 try:
                     dec = ecutil.decode(sinfo, ec_impl, have, set(want))
                 except Exception:
@@ -719,32 +944,37 @@ class EncodeBatcher:
         with section("batcher.submit", lane="delta",
                      bytes=ecutil.nbytes_of(delta)):
             cols = tuple(sorted(dirty_cols))
-            stopped = self._stop or \
-                not hasattr(ec_impl, "delta_encode_batch_async")
-            req = None
-            if not stopped:
+            queued = not self._stop and \
+                hasattr(ec_impl, "delta_encode_batch_async")
+            if queued:
                 req = _DeltaReq(ec_impl, sinfo, delta, cols, cb, tracked)
                 if req.nstripes == 0:
                     k = ec_impl.get_data_chunk_count()
                     m = ec_impl.get_coding_chunk_count()
                     cb({k + j: b"" for j in range(m)})
                     return
-                key = ("delta", _geometry_key(ec_impl, sinfo), cols)
-                with self._cond:
-                    if self._stop:
-                        stopped = True   # raced shutdown: compute inline
-                    else:
-                        if not self._queues:
-                            self._first_enqueue = time.monotonic()
-                        self._queues.setdefault(key, []).append(req)
-                        self._pending_stripes += req.nstripes
-                        self._cond.notify()
-            if stopped:
+                queued = self._enqueue(
+                    ("delta", _geometry_key(ec_impl, sinfo), cols), req)
+            if not queued:
                 try:
                     out = self._delta_inline(ec_impl, sinfo, delta, cols)
                 except Exception:
                     out = None
                 cb(out)
+
+    def _enqueue(self, key: Tuple, req) -> bool:
+        """Queue one request under its group key and wake the
+        collector.  False when the batcher has stopped (a submit that
+        raced shutdown): the caller serves the request inline."""
+        with self._cond:
+            if self._stop:
+                return False
+            if not self._queues:
+                self._first_enqueue = time.monotonic()
+            self._queues.setdefault(key, []).append(req)
+            self._pending_stripes += req.nstripes
+            self._cond.notify()
+        return True
 
     def _delta_inline(self, ec_impl, sinfo: ecutil.StripeInfo,
                       delta, cols) -> Dict[int, memoryview]:
@@ -816,7 +1046,8 @@ class EncodeBatcher:
                 probe = _Req(ec_impl, sinfo,
                              b"\0" * (sinfo.stripe_width * nprobe),
                              lambda _c: None)
-                self._cpu_rate(key, probe)
+                qkey = (_ENC.name,) + key
+                self._cpu_rate(_ENC, qkey, [probe])
                 import jax
                 if jax.default_backend() == "cpu":
                     return       # XLA:CPU compiles these in
@@ -859,12 +1090,10 @@ class EncodeBatcher:
                     # the CPU twin instead of waiting out a doomed
                     # round trip
                     t0 = time.monotonic()
-                    ec_impl.encode_batch_async(z).wait()
-                    warm_req = _Req(ec_impl, sinfo, z.tobytes(),  # copycheck: ok - one-time warmup calibration buffer
-                                    lambda _c: None)
+                    parity = ec_impl.encode_batch_async(z).wait()
                     self._learn_crossover(
-                        [warm_req], time.monotonic() - t0,
-                        trust_win=False)
+                        _ENC, qkey, [probe], time.monotonic() - t0,
+                        z.nbytes, parity.nbytes, trust_win=False)
             except Exception as e:
                 # the daemon stays up, but a geometry that cannot
                 # compile or dispatch has to be known BEFORE the first
@@ -895,38 +1124,6 @@ class EncodeBatcher:
         copytrack.note_copy(nbytes, site)
         if self.bperf is not None:
             self.bperf.inc("bytes_copied", nbytes)
-
-    def _shard_views(self, arr: np.ndarray, parity: np.ndarray,
-                     k: int, m: int) -> Dict[int, memoryview]:
-        """Per-shard chunk buffers as 1-D byte memoryviews.
-
-        The column gathers (arr[:, i] / parity[:, j]) are the ONE
-        unavoidable copy on the encode output side — the
-        [nstripes, k, chunk] layout interleaves shards, so each
-        shard's chunks must be made contiguous exactly once.  The
-        views then ride by reference through the sub-write
-        transactions, the wire iovecs and the store with no further
-        bytes()/tobytes() round trips.  memoryview compares by
-        content, so callers that check chunks against reference
-        encodes with == still work.
-        """
-        out: Dict[int, memoryview] = {}
-        copied = 0
-        for i in range(k):
-            src = arr[:, i]
-            col = np.ascontiguousarray(src)
-            if col is not src:
-                copied += col.nbytes
-            out[i] = memoryview(col).cast("B")
-        for j in range(m):
-            src = parity[:, j]
-            col = np.ascontiguousarray(src)
-            if col is not src:
-                copied += col.nbytes
-            out[k + j] = memoryview(col).cast("B")
-        if copied:
-            self._note_copy(copied, "batcher.shard_gather")
-        return out
 
     # -- collector -------------------------------------------------------
     def apply_tuning(self) -> None:
@@ -1049,33 +1246,23 @@ class EncodeBatcher:
                     gstripes = sum(r.nstripes for r in reqs)
                     if gstripes > self.group_stripes_hwm:
                         self.group_stripes_hwm = gstripes
-                    if key[0] == "dec":
-                        # decode groups route + dispatch HERE like encode
-                        # groups (ISSUE 11): the async handle rides the
-                        # same bounded completion queue, so decode honors
-                        # ec_tpu_inflight_groups and pipelines its h2d
-                        # under the previous group's compute
-                        groups.append((key, reqs,
-                                       self._route_dec_group(key, reqs)))
-                        continue
-                    if key[0] == "delta":
-                        # parity-delta groups route + dispatch like
-                        # decode groups: async handle on the bounded
-                        # completion queue, h2d pipelined under the
-                        # previous group's compute
-                        groups.append((key, reqs,
-                                       self._route_delta_group(key,
-                                                               reqs)))
-                        continue
-                    to_cpu = self._route_to_cpu(key, reqs)
-                    if not to_cpu and self._breaker_blocks():
-                        to_cpu = True
-                    self._note_route(key, reqs, to_cpu)
-                    groups.append((key, reqs, "cpu" if to_cpu
-                                   else self._dispatch_group(reqs)))
-            for key, reqs, handle in groups:
-                self._completions.put((key, reqs, handle,
-                                       len(groups)))
+                    # every lane routes + dispatches HERE: the async
+                    # handle rides the one bounded completion queue, so
+                    # each honors ec_tpu_inflight_groups and pipelines
+                    # its h2d under the previous group's compute.  The
+                    # handle is the in-flight tiles (None: the dispatch
+                    # failed), "twin", or "sync" (_Lane.without_async)
+                    lane = _LANES[key[0]]
+                    if lane.has_async(reqs[0].ec_impl):
+                        to_cpu = self._route(lane, key, reqs)
+                        self._note_route(lane, key, reqs, to_cpu)
+                        handle = "twin" if to_cpu \
+                            else self._dispatch(lane, key, reqs)
+                    else:
+                        handle = lane.without_async
+                    groups.append((lane, key, reqs, handle))
+            for group in groups:
+                self._completions.put(group + (len(groups),))
                 if self.dperf is not None:
                     depth = self._completions.qsize()
                     self.dperf.set("inflight_groups_now", depth)
@@ -1098,53 +1285,40 @@ class EncodeBatcher:
             item = self._completions.get()
             if item is None:
                 return
-            key, reqs, handle, ngroups = item
+            lane, key, reqs, handle, ngroups = item
             with section("batcher.complete", d=self.daemon,
-                         lane=reqs[0].lane, reqs=len(reqs)):
+                         lane=lane.name, reqs=len(reqs)):
                 try:
-                    if handle == "dec":
-                        self._complete_group_dec(key, reqs)
-                    elif handle == "dec_cpu":
-                        self._complete_group_dec_twin(key, reqs)
-                    elif isinstance(handle, tuple) and handle \
-                            and handle[0] == "decdev":
-                        self._complete_group_dec_dev(
-                            key, reqs, handle,
-                            trust_win=(ngroups == 1))
-                    elif handle == "delta_cpu":
-                        self._complete_group_delta_twin(key, reqs)
-                    elif isinstance(handle, tuple) and handle \
-                            and handle[0] == "deltadev":
-                        self._complete_group_delta_dev(
-                            key, reqs, handle,
-                            trust_win=(ngroups == 1))
-                    elif handle == "cpu":
-                        self._complete_group_cpu(reqs)
+                    if handle == "twin":
+                        self._twin(lane, key, reqs)
+                    elif handle == "sync":
+                        self._serve_sync(lane, key, reqs)
                     else:
-                        # loss-direction learning runs on EVERY group
-                        # (raising the threshold is safe even when
-                        # sibling completions inflate dev_time — worst
-                        # case we conservatively route small batches to
-                        # the CPU twin); the win direction (lowering it)
-                        # only trusts single-group cycles
-                        self._complete_group(reqs, handle, learn=True,
-                                             trust_win=(ngroups == 1))
+                        self._join(lane, key, reqs, handle,
+                                   trust_win=(ngroups == 1))
                 except Exception:
                     # fail every rider op that has not completed yet: a
                     # worker-level fault must surface as EIO on the
                     # affected ops, never as a hang
                     self._cb_error(reqs)
 
-    def _route_to_cpu(self, key: Tuple, reqs: List[_Req]) -> bool:
-        """True when the learned crossover says this batch is too
-        small to pay the device round trip."""
-        if not self.adaptive_cpu or self._min_device_bytes <= 0:
+    def _min_bytes(self, lane: _Lane) -> float:
+        """The lane's crossover threshold: its own once its groups
+        have taught it one, encode's until then (_Lane.crossover)."""
+        cls = EncodeBatcher
+        return getattr(cls, lane.crossover) or cls._min_device_bytes
+
+    def _route(self, lane: _Lane, key: Tuple, reqs: List) -> bool:
+        """True when the group goes to the CPU twin: the learned
+        crossover says it is too small to pay the device round trip,
+        or the open breaker blocks it.  One ladder for the three
+        lanes, with a shared probe cadence and shared idle clocks —
+        the device is one machine property; the verdict's reason is
+        left in _route_reason for _note_route."""
+        thr = self._min_bytes(lane) if self.adaptive_cpu else 0
+        if thr <= 0 or lane.group_bytes(reqs) >= thr:
             self._route_reason = "device"
-            return False
-        total = sum(r.nbytes for r in reqs)
-        if total >= self._min_device_bytes:
-            self._route_reason = "device"
-            return False
+            return self._breaker_blocks()
         # idle re-probe: a device that served ZERO traffic for a
         # whole idle period gets one group as a probe IMMEDIATELY —
         # a learned CPU bias with no device activity behind it is
@@ -1163,7 +1337,7 @@ class EncodeBatcher:
         # challenged by the idle/tick probes below.
         cls = EncodeBatcher
         if 0 < cls._pinned_min_device_bytes and \
-                cls._min_device_bytes <= cls._pinned_min_device_bytes:
+                thr <= cls._pinned_min_device_bytes:
             self._route_reason = "pin"
             return True
         now = time.monotonic()
@@ -1172,7 +1346,7 @@ class EncodeBatcher:
                 now - cls._last_idle_probe_ts > self.idle_reprobe_s:
             cls._last_idle_probe_ts = now
             self._route_reason = "idle_probe"
-            return False
+            return self._breaker_blocks()
         # periodic probe: route an occasional small batch to the
         # device anyway so the threshold can come back down when the
         # link/device recovers.  The tick is class-level like the
@@ -1181,11 +1355,12 @@ class EncodeBatcher:
         # instead of each paying its own 1-in-N device round trips
         # (per-instance ticks also mean a primary seeing few ops
         # never probes at all)
-        EncodeBatcher._probe_tick += 1
-        blocked = EncodeBatcher._probe_tick % self.probe_interval != 0
-        self._route_reason = "learned" if blocked else "tick_probe"
-        return blocked
-
+        cls._probe_tick += 1
+        if cls._probe_tick % self.probe_interval != 0:
+            self._route_reason = "learned"
+            return True
+        self._route_reason = "tick_probe"
+        return self._breaker_blocks()
     def _breaker_blocks(self) -> bool:
         """True when the open circuit breaker routes this encode
         group to the coalesced CPU twin.  Rides the shared probe tick
@@ -1200,25 +1375,25 @@ class EncodeBatcher:
             else "breaker_probe"
         return blocked
 
-    def _note_route(self, key: Tuple, reqs: List[_Req],
-                    to_cpu: bool) -> None:
+    def _note_route(self, lane: _Lane, key: Tuple, reqs: List,
+                    to_cpu: bool, record: bool = True) -> None:
         """Publish one routing verdict: reason-coded counter in the
-        ec_device subsystem + one flight-recorder event.  Collector
-        thread only — no locking beyond the perf counters' own."""
+        ec_device subsystem + one flight-recorder event.  Consumes
+        _route_reason, so one group's reason cannot leak into the
+        next's.  No locking beyond the perf counters' own."""
         reason = self._route_reason or \
             ("learned" if to_cpu else "device")
         self._route_reason = None
-        if self.dperf is not None and \
-                f"route_{reason}" in self.dperf._types:
-            self.dperf.inc(f"route_{reason}")
+        name = f"{lane.prefix}route_{reason}"
+        if self.dperf is not None and name in self.dperf._types:
+            self.dperf.inc(name)
         rec = self.recorder
-        if rec is not None:
-            rec.note("route", reason=reason,
+        if rec is not None and record:
+            rec.note(f"{lane.prefix}route", reason=reason,
                      to="cpu" if to_cpu else "device",
-                     bytes=sum(r.nbytes for r in reqs),
-                     reqs=len(reqs),
-                     crossover=int(EncodeBatcher._min_device_bytes))
-
+                     bytes=lane.group_bytes(reqs), reqs=len(reqs),
+                     crossover=int(self._min_bytes(lane)),
+                     **lane.route_fields(key))
     def note_prewarm_error(self, where: str, exc: BaseException) -> None:
         """A prewarm (compile + first dispatch of a pool geometry)
         failed: log it with its traceback, flight-record it, and keep
@@ -1447,951 +1622,43 @@ class EncodeBatcher:
                          n_devices=ev.get("n_devices"),
                          device_ids=ev.get("device_ids"))
 
-    def _cpu_rate(self, key: Tuple, req: _Req) -> float:
-        """CPU twin throughput for this geometry, measured once on
-        real data (bytes/sec); shared process-wide."""
-        rate = self._cpu_bps.get(key)
-        if rate is None:
-            # build the twin (which builds and loads the native
-            # library in a fresh checkout) before the clock starts
-            self.cpu_twin(req.ec_impl, req.sinfo)
-            t0 = time.monotonic()
-            self._cpu_encode(req)
-            dt = max(time.monotonic() - t0, 1e-6)
-            rate = req.nbytes / dt
-            EncodeBatcher._cpu_bps[key] = rate
-        return rate
-
-    def _complete_group_cpu(self, reqs: List[_Req]) -> None:
-        """Coalesced device-free encode: the whole group's stripes go
-        through ONE batched kernel call on the _BatchTwin (native C++
-        when available) — the coalescing win survives CPU routing."""
-        t_form = time.monotonic()
-        t_wall = time.time()
-        self._account_queue_wait(reqs, t_form)
-        for r in reqs:
-            if r.tracked is not None:
-                r.tracked.mark_event("ec:batch_dispatched")
-        chunks_list: Optional[List] = None
-        try:
-            sinfo = reqs[0].sinfo
-            k = reqs[0].ec_impl.get_data_chunk_count()
-            m = reqs[0].ec_impl.get_coding_chunk_count()
-            twin = self.cpu_twin(reqs[0].ec_impl, sinfo)
-            arrs = [r.as_array(k) for r in reqs]
-            if len(arrs) > 1:
-                batch = np.concatenate(arrs, axis=0)
-                self._note_copy(batch.nbytes, "batcher.batch_concat")
-            else:
-                batch = arrs[0]
-            parity = twin.encode_batch(batch)
-            self.cpu_calls += 1
-            # twin encode is pure compute: no transfer legs
-            self.stage_seconds["device"] += \
-                time.monotonic() - t_form
-            # twin groups still fold into the device waterfall: a
-            # coarse two-stamp ledger keyed device=-1 (host), so
-            # dump_device and the bench attribution account for every
-            # group regardless of routing.  No h2d/d2h stamps — the
-            # whole interval charges to the compute fence — and the
-            # overlap engine ignores negative device ids (a host
-            # group has no transfer to hide under compute).
-            t_done = time.time()
-            self._observe_device_ledger(
-                {"stage_acquire": t_wall, "compute_start": t_wall,
-                 "compute_done": t_done, "deliver": t_done,
-                 "device": -1, "bytes": int(batch.nbytes),
-                 "stripes": int(batch.shape[0]), "group": "encode"})
-            if self.bperf is not None:
-                self.bperf.hinc("batch_stripes", batch.shape[0])
-                self.bperf.inc("cpu_reqs", len(reqs))
-                if len(reqs) > 1:
-                    self.bperf.inc("coalesced_reqs", len(reqs))
-            if len(reqs) > 1:
-                self.reqs_coalesced += len(reqs)
-                if self.perf is not None:
-                    self.perf.inc("ec_batch_coalesced", len(reqs))
-            chunks_list = []
-            off = 0
-            for r, arr in zip(reqs, arrs):
-                p = parity[off:off + r.nstripes]
-                off += r.nstripes
-                chunks_list.append(
-                    self._shard_views(arr, p, k, m))
-        except Exception:
-            chunks_list = None
-        if chunks_list is None:
-            # twin trouble: per-request fallback (still device-free)
-            chunks_list = []
-            for r in reqs:
-                try:
-                    chunks = self._cpu_encode(r)
-                    self.cpu_calls += 1
-                except Exception:
-                    self._cb_error()
-                    chunks = None
-                chunks_list.append(chunks)
-        for r, chunks in zip(reqs, chunks_list):
-            self.reqs_total += 1
-            self.cpu_reqs += 1
-            self._deliver(r, chunks)
-
-    def _complete_group_dec(self, key: Tuple,
-                            reqs: List[_DecReq]) -> None:
-        """One batched reconstruction for every decode request of one
-        (geometry, erasure-signature) group.  Routing mirrors the
-        encode side: below the learned crossover the batch decodes on
-        the _BatchTwin (one native C++ call); above it, on the device
-        codec's signature-cached compiled kernel.  Device round trips
-        run on their OWN thread — a slow synchronous decode stalling
-        the collector would block every pending encode group behind
-        it (the encode path likewise dispatches all groups before
-        joining any)."""
-        sinfo = reqs[0].sinfo
-        total = sum(sum(ecutil.nbytes_of(v) for v in r.have.values())
-                    for r in reqs)
-        impl = None
-        if (self.adaptive_cpu and self._dec_min_bytes() > 0 and
-                total < self._dec_min_bytes()) or \
-                self._breaker_blocks():
-            try:
-                impl = self.cpu_twin(reqs[0].ec_impl, sinfo)
-            except Exception:
-                impl = None
-        on_twin = impl is not None
-        # publish the verdict (and consume _route_reason so a decode
-        # probe through the breaker cannot leak its reason into the
-        # next encode group's _note_route)
-        reason = self._route_reason
-        self._route_reason = None
-        if reason is None:
-            reason = "learned" if on_twin else "device"
-        if self.dperf is not None and \
-                f"dec_route_{reason}" in self.dperf._types:
-            self.dperf.inc(f"dec_route_{reason}")
-        if impl is None:
-            impl = reqs[0].ec_impl
-        if on_twin:
-            self._exec_group_dec(key, reqs, impl, on_twin)
-        else:
-            t = threading.Thread(
-                target=self._exec_group_dec,
-                args=(key, reqs, impl, on_twin),
-                name="ec-dec-dev", daemon=True)
-            # tracked so stop() can honor its drain contract (no
-            # continuation after the caller unmounts the store)
-            self._dec_threads = [x for x in self._dec_threads
-                                 if x.is_alive()] + [t]
-            t.start()
-
-    def _exec_group_dec(self, key: Tuple, reqs: List[_DecReq],
-                        impl, on_twin: bool) -> None:
-        sinfo = reqs[0].sinfo
-        cs = sinfo.chunk_size
-        have_ids, missing = key[2], key[3]
-        try:
-            present = {
-                s: (np.concatenate(
-                    [ecutil.as_stripe_array(r.have[s], r.nstripes,
-                                            1, cs)
-                     .reshape(r.nstripes, cs) for r in reqs], axis=0)
-                    if len(reqs) > 1 else
-                    ecutil.as_stripe_array(
-                        reqs[0].have[s], reqs[0].nstripes, 1, cs)
-                    .reshape(-1, cs))
-                for s in have_ids}
-        except Exception:
-            present = None           # malformed input, not a device
-                                     # fault: per-request fallback
-        rec = None
-        if present is not None:
-            try:
-                if not on_twin:
-                    faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
-                t0 = time.time()
-                rec = impl.decode_batch(present, cs)
-                # decode_batch is a fenced synchronous call, so the
-                # group ledger is coarse: the whole interval charges
-                # to the compute fence.  Still keyed and accumulated
-                # like encode groups so the read path shows up in
-                # the device waterfall; twin-routed groups carry
-                # device=-1 (host lane, excluded from overlap).
-                t1 = time.time()
-                led = {"stage_acquire": t0, "compute_start": t0,
-                       "compute_done": t1, "deliver": t1,
-                       "group": "decode"}
-                if on_twin:
-                    led["device"] = -1
-                self._observe_device_ledger(led)
-                if not on_twin:
-                    self._device_success()
-            except Exception as e:
-                rec = None
-                if not on_twin:
-                    self._device_failure("decode", e)
-        if rec is None:
-            # group decode trouble: per-request fallback
-            for r in reqs:
-                try:
-                    dec = ecutil.decode(sinfo, r.ec_impl, r.have,
-                                        set(r.want))
-                except Exception:
-                    self._cb_error()
-                    dec = None
-                self.dec_reqs += 1
-                self._deliver(r, dec)
-            return
-        self.dec_calls += 1
-        self.dec_reqs += len(reqs)
-        if len(reqs) > 1:
-            self.dec_coalesced += len(reqs)
-        if on_twin:
-            self.dec_cpu_reqs += len(reqs)
-        if self.perf is not None:
-            self.perf.inc("ec_dec_batch_calls")
-            if len(reqs) > 1:
-                self.perf.inc("ec_dec_batch_coalesced", len(reqs))
-        off = 0
-        for r in reqs:
-            # reconstructed shards from the batched call; wanted
-            # shards that were read directly pass through (same
-            # contract as ecutil.decode)
-            out = {}
-            for s in r.want:
-                if s in missing:
-                    # row slice of a contiguous [B, cs] batch result:
-                    # ascontiguousarray is a no-copy view here, and
-                    # the memoryview rides downstream by reference
-                    out[s] = memoryview(np.ascontiguousarray(
-                        rec[s][off:off + r.nstripes])).cast("B")
-                else:
-                    h = r.have[s]
-                    out[s] = h if isinstance(h, bytes) else \
-                        memoryview(h).cast("B")
-            off += r.nstripes
-            self._deliver(r, out)
-
-    # -- decode device pipeline (ISSUE 11 tentpole) --------------------
-    def _dec_min_bytes(self) -> float:
-        """The decode-side crossover threshold.  Decode keeps its own
-        learned value (recovery moves k survivor chunks IN per erased
-        chunk OUT, so its transfer economics differ from encode's
-        k-in/m-out), but until decode groups have taught it anything
-        it is SEEDED from the encode EWMA — the device and link are
-        the same hardware, so encode's measurement beats flying
-        blind on the first rebuild window."""
+    def _cpu_rate(self, lane: _Lane, key: Tuple, reqs: List) -> float:
+        """CPU twin throughput of the lane for this geometry (input
+        bytes/sec), measured once on the first request's real data;
+        shared process-wide."""
         cls = EncodeBatcher
-        if cls._dec_min_device_bytes > 0:
-            return cls._dec_min_device_bytes
-        return cls._min_device_bytes
-
-    def _route_dec_group(self, key: Tuple, reqs: List[_DecReq]):
-        """Collect-time routing + dispatch for one decode group.
-        Returns the completion-queue handle:
-
-        * ``("decdev", handles, t_disp, in_bytes)`` — async device
-          dispatch in flight (joined by _complete_group_dec_dev);
-        * ``"dec_cpu"`` — routed to the CPU twin (verdict already
-          published);
-        * ``"dec"`` — legacy completion-time path for codecs without
-          the async decode API (routing happens there)."""
-        impl = reqs[0].ec_impl
-        sup = getattr(impl, "decode_async_supported", None)
-        if sup is None or not hasattr(impl, "decode_batch_async"):
-            return "dec"
-        try:
-            if not sup():
-                return "dec"
-        except Exception:
-            return "dec"
-        to_cpu = self._route_to_cpu_dec(key, reqs)
-        if not to_cpu and self._breaker_blocks():
-            to_cpu = True
-        self._note_route_dec(key, reqs, to_cpu)
-        if to_cpu:
-            return "dec_cpu"
-        handle = self._dispatch_group_dec(key, reqs)
-        if handle is None:
-            return "dec_cpu"         # dispatch failed: twin serves
-        return ("decdev",) + handle
-
-    def _route_to_cpu_dec(self, key: Tuple,
-                          reqs: List[_DecReq]) -> bool:
-        """_route_to_cpu with the decode-side crossover: same
-        pin/idle-probe/tick-probe ladder (shared probe cadence and
-        idle clocks — the device is one machine property), judged
-        against _dec_min_bytes()."""
-        if not self.adaptive_cpu:
-            self._route_reason = "device"
-            return False
-        thr = self._dec_min_bytes()
-        if thr <= 0:
-            self._route_reason = "device"
-            return False
-        cs = reqs[0].sinfo.chunk_size
-        total = sum(r.nstripes * cs * len(r.have) for r in reqs)
-        if total >= thr:
-            self._route_reason = "device"
-            return False
-        cls = EncodeBatcher
-        if 0 < cls._pinned_min_device_bytes and \
-                thr <= cls._pinned_min_device_bytes:
-            self._route_reason = "pin"
-            return True
-        now = time.monotonic()
-        if self.idle_reprobe_s > 0 and \
-                now - cls._last_device_ts > self.idle_reprobe_s and \
-                now - cls._last_idle_probe_ts > self.idle_reprobe_s:
-            cls._last_idle_probe_ts = now
-            self._route_reason = "idle_probe"
-            return False
-        cls._probe_tick += 1
-        blocked = cls._probe_tick % self.probe_interval != 0
-        self._route_reason = "learned" if blocked else "tick_probe"
-        return blocked
-
-    def _note_route_dec(self, key: Tuple, reqs: List[_DecReq],
-                        to_cpu: bool) -> None:
-        """Publish one decode routing verdict (dec_route_* counter +
-        flight-recorder event).  Collector thread only."""
-        reason = self._route_reason or \
-            ("learned" if to_cpu else "device")
-        self._route_reason = None
-        if self.dperf is not None and \
-                f"dec_route_{reason}" in self.dperf._types:
-            self.dperf.inc(f"dec_route_{reason}")
-        rec = self.recorder
-        if rec is not None:
-            cs = reqs[0].sinfo.chunk_size
-            rec.note("dec_route", reason=reason,
-                     to="cpu" if to_cpu else "device",
-                     bytes=sum(r.nstripes * cs * len(r.have)
-                               for r in reqs),
-                     reqs=len(reqs),
-                     crossover=int(self._dec_min_bytes()))
-
-    def _dispatch_group_dec(self, key: Tuple, reqs: List[_DecReq]):
-        """Issue the async device decode for one (geometry,
-        erasure-signature) group: concat every request's survivor
-        chunks into one [B, cs] stack per shard id and dispatch
-        tile-by-tile through decode_batch_async (signature-cached
-        combined recovery rows, StagingPool staging, full seven-phase
-        ledger).  Returns (handles, t_disp, in_bytes) or None on
-        dispatch failure."""
-        t_form = time.monotonic()
-        waited = self._account_queue_wait(reqs, t_form)
-        with section("batcher.dispatch", lane=reqs[0].lane,
-                     reqs=len(reqs),
-                     stripes=sum(r.nstripes for r in reqs),
-                     queue_wait_us=waited * 1e6):
-            sinfo = reqs[0].sinfo
-            cs = sinfo.chunk_size
-            have_ids = key[2]
-            try:
-                present = {
-                    s: (np.concatenate(
-                        [ecutil.as_stripe_array(r.have[s], r.nstripes,
-                                                1, cs)
-                         .reshape(r.nstripes, cs) for r in reqs], axis=0)
-                        if len(reqs) > 1 else
-                        ecutil.as_stripe_array(
-                            reqs[0].have[s], reqs[0].nstripes, 1, cs)
-                        .reshape(-1, cs))
-                    for s in have_ids}
-                if len(reqs) > 1:
-                    self._note_copy(sum(v.nbytes
-                                        for v in present.values()),
-                                    "batcher.dec_batch_concat")
-            except Exception:
-                # malformed request payload: NOT a device fault (must not
-                # trip the breaker) — the twin path fails the bad rider
-                # per-request and still serves its group-mates
-                return None
-            nstripes = sum(r.nstripes for r in reqs)
-            in_bytes = sum(v.nbytes for v in present.values())
-            tile = max(1, self.max_stripes)
-            handles = err = None
-            delay = self.device_retry_s
-            for attempt in range(3):
-                try:
-                    faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
-                    handles = [
-                        reqs[0].ec_impl.decode_batch_async(
-                            {s: v[i:i + tile]
-                             for s, v in present.items()}, cs)
-                        for i in range(0, nstripes, tile)]
-                    break
-                except Exception as e:
-                    handles, err = None, e
-                    if attempt < 2 and delay > 0:
-                        time.sleep(min(delay, 0.1))
-                        delay *= 2
-            if handles is None:
-                self._device_failure("dispatch", err)
-                return None
-            t_disp = time.monotonic()
-            EncodeBatcher._last_device_ts = t_disp
-            self.stage_seconds["batch_form"] += t_disp - t_form
-            if self.bperf is not None:
-                self.bperf.hinc("batch_stripes", nstripes)
-                self.bperf.inc("h2d_bytes", in_bytes)
-            return (handles, t_disp, in_bytes)
-
-    def _complete_group_dec_twin(self, key: Tuple,
-                                 reqs: List[_DecReq]) -> None:
-        """Execute a decode group the collect-time router already
-        sent to the CPU (verdict published there — no re-routing)."""
-        impl = None
-        try:
-            impl = self.cpu_twin(reqs[0].ec_impl, reqs[0].sinfo)
-        except Exception:
-            impl = None
-        on_twin = impl is not None
-        if impl is None:
-            impl = reqs[0].ec_impl
-        self._exec_group_dec(key, reqs, impl, on_twin)
-
-    def _complete_group_dec_dev(self, key: Tuple,
-                                reqs: List[_DecReq], handle,
-                                trust_win: bool = True) -> None:
-        """Join one in-flight device decode group: the decode twin of
-        _complete_group.  Harvests the seven-phase ledgers, folds h2d
-        samples into the link EWMA, teaches the decode crossover, and
-        splits the reconstructed [B, cs] stacks back to each rider's
-        callback.  Device trouble falls the WHOLE group back to the
-        batched CPU twin — zero client errors."""
-        _tag, handles, t_disp, in_bytes = handle
-        sinfo = reqs[0].sinfo
-        missing = key[3]
-        rec = None
-        dev_time = None
-        out_bytes = 0
-        try:
-            faultlib.registry().hit(faultlib.DEVICE_COMPLETION)
-            parts = [h.wait() for h in handles]
-            rec = parts[0] if len(parts) == 1 else {
-                e: np.concatenate([p[e] for p in parts], axis=0)
-                for e in parts[0]}
-            out_bytes = sum(v.nbytes for v in rec.values())
-            dev_time = time.monotonic() - t_disp
-            self._device_success()
-            for h in handles:
-                hb = getattr(h, "h2d_bytes", 0)
-                hs = getattr(h, "h2d_seconds", 0.0)
-                if hb and hs > 0:
-                    bps = hb / hs
-                    EncodeBatcher._h2d_bps = bps \
-                        if EncodeBatcher._h2d_bps <= 0 else (
-                            0.7 * EncodeBatcher._h2d_bps + 0.3 * bps)
-        except Exception as e:
-            rec = None
-            self._device_failure("completion", e)
-        if rec is None:
-            self._complete_group_dec_twin(key, reqs)
-            return
-        if self.adaptive_cpu:
-            self._learn_crossover_dec(key, reqs, dev_time, in_bytes,
-                                      out_bytes, trust_win=trust_win)
-        self.dec_calls += 1
-        self.dec_reqs += len(reqs)
-        if len(reqs) > 1:
-            self.dec_coalesced += len(reqs)
-        if self.perf is not None:
-            self.perf.inc("ec_dec_batch_calls")
-            if len(reqs) > 1:
-                self.perf.inc("ec_dec_batch_coalesced", len(reqs))
-        # fenced-window stage split, same link-rate model as encode
-        h2d_s = d2h_s = 0.0
-        if self._h2d_bps > 0:
-            h2d_s = min(dev_time, in_bytes / self._h2d_bps)
-            d2h_s = min(dev_time - h2d_s, out_bytes / self._h2d_bps)
-        self.stage_seconds["h2d"] += h2d_s
-        self.stage_seconds["d2h"] += d2h_s
-        self.stage_seconds["device"] += max(
-            0.0, dev_time - h2d_s - d2h_s)
-        if self.bperf is not None:
-            self.bperf.hinc("dispatch_ms", dev_time * 1e3)
-            self.bperf.inc("d2h_bytes", out_bytes)
-            self.bperf.inc("device_reqs", len(reqs))
-            if len(reqs) > 1:
-                self.bperf.inc("coalesced_reqs", len(reqs))
-        for h in handles:
-            # a mesh dispatch carries one ledger clone per chip
-            # (AsyncBatch.ledgers); single-chip keeps the scalar
-            leds = getattr(h, "ledgers", None) or \
-                [getattr(h, "ledger", None)]
-            for led in leds:
-                if led is not None:
-                    led["group"] = "decode"
-                self._observe_device_ledger(led)
-        self._publish_device_telemetry(reqs[0].ec_impl)
-        off = 0
-        for r in reqs:
-            out = {}
-            for s in r.want:
-                if s in missing:
-                    out[s] = memoryview(np.ascontiguousarray(
-                        rec[s][off:off + r.nstripes])).cast("B")
-                else:
-                    hv = r.have[s]
-                    out[s] = hv if isinstance(hv, bytes) else \
-                        memoryview(hv).cast("B")
-            off += r.nstripes
-            self._deliver(r, out)
-
-    def _cpu_rate_dec(self, key: Tuple,
-                      reqs: List[_DecReq]) -> float:
-        """CPU twin DECODE throughput for this geometry (bytes of
-        survivor input per second), measured once on real data;
-        shared process-wide like _cpu_rate."""
-        rk = ("dec", key[1])
-        rate = EncodeBatcher._cpu_bps.get(rk)
+        rk, geom = lane.bucket(key), lane.geometry(key)
+        rate = cls._cpu_bps.get(rk)
         if rate is None:
-            r = reqs[0]
-            cs = r.sinfo.chunk_size
+            one = reqs[:1]
             try:
-                twin = self.cpu_twin(r.ec_impl, r.sinfo)
-                present = {
-                    s: ecutil.as_stripe_array(r.have[s], r.nstripes,
-                                              1, cs)
-                    .reshape(r.nstripes, cs) for s in r.have}
+                # build the twin (which builds and loads the native
+                # library in a fresh checkout) and view the request
+                # before the clock starts
+                twin = self.cpu_twin(one[0].ec_impl, one[0].sinfo)
+                stack = lane.form(key, one)
                 t0 = time.monotonic()
-                twin.decode_batch(present, cs)
+                lane.probe(self, twin, key, one, stack)
                 dt = max(time.monotonic() - t0, 1e-6)
-                rate = sum(v.nbytes for v in present.values()) / dt
+                rate = _nbytes(stack) / dt
             except Exception:
-                # no twin: fall back to the encode-side measurement
-                # (same matmul cost model) rather than guessing
-                rate = EncodeBatcher._cpu_bps.get(key[1], 0.0)
-            EncodeBatcher._cpu_bps[rk] = rate
+                # no twin: a lane seeded from encode borrows encode's
+                # measurement (same matmul cost model) rather than
+                # guessing; encode itself has nothing to borrow
+                if rk == geom:
+                    raise
+                rate = cls._cpu_bps.get(geom, 0.0)
+            cls._cpu_bps[rk] = rate
         return rate
 
-    def _learn_crossover_dec(self, key: Tuple, reqs: List[_DecReq],
-                             dev_time: float, in_bytes: int,
-                             out_bytes: int,
-                             trust_win: bool = True) -> None:
-        """_learn_crossover for decode groups: the same pipelined
-        cost model (max of the h2d/compute/d2h legs vs the CPU twin's
-        prediction) and compile/outlier rejection, but moving the
-        DECODE-side threshold and its own per-geometry device-rate
-        EWMA bucket."""
-        try:
-            cls = EncodeBatcher
-            rk = ("dec", key[1])
-            cpu_rate = max(self._cpu_rate_dec(key, reqs), 1.0)
-            cpu_pred = in_bytes / cpu_rate
-            h2d_s = d2h_s = 0.0
-            if cls._h2d_bps > 0:
-                h2d_s = min(dev_time, in_bytes / cls._h2d_bps)
-                d2h_s = min(max(0.0, dev_time - h2d_s),
-                            out_bytes / cls._h2d_bps)
-            compute_s = max(0.0, dev_time - h2d_s - d2h_s)
-            rate = cls._dev_bps.get(rk, 0.0)
-            if rate > 0 and compute_s > 5.0 * (in_bytes / rate) \
-                    and compute_s > 1e-3:
-                return               # compile/stall outlier
-            if compute_s > 0:
-                bps = in_bytes / compute_s
-                cls._dev_bps[rk] = bps if rate <= 0 else (
-                    0.7 * rate + 0.3 * bps)
-            dev_pipe = max(h2d_s, compute_s, d2h_s) \
-                if (h2d_s or d2h_s) else dev_time
-            cur = self._dec_min_bytes()
-            if dev_pipe > cpu_pred:
-                cls._dec_min_device_bytes = max(
-                    cur, dev_pipe * cpu_rate / 2, self.crossover_min)
-            elif trust_win and dev_pipe < cpu_pred / 2 and cur > 0:
-                cls._dec_min_device_bytes = min(cur, in_bytes / 2)
-        except Exception:
-            pass                     # learning is best-effort
-
-    # -- parity-delta device pipeline (sub-stripe overwrite RMW) -------
-    def _delta_min_bytes(self) -> float:
-        """The parity-delta crossover threshold.  Delta keeps its own
-        learned value (a delta call moves D dirty columns IN per m
-        parity columns OUT — different transfer economics from both
-        encode and decode), seeded from the encode EWMA until delta
-        groups have taught it anything, same as the decode side."""
-        cls = EncodeBatcher
-        if cls._delta_min_device_bytes > 0:
-            return cls._delta_min_device_bytes
-        return cls._min_device_bytes
-
-    def _route_delta_group(self, key: Tuple,
-                           reqs: List["_DeltaReq"]):
-        """Collect-time routing + dispatch for one parity-delta
-        group.  Returns the completion-queue handle:
-
-        * ``("deltadev", handles, t_disp, in_bytes)`` — async device
-          dispatch in flight (joined by _complete_group_delta_dev);
-        * ``"delta_cpu"`` — routed to (or falling back on) the CPU
-          twin's delta_parity."""
-        impl = reqs[0].ec_impl
-        sup = getattr(impl, "delta_async_supported", None)
-        if sup is None or \
-                not hasattr(impl, "delta_encode_batch_async"):
-            return "delta_cpu"
-        try:
-            if not sup():
-                return "delta_cpu"
-        except Exception:
-            return "delta_cpu"
-        to_cpu = self._route_to_cpu_delta(key, reqs)
-        if not to_cpu and self._breaker_blocks():
-            to_cpu = True
-        self._note_route_delta(key, reqs, to_cpu)
-        if to_cpu:
-            return "delta_cpu"
-        handle = self._dispatch_group_delta(key, reqs)
-        if handle is None:
-            return "delta_cpu"       # dispatch failed: twin serves
-        return ("deltadev",) + handle
-
-    def _route_to_cpu_delta(self, key: Tuple,
-                            reqs: List["_DeltaReq"]) -> bool:
-        """_route_to_cpu with the delta-side crossover: same
-        pin/idle-probe/tick-probe ladder (shared probe cadence and
-        idle clocks), judged against _delta_min_bytes() over the
-        group's dirty-column input bytes."""
-        if not self.adaptive_cpu:
-            self._route_reason = "device"
-            return False
-        thr = self._delta_min_bytes()
-        if thr <= 0:
-            self._route_reason = "device"
-            return False
-        total = sum(r.nbytes for r in reqs)
-        if total >= thr:
-            self._route_reason = "device"
-            return False
-        cls = EncodeBatcher
-        if 0 < cls._pinned_min_device_bytes and \
-                thr <= cls._pinned_min_device_bytes:
-            self._route_reason = "pin"
-            return True
-        now = time.monotonic()
-        if self.idle_reprobe_s > 0 and \
-                now - cls._last_device_ts > self.idle_reprobe_s and \
-                now - cls._last_idle_probe_ts > self.idle_reprobe_s:
-            cls._last_idle_probe_ts = now
-            self._route_reason = "idle_probe"
-            return False
-        cls._probe_tick += 1
-        blocked = cls._probe_tick % self.probe_interval != 0
-        self._route_reason = "learned" if blocked else "tick_probe"
-        return blocked
-
-    def _note_route_delta(self, key: Tuple, reqs: List["_DeltaReq"],
-                          to_cpu: bool) -> None:
-        """Publish one delta routing verdict (delta_route_* counter
-        + flight-recorder event).  Collector thread only."""
-        reason = self._route_reason or \
-            ("learned" if to_cpu else "device")
-        self._route_reason = None
-        if self.dperf is not None and \
-                f"delta_route_{reason}" in self.dperf._types:
-            self.dperf.inc(f"delta_route_{reason}")
-        rec = self.recorder
-        if rec is not None:
-            rec.note("delta_route", reason=reason,
-                     to="cpu" if to_cpu else "device",
-                     bytes=sum(r.nbytes for r in reqs),
-                     reqs=len(reqs),
-                     dirty_cols=len(key[2]),
-                     crossover=int(self._delta_min_bytes()))
-
-    def _dispatch_group_delta(self, key: Tuple,
-                              reqs: List["_DeltaReq"]):
-        """Issue the async device delta-matmul for one (geometry,
-        dirty-column signature) group: concat every request's
-        [nstripes, D, chunk] delta stack and dispatch tile-by-tile
-        through delta_encode_batch_async (prewarmed compiled shape,
-        StagingPool staging, full seven-phase ledger).  Returns
-        (handles, t_disp, in_bytes) or None on dispatch failure."""
-        t_form = time.monotonic()
-        waited = self._account_queue_wait(reqs, t_form)
-        with section("batcher.dispatch", lane=reqs[0].lane,
-                     reqs=len(reqs),
-                     stripes=sum(r.nstripes for r in reqs),
-                     queue_wait_us=waited * 1e6):
-            cols = key[2]
-            try:
-                arrs = [r.as_array(len(cols)) for r in reqs]
-                if len(arrs) > 1:
-                    batch = np.concatenate(arrs, axis=0)
-                    self._note_copy(batch.nbytes,
-                                    "batcher.delta_batch_concat")
-                else:
-                    batch = np.asarray(arrs[0])
-            except Exception:
-                # malformed request payload: NOT a device fault (must not
-                # trip the breaker) — the twin path fails the bad rider
-                # per-request and still serves its group-mates
-                return None
-            in_bytes = batch.nbytes
-            tile = max(1, self.max_stripes)
-            handles = err = None
-            delay = self.device_retry_s
-            for attempt in range(3):
-                try:
-                    faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
-                    handles = [
-                        reqs[0].ec_impl.delta_encode_batch_async(
-                            batch[i:i + tile], cols)
-                        for i in range(0, batch.shape[0], tile)]
-                    break
-                except Exception as e:
-                    handles, err = None, e
-                    if attempt < 2 and delay > 0:
-                        time.sleep(min(delay, 0.1))
-                        delay *= 2
-            if handles is None:
-                self._device_failure("dispatch", err)
-                return None
-            t_disp = time.monotonic()
-            EncodeBatcher._last_device_ts = t_disp
-            self.stage_seconds["batch_form"] += t_disp - t_form
-            if self.bperf is not None:
-                self.bperf.hinc("batch_stripes", batch.shape[0])
-                self.bperf.inc("h2d_bytes", in_bytes)
-            for r in reqs:
-                if r.tracked is not None:
-                    r.tracked.mark_event("ec:delta_dispatched")
-            return (handles, t_disp, in_bytes)
-
-    def _complete_group_delta_twin(self, key: Tuple,
-                                   reqs: List["_DeltaReq"]) -> None:
-        """Coalesced device-free Δparity: the whole group's delta
-        stripes go through ONE CodecCore.delta_parity call on the
-        CPU twin (native GF kernels when available) — the coalescing
-        win survives CPU routing, like _complete_group_cpu."""
-        t_form = time.monotonic()
-        t_wall = time.time()
-        self._account_queue_wait(reqs, t_form)
-        cols = key[2]
-        k = reqs[0].ec_impl.get_data_chunk_count()
-        parity = None
-        arrs = None
-        try:
-            twin = self.cpu_twin(reqs[0].ec_impl, reqs[0].sinfo)
-            arrs = [r.as_array(len(cols)) for r in reqs]
-            if len(arrs) > 1:
-                batch = np.concatenate(arrs, axis=0)
-                self._note_copy(batch.nbytes,
-                                "batcher.delta_batch_concat")
-            else:
-                batch = np.asarray(arrs[0])
-            parity = twin.core.delta_parity(
-                np.asarray(batch, dtype=np.uint8), cols)
-        except Exception:
-            parity = None
-        if parity is None:
-            # twin trouble: per-request fallback (still device-free)
-            for r in reqs:
-                try:
-                    out = self._delta_inline(r.ec_impl, r.sinfo,
-                                             r.delta, cols)
-                except Exception:
-                    self._cb_error()
-                    out = None
-                self.delta_reqs += 1
-                self.delta_cpu_reqs += 1
-                self._deliver(r, out)
-            return
-        self.delta_calls += 1
-        self.cpu_calls += 1
-        self.delta_cpu_reqs += len(reqs)
-        self.stage_seconds["device"] += time.monotonic() - t_form
-        # twin groups still fold into the device waterfall: coarse
-        # two-stamp host-lane ledger, same idiom as the encode twin
-        t_done = time.time()
-        self._observe_device_ledger(
-            {"stage_acquire": t_wall, "compute_start": t_wall,
-             "compute_done": t_done, "deliver": t_done,
-             "device": -1, "bytes": int(sum(r.nbytes for r in reqs)),
-             "stripes": int(sum(r.nstripes for r in reqs)),
-             "group": "delta"})
-        if self.bperf is not None:
-            self.bperf.hinc("batch_stripes",
-                            sum(r.nstripes for r in reqs))
-            self.bperf.inc("cpu_reqs", len(reqs))
-            if len(reqs) > 1:
-                self.bperf.inc("coalesced_reqs", len(reqs))
-        if len(reqs) > 1:
-            self.delta_coalesced += len(reqs)
-        self._deliver_delta(reqs, parity, k)
-
-    def _complete_group_delta_dev(self, key: Tuple,
-                                  reqs: List["_DeltaReq"], handle,
-                                  trust_win: bool = True) -> None:
-        """Join one in-flight device delta group: harvest the
-        seven-phase ledgers, fold h2d samples into the link EWMA,
-        teach the delta crossover, and split the [B, m, chunk]
-        Δparity stack back to each rider.  Device trouble falls the
-        WHOLE group back to the batched CPU twin — zero client
-        errors."""
-        _tag, handles, t_disp, in_bytes = handle
-        k = reqs[0].ec_impl.get_data_chunk_count()
-        parity = None
-        dev_time = None
-        out_bytes = 0
-        try:
-            faultlib.registry().hit(faultlib.DEVICE_COMPLETION)
-            parts = [np.asarray(h.wait()) for h in handles]
-            parity = parts[0] if len(parts) == 1 \
-                else np.concatenate(parts, axis=0)
-            out_bytes = parity.nbytes
-            dev_time = time.monotonic() - t_disp
-            self._device_success()
-            for h in handles:
-                hb = getattr(h, "h2d_bytes", 0)
-                hs = getattr(h, "h2d_seconds", 0.0)
-                if hb and hs > 0:
-                    bps = hb / hs
-                    EncodeBatcher._h2d_bps = bps \
-                        if EncodeBatcher._h2d_bps <= 0 else (
-                            0.7 * EncodeBatcher._h2d_bps + 0.3 * bps)
-        except Exception as e:
-            parity = None
-            self._device_failure("completion", e)
-        if parity is None:
-            self._complete_group_delta_twin(key, reqs)
-            return
-        if self.adaptive_cpu:
-            self._learn_crossover_delta(key, reqs, dev_time,
-                                        in_bytes, out_bytes,
-                                        trust_win=trust_win)
-        self.delta_calls += 1
-        if len(reqs) > 1:
-            self.delta_coalesced += len(reqs)
-        if self.perf is not None:
-            self.perf.inc("ec_delta_batch_calls")
-            if len(reqs) > 1:
-                self.perf.inc("ec_delta_batch_coalesced", len(reqs))
-        # fenced-window stage split, same link-rate model as decode
-        h2d_s = d2h_s = 0.0
-        if self._h2d_bps > 0:
-            h2d_s = min(dev_time, in_bytes / self._h2d_bps)
-            d2h_s = min(dev_time - h2d_s, out_bytes / self._h2d_bps)
-        self.stage_seconds["h2d"] += h2d_s
-        self.stage_seconds["d2h"] += d2h_s
-        self.stage_seconds["device"] += max(
-            0.0, dev_time - h2d_s - d2h_s)
-        if self.bperf is not None:
-            self.bperf.hinc("dispatch_ms", dev_time * 1e3)
-            self.bperf.inc("d2h_bytes", out_bytes)
-            self.bperf.inc("device_reqs", len(reqs))
-            if len(reqs) > 1:
-                self.bperf.inc("coalesced_reqs", len(reqs))
-        for h in handles:
-            leds = getattr(h, "ledgers", None) or \
-                [getattr(h, "ledger", None)]
-            for led in leds:
-                if led is not None:
-                    led["group"] = "delta"
-                self._observe_device_ledger(led)
-        self._publish_device_telemetry(reqs[0].ec_impl)
-        self._deliver_delta(reqs, parity, k)
-
-    def _deliver_delta(self, reqs: List["_DeltaReq"],
-                       parity: np.ndarray, k: int) -> None:
-        """Split a [B, m, chunk] Δparity stack back per rider and
-        fire callbacks with {parity_shard_index: Δparity bytes}.
-        The per-parity column gathers are the one unavoidable copy
-        (the stack interleaves shards) — the memoryviews then ride
-        by reference into the xor_write sub-transactions."""
-        m = parity.shape[1]
-        off = 0
-        copied = 0
-        for r in reqs:
-            p = parity[off:off + r.nstripes]
-            off += r.nstripes
-            out = {}
-            for j in range(m):
-                src = p[:, j]
-                col = np.ascontiguousarray(src)
-                if col is not src:
-                    copied += col.nbytes
-                out[k + j] = memoryview(col).cast("B")
-            self.delta_reqs += 1
-            self._deliver(r, out)
-        if copied:
-            self._note_copy(copied, "batcher.delta_shard_gather")
-
-    def _cpu_rate_delta(self, key: Tuple,
-                        reqs: List["_DeltaReq"]) -> float:
-        """CPU twin Δparity throughput for this geometry (bytes of
-        dirty-column input per second), measured once on real data;
-        shared process-wide like _cpu_rate.  One bucket per geometry
-        (not per dirty signature): the GF matmul's bytes/s is nearly
-        independent of D — compute and input both scale with D."""
-        rk = ("delta", key[1])
-        rate = EncodeBatcher._cpu_bps.get(rk)
-        if rate is None:
-            r = reqs[0]
-            cols = key[2]
-            try:
-                twin = self.cpu_twin(r.ec_impl, r.sinfo)
-                arr = np.asarray(r.as_array(len(cols)),
-                                 dtype=np.uint8)
-                t0 = time.monotonic()
-                twin.core.delta_parity(arr, cols)
-                dt = max(time.monotonic() - t0, 1e-6)
-                rate = r.nbytes / dt
-            except Exception:
-                # no twin: fall back to the encode-side measurement
-                # (same matmul cost model) rather than guessing
-                rate = EncodeBatcher._cpu_bps.get(key[1], 0.0)
-            EncodeBatcher._cpu_bps[rk] = rate
-        return rate
-
-    def _learn_crossover_delta(self, key: Tuple,
-                               reqs: List["_DeltaReq"],
-                               dev_time: float, in_bytes: int,
-                               out_bytes: int,
-                               trust_win: bool = True) -> None:
-        """_learn_crossover for delta groups: same pipelined cost
-        model (max of the h2d/compute/d2h legs vs the CPU twin's
-        prediction) and compile/outlier rejection, moving the
-        DELTA-side threshold and its own per-geometry device-rate
-        EWMA bucket."""
-        try:
-            cls = EncodeBatcher
-            rk = ("delta", key[1])
-            cpu_rate = max(self._cpu_rate_delta(key, reqs), 1.0)
-            cpu_pred = in_bytes / cpu_rate
-            h2d_s = d2h_s = 0.0
-            if cls._h2d_bps > 0:
-                h2d_s = min(dev_time, in_bytes / cls._h2d_bps)
-                d2h_s = min(max(0.0, dev_time - h2d_s),
-                            out_bytes / cls._h2d_bps)
-            compute_s = max(0.0, dev_time - h2d_s - d2h_s)
-            rate = cls._dev_bps.get(rk, 0.0)
-            if rate > 0 and compute_s > 5.0 * (in_bytes / rate) \
-                    and compute_s > 1e-3:
-                return               # compile/stall outlier
-            if compute_s > 0:
-                bps = in_bytes / compute_s
-                cls._dev_bps[rk] = bps if rate <= 0 else (
-                    0.7 * rate + 0.3 * bps)
-            dev_pipe = max(h2d_s, compute_s, d2h_s) \
-                if (h2d_s or d2h_s) else dev_time
-            cur = self._delta_min_bytes()
-            if dev_pipe > cpu_pred:
-                cls._delta_min_device_bytes = max(
-                    cur, dev_pipe * cpu_rate / 2, self.crossover_min)
-            elif trust_win and dev_pipe < cpu_pred / 2 and cur > 0:
-                cls._delta_min_device_bytes = min(cur, in_bytes / 2)
-        except Exception:
-            pass                     # learning is best-effort
-
-    def _learn_crossover(self, reqs: List[_Req],
-                         dev_time: float,
+    def _learn_crossover(self, lane: _Lane, key: Tuple, reqs: List,
+                         dev_time: float, in_bytes: int,
+                         out_bytes: int,
                          trust_win: bool = True) -> None:
         """Compare the device's PIPELINED cost model against the CPU
-        twin's predicted time for the same bytes and move the routing
-        threshold: lost -> raise it past this batch size; won big ->
-        lower it.
+        twin's predicted time for the same bytes and move the lane's
+        routing threshold: lost -> raise it past this batch size; won
+        big -> lower it.
 
         Two properties matter here (both were misrouting bugs):
 
@@ -2408,51 +1675,348 @@ class EncodeBatcher:
           outlier, not a measurement."""
         try:
             cls = EncodeBatcher
-            key = _geometry_key(reqs[0].ec_impl, reqs[0].sinfo)
-            total = sum(r.nbytes for r in reqs)
-            m_over_k = (reqs[0].ec_impl.get_coding_chunk_count()
-                        / max(1, reqs[0].ec_impl.get_data_chunk_count()))
-            cpu_rate = max(self._cpu_rate(key, reqs[0]), 1.0)
-            cpu_pred = total / cpu_rate
+            rk = lane.bucket(key)
+            cpu_rate = max(self._cpu_rate(lane, key, reqs), 1.0)
+            cpu_pred = in_bytes / cpu_rate
             # split the fenced window into transfer legs (measured
             # warm link rate) and the compute remainder
             h2d_s = d2h_s = 0.0
             if cls._h2d_bps > 0:
-                h2d_s = min(dev_time, total / cls._h2d_bps)
+                h2d_s = min(dev_time, in_bytes / cls._h2d_bps)
                 d2h_s = min(max(0.0, dev_time - h2d_s),
-                            total * m_over_k / cls._h2d_bps)
+                            out_bytes / cls._h2d_bps)
             compute_s = max(0.0, dev_time - h2d_s - d2h_s)
             # compile/outlier rejection BEFORE the EWMA absorbs it:
             # against this geometry's steady-state compute rate, a
             # 5x-slower call is a one-off (jit compile, allocator
             # stall, scheduler hiccup), not the device's cost
-            rate = cls._dev_bps.get(key, 0.0)
-            if rate > 0 and compute_s > 5.0 * (total / rate) \
+            rate = cls._dev_bps.get(rk, 0.0)
+            if rate > 0 and compute_s > 5.0 * (in_bytes / rate) \
                     and compute_s > 1e-3:
                 return
             if compute_s > 0:
-                bps = total / compute_s
-                cls._dev_bps[key] = bps if rate <= 0 else (
+                bps = in_bytes / compute_s
+                cls._dev_bps[rk] = bps if rate <= 0 else (
                     0.7 * rate + 0.3 * bps)
             # the PIPELINED device cost: legs overlap across batches,
             # so the sustained per-batch cost is the slowest leg
             dev_pipe = max(h2d_s, compute_s, d2h_s) \
                 if (h2d_s or d2h_s) else dev_time
+            cur = self._min_bytes(lane)
             if dev_pipe > cpu_pred:
                 # the device LOST even with overlap credited: set the
                 # crossover where the CPU would have taken as long as
                 # this call's bottleneck leg (one losing measurement
                 # teaches the whole region below it, not just 2x this
                 # batch — bursts must not need a convergence loop)
-                cls._min_device_bytes = max(
-                    self._min_device_bytes,
-                    dev_pipe * cpu_rate / 2, self.crossover_min)
-            elif trust_win and dev_pipe < cpu_pred / 2 and \
-                    self._min_device_bytes > 0:
-                cls._min_device_bytes = min(
-                    self._min_device_bytes, total / 2)
+                setattr(cls, lane.crossover, max(
+                    cur, dev_pipe * cpu_rate / 2, self.crossover_min))
+            elif trust_win and dev_pipe < cpu_pred / 2 and cur > 0:
+                setattr(cls, lane.crossover, min(cur, in_bytes / 2))
         except Exception:
             pass                     # learning is best-effort
+
+    def _bump(self, *attrs: str, n: int = 1) -> None:
+        for a in attrs:
+            setattr(self, a, getattr(self, a) + n)
+
+    def _mark(self, reqs: List, event: Optional[str]) -> None:
+        if event is not None:
+            for r in reqs:
+                if r.tracked is not None:
+                    r.tracked.mark_event(event)
+
+    def _dispatch(self, lane: _Lane, key: Tuple, reqs: List):
+        """Issue the async device calls for one group: stack every
+        request (lane.form) and launch tile by tile on the plug-in's
+        async entry (signature-cached rows, StagingPool staging, full
+        seven-phase ledger).  Returns (tiles, t_disp, in_bytes), or
+        None on a dispatch failure (the join then falls back).
+        On a multi-device host the backend's staged dispatch itself
+        lays each group out with a NamedSharding(dp, None, sp) over
+        the device mesh (jax_engine._staged_put + parallel/mesh.py
+        kernels), so this production path rides every local chip —
+        one dispatch is still ONE sharded GF matmul, and the ledger
+        fans out per chip (AsyncBatch.ledgers)."""
+        t_form = time.monotonic()
+        waited = self._account_queue_wait(reqs, t_form)
+        nstripes = sum(r.nstripes for r in reqs)
+        with section("batcher.dispatch", lane=lane.name,
+                     reqs=len(reqs), stripes=nstripes,
+                     queue_wait_us=waited * 1e6):
+            try:
+                stack = lane.form(key, reqs)
+                in_bytes = _nbytes(stack)
+                if len(reqs) > 1:
+                    self._note_copy(in_bytes, lane.concat_site)
+            except Exception:
+                # malformed request payload/geometry: NOT a device
+                # fault (must not trip the breaker) — the fallback
+                # fails the bad rider per-request and still serves
+                # its group-mates
+                return None
+            # tile oversized batches at max_stripes: bounds per-call
+            # device memory AND caps the largest compiled batch shape
+            # at bucket(max_stripes) — the shape prewarm() compiles —
+            # so a burst can never hit a never-seen (slow-compiling)
+            # shape mid-benchmark.  All tiles dispatch before any
+            # wait: h2d/MXU/d2h still overlap tile-to-tile.
+            tile = max(1, self.max_stripes)
+            tiles = err = None
+            delay = self.device_retry_s
+            for attempt in range(3):
+                try:
+                    faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
+                    tiles = [lane.launch(key, reqs, stack, i, i + tile)
+                             for i in range(0, nstripes, tile)]
+                    break
+                except Exception as e:
+                    # classified device dispatch failure: transient until
+                    # proven otherwise — retry with capped backoff before
+                    # charging the breaker
+                    tiles, err = None, e
+                    if attempt < 2 and delay > 0:
+                        time.sleep(min(delay, 0.1))
+                        delay *= 2
+            if tiles is None:
+                self._device_failure("dispatch", err)
+                return None
+            t_disp = time.monotonic()
+            EncodeBatcher._last_device_ts = t_disp
+            self.stage_seconds["batch_form"] += t_disp - t_form
+            if self.bperf is not None:
+                self.bperf.hinc("batch_stripes", nstripes)
+                self.bperf.inc("h2d_bytes", in_bytes)
+            self._mark(reqs, lane.dispatch_event)
+            return (tiles, t_disp, in_bytes)
+
+    def _join(self, lane: _Lane, key: Tuple, reqs: List, handle,
+              trust_win: bool = True) -> None:
+        """Join one in-flight device group (``handle`` from _dispatch;
+        None when the dispatch failed): harvest the seven-phase
+        ledgers, fold h2d samples into the link EWMA, teach the
+        lane's crossover, and split the result back to each rider's
+        callback.  Loss-direction learning runs on EVERY group
+        (raising the threshold is safe even when sibling completions
+        inflate dev_time — worst case small batches route to the CPU
+        twin conservatively); the win direction (lowering it) only
+        trusts single-group cycles (``trust_win``)."""
+        result = None
+        if handle is not None:
+            tiles, t_disp, in_bytes = handle
+            try:
+                faultlib.registry().hit(faultlib.DEVICE_COMPLETION)
+                result = _cat([t.wait() for t in tiles])
+                dev_time = time.monotonic() - t_disp
+                self._device_success()
+                # fold any fenced WARM h2d samples the staging pool
+                # took during this batch into the shared link EWMA —
+                # real-traffic measurements keep the h2d/device/d2h
+                # split and the overlap model honest
+                for t in tiles:
+                    hb = getattr(t, "h2d_bytes", 0)
+                    hs = getattr(t, "h2d_seconds", 0.0)
+                    if hb and hs > 0:
+                        bps = hb / hs
+                        EncodeBatcher._h2d_bps = bps \
+                            if EncodeBatcher._h2d_bps <= 0 else (
+                                0.7 * EncodeBatcher._h2d_bps
+                                + 0.3 * bps)
+            except Exception as e:
+                # classified completion failure (a dispatched handle
+                # cannot be re-waited, so no retry here — the CPU
+                # serves the group and the breaker learns)
+                result = None
+                self._device_failure("completion", e)
+        if result is None:
+            if lane.fails_to_twin:
+                self._twin(lane, key, reqs)
+            else:
+                self._singly(lane, key, reqs)
+            return
+        out_bytes = _nbytes(result)
+        if self.adaptive_cpu:
+            self._learn_crossover(lane, key, reqs, dev_time, in_bytes,
+                                  out_bytes, trust_win=trust_win)
+        c_calls, c_reqs, _c_twin, c_coalesced = lane.counters
+        self._bump(c_calls)
+        self._bump(c_reqs, n=len(reqs))
+        if len(reqs) > 1:
+            self._bump(c_coalesced, n=len(reqs))
+        if self.perf is not None:
+            self.perf.inc(f"ec_{lane.prefix}batch_calls")
+            stripes = f"ec_{lane.prefix}batch_stripes"
+            if stripes in self.perf._types:   # the OSD has encode's only
+                self.perf.inc(stripes, sum(r.nstripes for r in reqs))
+            if len(reqs) > 1:
+                self.perf.inc(f"ec_{lane.prefix}batch_coalesced",
+                              len(reqs))
+        # split the fenced device window into transfer vs compute
+        # using the link rate prewarm measured; without a measurement
+        # the whole window is charged to "device"
+        h2d_s = d2h_s = 0.0
+        if self._h2d_bps > 0:
+            h2d_s = min(dev_time, in_bytes / self._h2d_bps)
+            d2h_s = min(dev_time - h2d_s, out_bytes / self._h2d_bps)
+        self.stage_seconds["h2d"] += h2d_s
+        self.stage_seconds["d2h"] += d2h_s
+        self.stage_seconds["device"] += max(
+            0.0, dev_time - h2d_s - d2h_s)
+        if self.bperf is not None:
+            self.bperf.hinc("dispatch_ms", dev_time * 1e3)
+            self.bperf.inc("d2h_bytes", out_bytes)
+            self.bperf.inc("device_reqs", len(reqs))
+            if len(reqs) > 1:
+                self.bperf.inc("coalesced_reqs", len(reqs))
+        # harvest each tile's device-phase ledger (finalized by
+        # AsyncBatch.wait above): feeds the phase accumulator, the
+        # overlap engine, and the stall flight recorder.  A mesh
+        # dispatch finalizes one clone per chip (.ledgers), so every
+        # device gets its own waterfall/trace lane.
+        for t in tiles:
+            for led in (getattr(t, "ledgers", None) or
+                        [getattr(t, "ledger", None)]):
+                if led is not None:
+                    led["group"] = lane.group
+                self._observe_device_ledger(led)
+        self._publish_device_telemetry(reqs[0].ec_impl)
+        for r, out in zip(reqs, lane.split(self, key, reqs, result)):
+            self._deliver(r, out)
+
+    def _singly(self, lane: _Lane, key: Tuple, reqs: List,
+                counts: Tuple[str, ...] = ()) -> None:
+        """The per-request fallback behind a failed batched call:
+        each rider on a REAL path of its own (for encode a jerasure
+        twin of the same geometry — bit-exact by the corpus contract,
+        and free of a broken device).  A request that still cannot be
+        served gets cb(None), so its op fails with EIO instead of
+        hanging."""
+        for r in reqs:
+            try:
+                out = lane.single(self, key, r)
+            except Exception:
+                self._cb_error()
+                out = None
+            self._bump(*counts)
+            self._deliver(r, out)
+
+    def _twin(self, lane: _Lane, key: Tuple, reqs: List,
+              impl=None) -> None:
+        """Coalesced device-free completion: the whole group's
+        stripes go through ONE batched call on the _BatchTwin (native
+        C++ when available) — the coalescing win survives CPU
+        routing — with the lane's per-request fallback behind it.
+        The verdict was published at collect time; nothing re-routes
+        here.  ``impl`` set means the plug-in's own synchronous
+        batched call instead (_Lane.sync_call): a device call, with
+        its fault site, its breaker charge and no twin counters."""
+        t_form = time.monotonic()
+        t_wall = time.time()
+        books = lane.twin_books
+        if books:
+            self._account_queue_wait(reqs, t_form)
+        self._mark(reqs, lane.twin_event)
+        on_twin = impl is None
+        nstripes = sum(r.nstripes for r in reqs)
+        stack = outs = None
+        try:
+            if on_twin:
+                try:
+                    impl = self.cpu_twin(reqs[0].ec_impl,
+                                         reqs[0].sinfo)
+                except Exception:
+                    if not lane.sync_call:
+                        raise
+                    impl, on_twin = reqs[0].ec_impl, False
+            stack = lane.form(key, reqs)
+            if books and len(reqs) > 1:
+                self._note_copy(_nbytes(stack), lane.concat_site)
+        except Exception:
+            stack = None             # malformed input or no twin, not
+                                     # a device fault: per-request
+        if stack is not None:
+            try:
+                if not on_twin:
+                    faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
+                result = lane.twin_call(impl, key, reqs, stack)
+                # twin groups still fold into the device waterfall: a
+                # coarse two-stamp ledger keyed device=-1 (host), so
+                # dump_device and the bench attribution account for
+                # every group regardless of routing.  No h2d/d2h
+                # stamps — the whole interval charges to the compute
+                # fence — and the overlap engine ignores negative
+                # device ids (a host group has no transfer to hide
+                # under compute).
+                t_done = time.time()
+                led = {"stage_acquire": t_wall, "compute_start": t_wall,
+                       "compute_done": t_done, "deliver": t_done,
+                       "group": lane.group}
+                if on_twin:
+                    led["device"] = -1
+                else:
+                    self._device_success()
+                if books:
+                    # twin work is pure compute: no transfer legs
+                    self.stage_seconds["device"] += \
+                        time.monotonic() - t_form
+                    self.cpu_calls += 1
+                    led.update(bytes=int(_nbytes(stack)),
+                               stripes=int(nstripes))
+                    if self.bperf is not None:
+                        self.bperf.hinc("batch_stripes", nstripes)
+                        self.bperf.inc("cpu_reqs", len(reqs))
+                        if len(reqs) > 1:
+                            self.bperf.inc("coalesced_reqs", len(reqs))
+                self._observe_device_ledger(led)
+                outs = list(lane.split(self, key, reqs, result))
+            except Exception as e:
+                outs = None
+                if not on_twin:
+                    self._device_failure(lane.group, e)
+        if outs is None:
+            self._singly(lane, key, reqs, lane.single_counts)
+            return
+        c_calls, c_reqs, c_twin, c_coalesced = lane.counters
+        if lane.twin_is_call:
+            self._bump(c_calls)
+        self._bump(c_reqs, n=len(reqs))
+        if on_twin:
+            self._bump(c_twin, n=len(reqs))
+        if len(reqs) > 1:
+            self._bump(c_coalesced, n=len(reqs))
+        if self.perf is not None:
+            if "calls" in lane.twin_perf:
+                self.perf.inc(f"ec_{lane.prefix}batch_calls")
+            if "coalesced" in lane.twin_perf and len(reqs) > 1:
+                self.perf.inc(f"ec_{lane.prefix}batch_coalesced",
+                              len(reqs))
+        for r, out in zip(reqs, outs):
+            self._deliver(r, out)
+
+    def _serve_sync(self, lane: _Lane, key: Tuple, reqs: List) -> None:
+        """Completion-time path of a lane whose plug-in has a fenced
+        synchronous batched call but no async entry (_Lane.sync_call:
+        decode on a codec without decode_batch_async).  Routed here,
+        by the crossover and the breaker alone (a counter, no
+        recorder event); a device-bound group runs on its OWN thread
+        — a slow synchronous call on the completion worker would
+        stall every group queued behind it."""
+        thr = self._min_bytes(lane)
+        on_twin = (self.adaptive_cpu and thr > 0 and
+                   lane.group_bytes(reqs) < thr) or \
+            self._breaker_blocks()
+        self._note_route(lane, key, reqs, on_twin, record=False)
+        if on_twin:
+            self._twin(lane, key, reqs)
+            return
+        t = threading.Thread(
+            target=self._twin, args=(lane, key, reqs, reqs[0].ec_impl),
+            name="ec-dec-dev", daemon=True)
+        # tracked so stop() can honor its drain contract (no
+        # continuation after the caller unmounts the store)
+        self._dec_threads = [x for x in self._dec_threads
+                             if x.is_alive()] + [t]
+        t.start()
 
     # -- decode-side routing (consumed by ECBackend reads/recovery) ----
     def route_decode(self, nbytes: int) -> bool:
@@ -2463,8 +2027,8 @@ class EncodeBatcher:
         means the caller should take the CPU twin."""
         if EncodeBatcher._breaker_open:
             reason, to_cpu = "breaker_open", True
-        elif (self.adaptive_cpu and self._dec_min_bytes() > 0
-                and nbytes < self._dec_min_bytes()):
+        elif (self.adaptive_cpu and self._min_bytes(_DEC) > 0
+                and nbytes < self._min_bytes(_DEC)):
             reason, to_cpu = "learned", True
         else:
             reason, to_cpu = "device", False
@@ -2511,75 +2075,6 @@ class EncodeBatcher:
         loop."""
         twin = self.cpu_twin(req.ec_impl, req.sinfo)
         return ecutil.encode(req.sinfo, twin, req.data)
-
-    def _dispatch_group(self, reqs: List[_Req]):
-        """Issue one async device call for every request of one
-        geometry; returns (arrs, async_handle) or None on dispatch
-        failure (completion falls back to per-request CPU encode).
-        On a multi-device host the backend's staged dispatch itself
-        lays each group out with a NamedSharding(dp, None, sp) over
-        the device mesh (jax_engine._staged_put + parallel/mesh.py
-        kernels), so this production path rides every local chip —
-        one dispatch is still ONE sharded GF matmul, and the ledger
-        fans out per chip (AsyncBatch.ledgers)."""
-        t_form = time.monotonic()
-        waited = self._account_queue_wait(reqs, t_form)
-        with section("batcher.dispatch", lane=reqs[0].lane,
-                     reqs=len(reqs),
-                     stripes=sum(r.nstripes for r in reqs),
-                     queue_wait_us=waited * 1e6):
-            try:
-                k = reqs[0].ec_impl.get_data_chunk_count()
-                arrs = [r.as_array(k) for r in reqs]
-                if len(arrs) > 1:
-                    batch = np.concatenate(arrs, axis=0)
-                    self._note_copy(batch.nbytes, "batcher.batch_concat")
-                else:
-                    batch = arrs[0]
-            except Exception:
-                # malformed request payload/geometry: NOT a device fault
-                # (must not trip the breaker) — completion falls back to
-                # per-request CPU encode, which fails the bad rider with
-                # EIO and still serves its group-mates
-                return None
-            # tile oversized batches at max_stripes: bounds per-call
-            # device memory AND caps the largest compiled batch shape
-            # at bucket(max_stripes) — the shape prewarm() compiles —
-            # so a burst can never hit a never-seen (slow-compiling)
-            # shape mid-benchmark.  All tiles dispatch before any
-            # wait: h2d/MXU/d2h still overlap tile-to-tile.
-            tile = max(1, self.max_stripes)
-            handles = err = None
-            delay = self.device_retry_s
-            for attempt in range(3):
-                try:
-                    faultlib.registry().hit(faultlib.DEVICE_DISPATCH)
-                    handles = [
-                        reqs[0].ec_impl.encode_batch_async(
-                            batch[i:i + tile])
-                        for i in range(0, batch.shape[0], tile)]
-                    break
-                except Exception as e:
-                    # classified device dispatch failure: transient until
-                    # proven otherwise — retry with capped backoff before
-                    # charging the breaker
-                    handles, err = None, e
-                    if attempt < 2 and delay > 0:
-                        time.sleep(min(delay, 0.1))
-                        delay *= 2
-            if handles is None:
-                self._device_failure("dispatch", err)
-                return None
-            t_disp = time.monotonic()
-            EncodeBatcher._last_device_ts = t_disp
-            self.stage_seconds["batch_form"] += t_disp - t_form
-            if self.bperf is not None:
-                self.bperf.hinc("batch_stripes", batch.shape[0])
-                self.bperf.inc("h2d_bytes", batch.nbytes)
-            for r in reqs:
-                if r.tracked is not None:
-                    r.tracked.mark_event("ec:batch_dispatched")
-            return (arrs, handles, t_disp)
 
     def _publish_device_telemetry(self, ec_impl) -> None:
         """Refresh the ec_device staging/link gauges from the codec's
@@ -2761,102 +2256,3 @@ class EncodeBatcher:
                 self.bperf.hinc("queue_wait_us", w * 1e6)
         return total
 
-    def _complete_group(self, reqs: List[_Req], handle,
-                        learn: bool = True,
-                        trust_win: bool = True) -> None:
-        k = reqs[0].ec_impl.get_data_chunk_count()
-        m = reqs[0].ec_impl.get_coding_chunk_count()
-        parity = None
-        dev_time = None
-        if handle is not None:
-            arrs, async_tiles, t_dispatch = handle
-            try:
-                faultlib.registry().hit(faultlib.DEVICE_COMPLETION)
-                parts = [t.wait() for t in async_tiles]
-                parity = parts[0] if len(parts) == 1 \
-                    else np.concatenate(parts, axis=0)
-                dev_time = time.monotonic() - t_dispatch
-                self._device_success()
-                # fold any fenced WARM h2d samples the staging pool
-                # took during this batch into the shared link EWMA —
-                # real-traffic measurements keep the h2d/device/d2h
-                # split and the overlap model honest
-                for t in async_tiles:
-                    hb = getattr(t, "h2d_bytes", 0)
-                    hs = getattr(t, "h2d_seconds", 0.0)
-                    if hb and hs > 0:
-                        bps = hb / hs
-                        EncodeBatcher._h2d_bps = bps \
-                            if EncodeBatcher._h2d_bps <= 0 else (
-                                0.7 * EncodeBatcher._h2d_bps
-                                + 0.3 * bps)
-            except Exception as e:
-                # classified completion failure (a dispatched handle
-                # cannot be re-waited, so no retry here — the CPU
-                # twin serves the group and the breaker learns)
-                parity = None
-                self._device_failure("completion", e)
-        if parity is None:
-            # device trouble: encode each request on a REAL CPU path
-            # (a jerasure twin of the same geometry — bit-exact by the
-            # corpus contract, and free of the broken device).  A
-            # request that still cannot encode gets cb(None) so the
-            # write op fails with EIO instead of hanging.
-            for r in reqs:
-                try:
-                    chunks = self._cpu_encode(r)
-                except Exception:
-                    self._cb_error()
-                    chunks = None
-                self._deliver(r, chunks)
-            return
-        if dev_time is not None and self.adaptive_cpu and learn:
-            self._learn_crossover(reqs, dev_time,
-                                  trust_win=trust_win)
-        self.calls += 1
-        self.reqs_total += len(reqs)
-        nstripes = sum(r.nstripes for r in reqs)
-        if len(reqs) > 1:
-            self.reqs_coalesced += len(reqs)
-        if self.perf is not None:
-            self.perf.inc("ec_batch_calls")
-            self.perf.inc("ec_batch_stripes", nstripes)
-            if len(reqs) > 1:
-                self.perf.inc("ec_batch_coalesced", len(reqs))
-        if dev_time is not None:
-            # split the fenced device window into transfer vs compute
-            # using the link rate prewarm measured; without a
-            # measurement the whole window is charged to "device"
-            in_bytes = sum(r.nbytes for r in reqs)
-            out_bytes = parity.nbytes
-            h2d_s = d2h_s = 0.0
-            if self._h2d_bps > 0:
-                h2d_s = min(dev_time, in_bytes / self._h2d_bps)
-                d2h_s = min(dev_time - h2d_s,
-                            out_bytes / self._h2d_bps)
-            self.stage_seconds["h2d"] += h2d_s
-            self.stage_seconds["d2h"] += d2h_s
-            self.stage_seconds["device"] += max(
-                0.0, dev_time - h2d_s - d2h_s)
-            if self.bperf is not None:
-                self.bperf.hinc("dispatch_ms", dev_time * 1e3)
-                self.bperf.inc("d2h_bytes", out_bytes)
-                self.bperf.inc("device_reqs", len(reqs))
-                if len(reqs) > 1:
-                    self.bperf.inc("coalesced_reqs", len(reqs))
-            # harvest each tile's device-phase ledger (finalized by
-            # AsyncBatch.wait above): feeds the phase accumulator,
-            # the overlap engine, and the stall flight recorder.  A
-            # mesh dispatch finalizes one clone per chip (.ledgers),
-            # so every device gets its own waterfall/trace lane.
-            for t in async_tiles:
-                for led in (getattr(t, "ledgers", None) or
-                            [getattr(t, "ledger", None)]):
-                    self._observe_device_ledger(led)
-            self._publish_device_telemetry(reqs[0].ec_impl)
-        off = 0
-        for r, arr in zip(reqs, arrs):
-            p = parity[off:off + r.nstripes]
-            off += r.nstripes
-            out = self._shard_views(arr, p, k, m)
-            self._deliver(r, out)

@@ -337,7 +337,7 @@ class ECBackend(PGBackend):
                 # OSD loss pays no compile/alloc tax.  The decode
                 # crossover itself needs no warm — it seeds from the
                 # encode EWMA the batcher.prewarm above measures
-                # (EncodeBatcher._dec_min_bytes).
+                # (EncodeBatcher._min_bytes).
                 try:
                     warm_dec(chunk, batches=batches)
                 except Exception as e:

@@ -17,7 +17,7 @@ from ceph_tpu.cluster import Cluster
 from ceph_tpu.cluster import test_config as make_conf
 from ceph_tpu.ec import registry as ecreg
 from ceph_tpu.osd import ecutil
-from ceph_tpu.osd.batcher import EncodeBatcher
+from ceph_tpu.osd.batcher import _LANES, EncodeBatcher, _geometry_key
 
 
 def make_batcher(**over):
@@ -32,6 +32,60 @@ def make_batcher(**over):
 def codec():
     return ecreg.instance().factory(
         "tpu", {"k": "2", "m": "1", "technique": "reed_sol_van"})
+
+
+LANE_NAMES = sorted(_LANES)
+
+
+class LaneDrive:
+    """One small group on one of the batcher's three lanes, for
+    the tests that hold the ONE ladder, learner and route note on each
+    of them: how to submit it, the input bytes the router judges it
+    by, the queue key it rides under and the answer it must give."""
+
+    def __init__(self, name, codec, nstripes=2):
+        self.lane = lane = _LANES[name]
+        self.calls, _reqs, self.twin_reqs, _coalesced = lane.counters
+        sinfo = self.sinfo = ecutil.StripeInfo(2, 8192)
+        geom = _geometry_key(codec, sinfo)
+        data = os.urandom(nstripes * 8192)
+        enc = ecutil.encode(sinfo, codec, data)
+        if name == "enc":
+            self.key = ("enc",) + geom
+            self.args, self.want = (data,), enc
+            self.nbytes, self.out_bytes = len(data), len(data) // 2
+        elif name == "dec":
+            self.key = ("dec", geom, (0, 2), (1,))
+            self.args = ({0: enc[0], 2: enc[2]}, {1})
+            self.want = {1: enc[1]}
+            self.nbytes = nstripes * 2 * 4096
+            self.out_bytes = nstripes * 4096
+        else:
+            self.key = ("delta", geom, (0,))
+            delta = np.frombuffer(os.urandom(nstripes * 4096),
+                                  np.uint8).reshape(nstripes, 1, 4096)
+            self.args = (delta, (0,))
+            parity = codec.delta_encode_batch(delta, (0,))
+            self.want = {2: parity[:, 0].tobytes()}
+            self.nbytes, self.out_bytes = delta.nbytes, parity.nbytes
+        self._submit = {"enc": "submit", "dec": "submit_decode",
+                        "delta": "submit_delta"}[name]
+        self._codec = codec
+
+    def submit(self, b, cb):
+        getattr(b, self._submit)(self._codec, self.sinfo, *self.args, cb)
+
+    def request(self):
+        from ceph_tpu.osd import batcher
+        cls = {"enc": batcher._Req, "dec": batcher._DecReq,
+               "delta": batcher._DeltaReq}[self.lane.name]
+        return cls(self._codec, self.sinfo, *self.args, lambda c: None)
+
+    def set_crossover(self, value):
+        setattr(EncodeBatcher, self.lane.crossover, value)
+
+    def crossover(self):
+        return getattr(EncodeBatcher, self.lane.crossover)
 
 
 def test_two_ops_share_one_device_call(codec):
@@ -604,8 +658,12 @@ def test_view_based_encode_bit_exact_with_bytes_path(codec):
 def test_cluster_workload_device_routes_and_window_adapts():
     """Cluster-shaped workload: concurrent client writes must land in
     at least one DEVICE-routed encode group, and the admission window
-    must both grow (overlapping waves) and cut (drained solo ops)."""
-    conf = make_conf(ec_tpu_queue_window_us=150_000,
+    must both grow (overlapping waves) and cut (drained solo ops).
+    The time window is the classic OSD's: crimson's reactor cuts the
+    window at the end of every tick (tick_flush), so it never grows
+    there."""
+    conf = make_conf(osd_backend="classic",
+                     ec_tpu_queue_window_us=150_000,
                      ec_tpu_fallback_cpu=False)
     with Cluster(n_osds=3, conf=conf) as c:
         for i in range(3):
@@ -681,34 +739,36 @@ def test_8mib_k8m4_group_routes_to_device():
         b.stop()
 
 
-def test_idle_device_gets_reprobed_despite_cpu_bias(codec):
+@pytest.mark.parametrize("lane", LANE_NAMES)
+def test_idle_device_gets_reprobed_despite_cpu_bias(codec, lane):
     """A stale learned CPU bias with ZERO recent device traffic is the
     misrouting failure mode: once the device has been idle past
     ec_tpu_device_idle_reprobe_s, the next group must go to the
-    device as a probe instead of waiting out the 1-in-N tick."""
+    device as a probe instead of waiting out the 1-in-N tick — on
+    every lane, against that lane's own threshold."""
     b = make_batcher(ec_tpu_queue_window_us=1000)
     try:
+        d = LaneDrive(lane, codec)
         # absurd learned bias (every batch "too small" for the device)
-        EncodeBatcher._min_device_bytes = 1 << 30
+        d.set_crossover(1 << 30)
         # ...but the device has been idle for a long time
         past = time.monotonic() - 10 * b.idle_reprobe_s
         EncodeBatcher._last_device_ts = past
         EncodeBatcher._last_idle_probe_ts = past
-        sinfo = ecutil.StripeInfo(2, 8192)
-        data = os.urandom(2 * 8192)
         done = threading.Event()
-        b.submit(codec, sinfo, data, lambda c: done.set())
+        d.submit(b, lambda c: done.set())
         assert done.wait(30)
-        assert b.calls == 1 and b.cpu_reqs == 0, \
+        assert getattr(b, d.calls) == 1 and \
+            getattr(b, d.twin_reqs) == 0, \
             "idle device never re-probed; CPU bias locked in"
         # the probe is rate-limited: an immediate second small batch
         # (device no longer idle) goes back to the learned route
         done2 = threading.Event()
-        EncodeBatcher._min_device_bytes = 1 << 30
+        d.set_crossover(1 << 30)
         EncodeBatcher._probe_tick = 1   # keep the 1-in-N tick silent
-        b.submit(codec, sinfo, data, lambda c: done2.set())
+        d.submit(b, lambda c: done2.set())
         assert done2.wait(30)
-        assert b.cpu_reqs == 1
+        assert getattr(b, d.twin_reqs) == 1
     finally:
         b.stop()
 
@@ -737,20 +797,22 @@ def test_breaker_close_resets_learned_crossover(codec):
         b.stop()
 
 
-def test_learn_crossover_uses_pipelined_model_and_rejects_outliers(codec):
+@pytest.mark.parametrize("lane", LANE_NAMES)
+def test_learn_crossover_uses_pipelined_model_and_rejects_outliers(
+        codec, lane):
     """Unit-level checks on the rebuilt learner: (a) a serial fenced
     time whose slowest LEG still beats the CPU must not raise the
     threshold (pipelined overlap credited); (b) a call 5x slower than
     the geometry's steady-state EWMA is a compile/outlier and teaches
-    nothing."""
-    from ceph_tpu.osd.batcher import _Req, _geometry_key
+    nothing.  Held on every lane: its own byte count, its own rate
+    bucket and its own crossover."""
     b = make_batcher()
     try:
-        sinfo = ecutil.StripeInfo(2, 8192)
-        data = b"\0" * (64 * 8192)               # 512 KiB group
-        req = _Req(codec, sinfo, data, lambda c: None)
-        key = _geometry_key(codec, sinfo)
-        total = float(len(data))
+        d = LaneDrive(lane, codec, nstripes=64)   # 512 KiB encode group
+        req = d.request()
+        key = d.lane.bucket(d.key)
+        total = float(d.nbytes)
+        assert d.lane.group_bytes([req]) == d.nbytes
         # measured machine profile: CPU 1 GB/s, link 2 GB/s — the
         # transfer legs are a real fraction of the fenced window
         EncodeBatcher._cpu_bps[key] = 1e9
@@ -760,27 +822,31 @@ def test_learn_crossover_uses_pipelined_model_and_rejects_outliers(codec):
         # h2d (total/2e9) + d2h + compute, every leg is well under
         # cpu_pred: the pipelined router must NOT raise the threshold
         # (the old serial-sum judge did, and misrouted everything)
-        b._learn_crossover([req], dev_time=1.2 * cpu_pred)
-        assert EncodeBatcher._min_device_bytes == 0, \
+        b._learn_crossover(d.lane, d.key, [req], 1.2 * cpu_pred,
+                           d.nbytes, d.out_bytes)
+        assert d.crossover() == 0, \
             "serial-sum judging regressed: pipelined win raised the " \
             "crossover"
         steady = EncodeBatcher._dev_bps.get(key, 0.0)
         assert steady > 0
         # (b) a 100x-slower call (jit compile) must be rejected: no
         # threshold move, EWMA not poisoned
-        b._learn_crossover([req], dev_time=100 * total / steady)
-        assert EncodeBatcher._min_device_bytes == 0
+        b._learn_crossover(d.lane, d.key, [req], 100 * total / steady,
+                           d.nbytes, d.out_bytes)
+        assert d.crossover() == 0
         assert EncodeBatcher._dev_bps[key] == steady, \
             "compile outlier absorbed into the steady-state EWMA"
     finally:
         b.stop()
 
 
-def test_route_verdicts_hit_recorder_and_ec_device_counters(codec):
+@pytest.mark.parametrize("lane", LANE_NAMES)
+def test_route_verdicts_hit_recorder_and_ec_device_counters(codec, lane):
     """PR 6 tentpole: every routing verdict lands in the flight
     recorder with a reason code plus the crossover snapshot, and
-    increments the matching ``ec_device`` ``route_*`` counter; the
-    completed device group publishes staging/h2d telemetry."""
+    increments the matching ``ec_device`` ``<prefix>route_*`` counter
+    (``route_``, ``dec_route_``, ``delta_route_``); the completed
+    device group publishes staging/h2d telemetry."""
     from ceph_tpu.utils.flight_recorder import FlightRecorder
     from ceph_tpu.utils.perf import PerfCountersCollection
 
@@ -792,20 +858,21 @@ def test_route_verdicts_hit_recorder_and_ec_device_counters(codec):
                        "ec_tpu_min_device_bytes": 1},
                       perf_coll=coll, recorder=rec)
     try:
-        sinfo = ecutil.StripeInfo(2, 8192)
-        data = os.urandom(2 * 8192)
+        d = LaneDrive(lane, codec)
+        prefix = d.lane.prefix
         done = threading.Event()
-        b.submit(codec, sinfo, data, lambda c: done.set())
+        d.submit(b, lambda c: done.set())
         assert done.wait(30)
-        routes = [e for e in rec.dump() if e["kind"] == "route"]
+        routes = [e for e in rec.dump()
+                  if e["kind"] == prefix + "route"]
         assert routes, rec.dump()
         assert routes[0]["to"] == "device"
         assert routes[0]["reason"] == "device"
-        assert routes[0]["bytes"] == len(data)
+        assert routes[0]["bytes"] == d.nbytes
         assert routes[0]["crossover"] == 1
         dp = coll.perf_dump()["ec_device"]
-        assert dp["route_device"] >= 1
-        assert dp["route_pin"] == 0
+        assert dp[prefix + "route_device"] >= 1
+        assert dp[prefix + "route_pin"] == 0
         # the completed group published the staging-pool and link
         # telemetry into the same subsystem
         deadline = time.monotonic() + 10
@@ -819,10 +886,11 @@ def test_route_verdicts_hit_recorder_and_ec_device_counters(codec):
         b.stop()
 
 
-def test_pin_routed_twin_group_is_reason_coded(codec):
+@pytest.mark.parametrize("lane", LANE_NAMES)
+def test_pin_routed_twin_group_is_reason_coded(codec, lane):
     """A crossover pinned above the group size routes to the twin
     with reason="pin" — the exact evidence trail the r05 misrouting
-    post-mortem lacked."""
+    post-mortem lacked — on every lane, bit-exact."""
     from ceph_tpu.utils.flight_recorder import FlightRecorder
     from ceph_tpu.utils.perf import PerfCountersCollection
 
@@ -834,18 +902,19 @@ def test_pin_routed_twin_group_is_reason_coded(codec):
                        "ec_tpu_min_device_bytes": 256 << 20},
                       perf_coll=coll, recorder=rec)
     try:
-        sinfo = ecutil.StripeInfo(2, 8192)
-        data = os.urandom(2 * 8192)
+        d = LaneDrive(lane, codec)
+        prefix = d.lane.prefix
         out = {}
         done = threading.Event()
-        b.submit(codec, sinfo, data,
-                 lambda c: (out.update(c), done.set()))
+        d.submit(b, lambda c: (out.update(c), done.set()))
         assert done.wait(30)
-        assert out == ecutil.encode(sinfo, codec, data)
-        routes = [e for e in rec.dump() if e["kind"] == "route"]
+        assert out == d.want
+        assert getattr(b, d.twin_reqs) == 1
+        routes = [e for e in rec.dump()
+                  if e["kind"] == prefix + "route"]
         assert routes and routes[0]["to"] == "cpu"
         assert routes[0]["reason"] == "pin"
-        assert coll.perf_dump()["ec_device"]["route_pin"] >= 1
+        assert coll.perf_dump()["ec_device"][prefix + "route_pin"] >= 1
     finally:
         b.stop()
 

@@ -22,7 +22,7 @@ from ceph_tpu.cluster import Cluster
 from ceph_tpu.cluster import test_config as make_conf
 from ceph_tpu.ec import registry as ecreg
 from ceph_tpu.osd import ecutil
-from ceph_tpu.osd.batcher import EncodeBatcher
+from ceph_tpu.osd.batcher import _DEC, EncodeBatcher
 
 
 def make_codec(k, m):
@@ -198,10 +198,10 @@ def test_decode_crossover_seeds_from_encode_ewma():
     try:
         EncodeBatcher._min_device_bytes = 123456.0
         EncodeBatcher._dec_min_device_bytes = 0.0
-        assert b._dec_min_bytes() == 123456.0, \
+        assert b._min_bytes(_DEC) == 123456.0, \
             "decode crossover must seed from the encode EWMA"
         EncodeBatcher._dec_min_device_bytes = 777.0
-        assert b._dec_min_bytes() == 777.0
+        assert b._min_bytes(_DEC) == 777.0
         # breaker close re-seeds decode from encode
         for _ in range(b.device_error_threshold):
             b._device_failure("dispatch")
@@ -292,10 +292,10 @@ def test_decode_route_note_overhead_within_budget():
                       lambda dec: None)
         key = ("dec", "geom", (0, 2), (1,))
         n = 20_000
-        b._note_route_dec(key, [req], False)     # warm
+        b._note_route(_DEC, key, [req], False)     # warm
         t0 = time.perf_counter()
         for _ in range(n):
-            b._note_route_dec(key, [req], False)
+            b._note_route(_DEC, key, [req], False)
         cost = (time.perf_counter() - t0) / n
         assert cost < DEC_ROUTE_CEILING, \
             f"decode route note costs {cost * 1e6:.2f}us/op " \
