@@ -20,8 +20,6 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from .locks import wait_acquire
-
 LEVEL_BASIC = "basic"
 LEVEL_ADVANCED = "advanced"
 LEVEL_DEV = "dev"
@@ -787,12 +785,19 @@ def _opts() -> List[Option]:
 
 class Config:
     """Layered config values + observer notification (reference
-    common/config.cc md_config_t::set_val / apply_changes)."""
+    common/config.cc md_config_t::set_val / apply_changes).
+
+    Every daemon of a process reads its options from one ``Config``,
+    on every reactor pass and every frame, so a read takes no lock: it
+    indexes ``_merged``, the effective value of every option, which is
+    never mutated once published.  A writer, under ``_lock``, changes
+    the layered ``_values``, builds the next mapping and publishes it
+    by one attribute store."""
 
     SOURCES = ("default", "file", "env", "cli", "runtime")
 
     def __init__(self, overrides: Optional[Dict[str, Any]] = None):
-        self._lock = threading.RLock()
+        self._lock = threading.RLock()        # the writers' lock
         self.schema: Dict[str, Option] = {o.name: o for o in _opts()}
         self._values: Dict[str, Dict[str, Any]] = {
             s: {} for s in self.SOURCES}
@@ -800,6 +805,13 @@ class Config:
         for name, opt in self.schema.items():
             self._values["default"][name] = opt.default
         self._load_env()
+        #: snapshots published so far: what a ``set`` costs that it did
+        #: not before is one rebuild per step of this counter
+        self.generation = 0
+        merged: Dict[str, Any] = {}
+        for source in self.SOURCES:
+            merged.update(self._values[source])
+        self._publish(merged)
         for k, v in (overrides or {}).items():
             self.set(k, v, source="cli")
 
@@ -810,25 +822,30 @@ class Config:
             if env is not None:
                 self._values["env"][name] = self.schema[name].validate(env)
 
+    def _publish(self, merged: Dict[str, Any]) -> None:
+        self._merged = merged
+        self.generation += 1
+
+    def _changed(self, name: str) -> List[Callable[[str, Any], None]]:
+        """After a change to ``name``'s layers, under ``_lock``:
+        publish a snapshot if the effective value moved, and return
+        the observers to call once the lock is released."""
+        new = next(self._values[source][name]
+                   for source in reversed(self.SOURCES)
+                   if name in self._values[source])
+        if new == self._merged[name]:
+            return []
+        self._publish({**self._merged, name: new})
+        return list(self._observers.get(name, ()))
+
     # -- access ------------------------------------------------------------
     def get(self, name: str) -> Any:
-        lock = self._lock
-        if not lock.acquire(False):
-            # every daemon of a process reads its options through this
-            # one lock: a wait for it is a span of the profiler's trace
-            wait_acquire(lock, "config")
         try:
-            if name not in self.schema:
-                raise KeyError(f"unknown option {name!r}")
-            for source in reversed(self.SOURCES):
-                if name in self._values[source]:
-                    return self._values[source][name]
-        finally:
-            lock.release()
-        raise AssertionError("unreachable: defaults always populated")
+            return self._merged[name]
+        except KeyError:
+            raise KeyError(f"unknown option {name!r}") from None
 
-    def __getitem__(self, name: str) -> Any:
-        return self.get(name)
+    __getitem__ = get
 
     def is_overridden(self, name: str) -> bool:
         """True when any non-default layer sets the option — lets a
@@ -846,11 +863,9 @@ class Config:
         with self._lock:
             if name not in self.schema:
                 raise KeyError(f"unknown option {name!r}")
-            old = self.get(name)
             self._values.get(source, {}).pop(name, None)
-            new = self.get(name)
-            observers = list(self._observers.get(name, ())) \
-                if new != old else []
+            observers = self._changed(name)
+            new = self._merged[name]
         for fn in observers:
             fn(name, new)
 
@@ -860,12 +875,9 @@ class Config:
                 raise KeyError(f"unknown option {name!r}")
             if source not in self.SOURCES:
                 raise ValueError(f"unknown source {source!r}")
-            old = self.get(name)
-            value = self.schema[name].validate(value)
-            self._values[source][name] = value
-            new = self.get(name)
-            observers = list(self._observers.get(name, ())) \
-                if new != old else []
+            self._values[source][name] = self.schema[name].validate(value)
+            observers = self._changed(name)
+            new = self._merged[name]
         for fn in observers:
             fn(name, new)
 
@@ -879,15 +891,14 @@ class Config:
             self._observers.setdefault(name, []).append(fn)
 
     def dump(self) -> Dict[str, Any]:
-        with self._lock:
-            return {name: self.get(name) for name in sorted(self.schema)}
+        return dict(sorted(self._merged.items()))
 
     def diff(self) -> Dict[str, Any]:
         """Only options changed from their defaults (reference
         `ceph config diff`)."""
         with self._lock:
-            return {name: self.get(name) for name in sorted(self.schema)
-                    if self.get(name) != self.schema[name].default}
+            return {name: value for name, value in self.dump().items()
+                    if value != self.schema[name].default}
 
     def tunables(self) -> List[Option]:
         """Options carrying the machine-readable ``tunable`` marker —

@@ -20,7 +20,6 @@ counter.
 """
 from __future__ import annotations
 
-import re
 import threading
 import time
 from typing import List, Optional
@@ -40,24 +39,18 @@ US_BOUNDS: List[float] = [
 #: section: a handoff of a few microseconds, thousands of times a
 #: second, would cost the trace's reader more than it tells
 WAIT_SECTION_S = 50e-6
-_OWNER = re.compile(r"owner=(\d+)")     # in the repr of an RLock
 
 
-def wait_acquire(inner, site: str, holder: Optional[int] = None,
+def wait_acquire(inner, site: str, holder: int,
                  timeout: float = -1) -> bool:
     """The blocking half of an acquire whose ``inner.acquire(False)``
     just failed: the wait is a ``lock.wait`` section of the profiler's
     trace, naming the site and the thread that was holding the lock.
-    ``holder`` is the ident the lock noted when it was acquired; a
-    bare RLock whose holders cannot afford the note (``Config.get``:
-    the note would lengthen a critical section that 52 reactors queue
-    for) is asked for its owner here, on the waiter's time.  With no
-    session recording, this is the blocking acquire and nothing else."""
+    ``holder`` is the ident the lock noted when it was acquired.  With
+    no session recording, this is the blocking acquire and nothing
+    else."""
     if not tracing():
         return inner.acquire(True, timeout)
-    if holder is None:
-        owner = _OWNER.search(repr(inner))
-        holder = int(owner.group(1)) if owner else 0
     if timeout < 0 and inner.acquire(True, WAIT_SECTION_S):
         return True
     name = next((t.name for t in threading.enumerate()
