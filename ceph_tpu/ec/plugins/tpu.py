@@ -60,9 +60,11 @@ def shared_backend() -> JaxBackend:
 class _DecodeHandle:
     """AsyncBatch wrapper for a decode group: ``wait()`` splits the
     combined-recovery-row output [B, E, L] back into per-erased-chunk
-    arrays.  Exposes the underlying seven-phase DeviceLedger and h2d
-    sample so the OSD batcher folds decode groups into the same
-    waterfall/crossover machinery as encode groups."""
+    arrays — one for EVERY chunk id absent from what was handed in,
+    whether a rider wants it or not (see decode_batch_async).  Exposes
+    the underlying seven-phase DeviceLedger and h2d sample so the OSD
+    batcher folds decode groups into the same waterfall/crossover
+    machinery as encode groups."""
 
     __slots__ = ("_ab", "_erased")
 
@@ -159,13 +161,20 @@ class TpuCodecMixin:
     def decode_batch_async(self, present: Mapping[int, np.ndarray],
                            chunk_len: int) -> _DecodeHandle:
         """Non-blocking decode_batch: one staged device dispatch
-        reconstructs EVERY missing chunk id for the batch.  The
+        reconstructs EVERY chunk id absent from ``present`` — the
+        entry takes no ``want``, so a read that gathered k of k+m
+        shards gets m rows back where its riders asked for the one or
+        two they lost (the batcher counts both, ``dec_rows_out`` and
+        ``dec_rows_wanted``; the benchmark's
+        ``decode.unwanted_row_share`` is their gap).  The
         per-erasure-signature combined recovery rows (CodecCore
         `_recovery_rows` — inverse map for data erasures, encode row
         composed through it for parity erasures) make reconstruction a
-        single matmul, so decode groups pipeline through the same
-        StagingPool rings and inflight-group machinery as encode —
-        the decode twin of encode_batch_async."""
+        single matmul whose rows are an operand of the kernel family's
+        one program (jax_engine rows_program), so decode groups
+        pipeline through the same StagingPool rings, executables and
+        inflight-group machinery as encode — the decode twin of
+        encode_batch_async."""
         if not self.decode_async_supported():
             raise ValueError("async device decode needs a byte-domain "
                              "w=8 GF coding matrix or a packet layout")
@@ -267,13 +276,16 @@ class TpuCodecMixin:
         _PREWARMED_SHAPES.add(key)       # only once it really is warm
 
     def prewarm_decode(self, chunk_size: int, batches=(1,)) -> None:
-        """Make the common recovery signatures hot before the first
-        rebuild window: host-side combined recovery rows for every
-        single-erasure signature, the staging ring for the window
-        shape, and one compiled decode executable (each signature is
-        its own jit key, so the first window of any *other* signature
-        still pays one compile — but single erasures dominate real
-        recovery).  Idempotent per (geometry, chunk_size)."""
+        """Make recovery hot before the first rebuild window:
+        host-side combined recovery rows for every single-erasure
+        signature, the staging rings for the window shapes, and the
+        decode entry run once per batch shape with k chunks present —
+        what a degraded read or a recovery read gathers, so m rows
+        out.  Where the row set is an operand (jax_engine
+        rows_program) that is THE executable of every erasure
+        signature at that shape, the pool's encode included; off a
+        TPU a packet code's XOR schedule is still compiled per
+        signature.  Idempotent per (geometry, chunk_size)."""
         if not self.decode_async_supported():
             return
         core = self.core
@@ -285,9 +297,12 @@ class TpuCodecMixin:
         key = ("dec",) + self._geometry(chunk_size)
         if key in _PREWARMED_SHAPES:
             return
-        z = {i: np.zeros((1, int(chunk_size)), dtype=np.uint8)
-             for i in range(n) if i != 0}
-        self.decode_batch_async(z, int(chunk_size)).wait()
+        for nb in batches:
+            z = np.zeros((max(1, int(nb)), int(chunk_size)),
+                         dtype=np.uint8)
+            self.decode_batch_async(
+                {i: z for i in range(1, self.k + 1)},
+                int(chunk_size)).wait()
         _PREWARMED_SHAPES.add(key)       # only once it really is warm
 
     def prewarm_geometry(self, chunk_size: int,
@@ -336,8 +351,8 @@ class TpuCodecMixin:
         ``data_erased`` chunk ids from the staged ``chosen`` chunk
         stack [B, k, L] (device array in/out).  Uses the same
         signature-cached compiled kernels the OSD recovery path does
-        (jax_engine gf8_fn / packet_chain_fn — the compiled analog of
-        ISA-L's decode-table LRU, reference
+        (jax_engine gf8_fn / packet_chain_fn — the analog of ISA-L's
+        decode-table LRU, reference
         isa/ErasureCodeIsaTableCache.cc:253-306)."""
         core = self.core
         rows_gf, rows_bits = core._decode_rows(tuple(chosen),

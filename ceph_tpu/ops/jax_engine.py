@@ -20,7 +20,9 @@ same kernel executes every codec family:
 
 Decode uses the same kernel with per-erasure-signature inverse rows,
 cached like ISA-L's decode-table LRU (reference
-isa/ErasureCodeIsaTableCache.cc).
+isa/ErasureCodeIsaTableCache.cc).  On a TPU a row set is an OPERAND of
+its kernel family's one program (rows_program, BoundRows): the number
+of executables follows the shapes dispatched, not the signatures.
 
 Shapes are bucketed (batch to the next power of two, length to a lane
 multiple) so the jit cache stays small while the OSD feeds variable-size
@@ -46,12 +48,14 @@ LENGTH_QUANTUM = 128
 
 
 class ChainLRU:
-    """LRU of compiled per-signature chains — the moral equivalent of
-    ISA-L's decode-table cache (reference
+    """LRU of per-signature chains — the moral equivalent of ISA-L's
+    decode-table cache (reference
     isa/ErasureCodeIsaTableCache.cc:253-306): erasure signatures are few
-    (C(k+m, <=m)) and recovery hammers one signature for a whole rebuild,
-    so caching the compiled executable amortizes the one-time jit cost to
-    zero while the cap bounds compiled-program memory."""
+    (C(k+m, <=m)) and recovery hammers one signature for a whole rebuild.
+    An entry is a one-argument callable: a row set bound to its family's
+    shared program (BoundRows; nothing compiles per entry) or, off a TPU
+    and on a mesh, a static program of its own, whose memory the cap
+    bounds."""
 
     def __init__(self, cap: int = 256):
         self.cap = cap
@@ -233,10 +237,12 @@ def _packet_chain(data: jnp.ndarray, schedule, w: int,
     return out.reshape(batch, m_out, nw * sw)
 
 
-def _packet_mxu_pallas_fn(B: np.ndarray, w: int, packetsize: int,
-                          interpret: bool = False):
-    """Fused MXU kernel for packet-layout bitmatrix codes: uint8
-    [batch, k, L] -> uint8 [batch, R/w, L] with L = nw * w * ps.
+def _packet_mxu_pallas(bits, data, *, w: int, packetsize: int,
+                       interpret: bool = False):
+    """Fused MXU kernel for packet-layout bitmatrix codes: the row set
+    ``bits`` int8 [R, k*w] (an OPERAND: the pool's coding bit-matrix or
+    one erasure signature's recovery rows) applied to ``data`` uint8
+    [batch, k, L] -> uint8 [batch, R/w, L], with L = nw * w * ps.
 
     The packet apply is out_row[r] = XOR of the k*w input packets
     selected by bitmatrix row r — per OUTPUT BIT j that is a mod-2
@@ -255,63 +261,58 @@ def _packet_mxu_pallas_fn(B: np.ndarray, w: int, packetsize: int,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    R, KW = B.shape
+    R, KW = bits.shape
     m_out = R // w
     ps = packetsize
-    Bconst = jnp.asarray(B, dtype=jnp.int8)
+    batch, k_, L = data.shape
+    sw = w * ps
+    nw = L // sw
+    # tile a contiguous RUN of super-words per grid step (largest
+    # divisor of nw within the VMEM budget): a one-super-word
+    # block would fragment every HBM read into k*w strided
+    # ``ps``-byte pieces, which measured ~2.5x below the device's
+    # streaming rate — the contiguous run keeps reads at
+    # TB*w*ps-byte granularity, same idea as the byte-domain
+    # kernel's _pick_block_len
+    budget = max(1, (4 << 20) // (k_ * sw))
+    TB = 1
+    for t in range(1, min(nw, budget) + 1):
+        if nw % t == 0:
+            TB = t
+    xin = data.reshape(batch, k_, nw, w, ps)
 
-    # the closure's name is the XLA module's: jit_packet_mxu_pallas
-    def packet_mxu_pallas(data):
-        batch, k_, L = data.shape
-        sw = w * ps
-        nw = L // sw
-        # tile a contiguous RUN of super-words per grid step (largest
-        # divisor of nw within the VMEM budget): a one-super-word
-        # block would fragment every HBM read into k*w strided
-        # ``ps``-byte pieces, which measured ~2.5x below the device's
-        # streaming rate — the contiguous run keeps reads at
-        # TB*w*ps-byte granularity, same idea as the byte-domain
-        # kernel's _pick_block_len
-        budget = max(1, (4 << 20) // (k_ * sw))
-        TB = 1
-        for t in range(1, min(nw, budget) + 1):
-            if nw % t == 0:
-                TB = t
-        xin = data.reshape(batch, k_, nw, w, ps)
+    def kernel(b_ref, in_ref, out_ref):
+        for t in range(TB):
+            x = in_ref[0, :, t, :, :].reshape(KW, ps)  # [k*w, ps]
+            planes = [((x & jnp.uint8(1 << j)) != 0).astype(jnp.int8)
+                      for j in range(8)]
+            bits_in = jnp.concatenate(planes, axis=1)  # [k*w, 8*ps]
+            pb = jax.lax.dot_general(
+                b_ref[:, :], bits_in, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32)      # [R, 8*ps]
+            acc = None
+            for j in range(8):
+                v = (pb[:, j * ps:(j + 1) * ps] & 1) << j
+                acc = v if acc is None else acc | v
+            out_ref[0, :, t, :, :] = acc.astype(jnp.uint8).reshape(
+                m_out, w, ps)
 
-        def kernel(b_ref, in_ref, out_ref):
-            for t in range(TB):
-                x = in_ref[0, :, t, :, :].reshape(KW, ps)  # [k*w, ps]
-                planes = [((x & jnp.uint8(1 << j)) != 0).astype(jnp.int8)
-                          for j in range(8)]
-                bits = jnp.concatenate(planes, axis=1)     # [k*w, 8*ps]
-                pb = jax.lax.dot_general(
-                    b_ref[:, :], bits, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)      # [R, 8*ps]
-                acc = None
-                for j in range(8):
-                    v = (pb[:, j * ps:(j + 1) * ps] & 1) << j
-                    acc = v if acc is None else acc | v
-                out_ref[0, :, t, :, :] = acc.astype(jnp.uint8).reshape(
-                    m_out, w, ps)
-
-        out = pl.pallas_call(
-            kernel,
-            grid=(batch, nw // TB),
-            in_specs=[pl.BlockSpec((R, KW), lambda b, i: (0, 0),
-                                   memory_space=pltpu.VMEM),
-                      pl.BlockSpec((1, k_, TB, w, ps),
-                                   lambda b, i: (b, 0, i, 0, 0),
-                                   memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec((1, m_out, TB, w, ps),
-                                   lambda b, i: (b, 0, i, 0, 0),
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((batch, m_out, nw, w, ps),
-                                           jnp.uint8),
-            interpret=interpret,
-        )(Bconst, xin)
-        return out.reshape(batch, m_out, L)
-    return packet_mxu_pallas
+    out = pl.pallas_call(
+        kernel,
+        grid=(batch, nw // TB),
+        in_specs=[pl.BlockSpec((R, KW), lambda b, i: (0, 0),
+                               memory_space=pltpu.VMEM),
+                  pl.BlockSpec((1, k_, TB, w, ps),
+                               lambda b, i: (b, 0, i, 0, 0),
+                               memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((1, m_out, TB, w, ps),
+                               lambda b, i: (b, 0, i, 0, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((batch, m_out, nw, w, ps),
+                                       jnp.uint8),
+        interpret=interpret,
+    )(bits, xin)
+    return out.reshape(batch, m_out, L)
 
 
 def _pick_block_len(L: int, cap: int = 1 << 19) -> int:
@@ -325,10 +326,22 @@ def _pick_block_len(L: int, cap: int = 1 << 19) -> int:
     return best
 
 
-def _gf_mxu_pallas_fn(B: np.ndarray, k: int, w: int,
-                      interpret: bool = False):
-    """Fused bit-plane MXU kernel for byte-domain GF(2^w) codes:
-    uint8 [batch, k, L] -> uint8 [batch, R/w, L].
+def gf_plane_bits(B: np.ndarray, k: int, w: int) -> np.ndarray:
+    """A byte-domain bit-matrix [R, k*w] in the order _gf_mxu_pallas
+    takes it (built on the host, once per row set): cols
+    (c*w+j)->(j*k+c), rows (e*w+i)->(i*m_out+e), so the kernel
+    extracts/packs whole [k, T] planes instead of skinny rows."""
+    m_out = B.shape[0] // w
+    colp = [c * w + j for j in range(w) for c in range(k)]
+    rowp = [e * w + i for i in range(w) for e in range(m_out)]
+    return np.asarray(B[np.ix_(rowp, colp)], dtype=np.int8)
+
+
+def _gf_mxu_pallas(bits, data, *, w: int, interpret: bool = False):
+    """Fused bit-plane MXU kernel for byte-domain GF(2^w) codes: the
+    row set ``bits`` int8 [R, k*w] in plane order (gf_plane_bits; an
+    OPERAND, like _packet_mxu_pallas's) applied to ``data`` uint8
+    [batch, k, L] -> uint8 [batch, R/w, L].
 
     One VMEM-resident pass per block: extract bit-planes (wide [k, T]
     compares), one int8 dot_general on the MXU (mod-2 via the int32
@@ -340,56 +353,72 @@ def _gf_mxu_pallas_fn(B: np.ndarray, k: int, w: int,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    R, KW = B.shape
+    R, KW = bits.shape
     m_out = R // w
-    # permute cols (c*w+j)->(j*k+c), rows (e*w+i)->(i*m_out+e) so the
-    # kernel extracts/packs whole [k, T] planes instead of skinny rows
-    colp = [c * w + j for j in range(w) for c in range(k)]
-    rowp = [e * w + i for i in range(w) for e in range(m_out)]
-    Bconst = jnp.asarray(B[np.ix_(rowp, colp)], dtype=jnp.int8)
     TB = 16384
+    batch, k_, L = data.shape
+    # pad to a 128-multiple so the block length always divides L
+    # (zeros are harmless: the code is GF-linear); callers that
+    # pre-pad (host entry points, stage()) hit the no-op branch
+    Lp = _round_up(max(L, 128), 128)
+    if Lp != L:
+        data = jnp.pad(data, ((0, 0), (0, 0), (0, Lp - L)))
+    Lb = _pick_block_len(Lp)
+    tb = min(TB, Lb)
 
-    # the closure's name is the XLA module's: jit_gf8_mxu_pallas
-    def gf8_mxu_pallas(data):
-        batch, k_, L = data.shape
-        # pad to a 128-multiple so the block length always divides L
-        # (zeros are harmless: the code is GF-linear); callers that
-        # pre-pad (host entry points, stage()) hit the no-op branch
-        Lp = _round_up(max(L, 128), 128)
-        if Lp != L:
-            data = jnp.pad(data, ((0, 0), (0, 0), (0, Lp - L)))
-        Lb = _pick_block_len(Lp)
-        tb = min(TB, Lb)
+    def kernel(b_ref, in_ref, out_ref):
+        for t in range(Lb // tb):
+            x = in_ref[0, :, t * tb:(t + 1) * tb]       # [k, tb] u8
+            planes = [((x & jnp.uint8(1 << j)) != 0).astype(jnp.int8)
+                      for j in range(w)]
+            bits_in = jnp.concatenate(planes, axis=0)   # [w*k, tb]
+            pb = jax.lax.dot_general(
+                b_ref[:, :], bits_in, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32)       # [R, tb]
+            acc = None
+            for i in range(w):
+                v = (pb[i * m_out:(i + 1) * m_out, :] & 1) << i
+                acc = v if acc is None else acc | v
+            out_ref[0, :, t * tb:(t + 1) * tb] = acc.astype(jnp.uint8)
 
-        def kernel(b_ref, in_ref, out_ref):
-            for t in range(Lb // tb):
-                x = in_ref[0, :, t * tb:(t + 1) * tb]       # [k, tb] u8
-                planes = [((x & jnp.uint8(1 << j)) != 0).astype(jnp.int8)
-                          for j in range(w)]
-                bits = jnp.concatenate(planes, axis=0)      # [w*k, tb]
-                pb = jax.lax.dot_general(
-                    b_ref[:, :], bits, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)       # [R, tb]
-                acc = None
-                for i in range(w):
-                    v = (pb[i * m_out:(i + 1) * m_out, :] & 1) << i
-                    acc = v if acc is None else acc | v
-                out_ref[0, :, t * tb:(t + 1) * tb] = acc.astype(jnp.uint8)
+    out = pl.pallas_call(
+        kernel,
+        grid=(batch, Lp // Lb),
+        in_specs=[pl.BlockSpec((R, KW), lambda b, i: (0, 0),
+                               memory_space=pltpu.VMEM),
+                  pl.BlockSpec((1, k_, Lb), lambda b, i: (b, 0, i),
+                               memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((1, m_out, Lb), lambda b, i: (b, 0, i),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((batch, m_out, Lp), jnp.uint8),
+        interpret=interpret,
+    )(bits, data)
+    return out[:, :, :L] if Lp != L else out
 
-        out = pl.pallas_call(
-            kernel,
-            grid=(batch, Lp // Lb),
-            in_specs=[pl.BlockSpec((R, KW), lambda b, i: (0, 0),
-                                   memory_space=pltpu.VMEM),
-                      pl.BlockSpec((1, k_, Lb), lambda b, i: (b, 0, i),
-                                   memory_space=pltpu.VMEM)],
-            out_specs=pl.BlockSpec((1, m_out, Lb), lambda b, i: (b, 0, i),
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((batch, m_out, Lp), jnp.uint8),
-            interpret=interpret,
-        )(Bconst, data)
-        return out[:, :, :L] if Lp != L else out
-    return gf8_mxu_pallas
+
+@functools.lru_cache(maxsize=None)
+def rows_program(kernel: str, w: int, packetsize: int = 0,
+                 donate: bool = False, interpret: bool = False):
+    """THE jitted program of a Pallas kernel family: ``(bits, data) ->
+    out`` with the row set an operand, so jit builds one executable
+    per (family, row-set shape, input shape) and every erasure
+    signature of that shape runs it — a pool's encode matrix and the
+    recovery rows of a read that gathered k shards (m rows either way)
+    share one.  The closures' names are the XLA modules':
+    jit_gf8_mxu_pallas, jit_packet_mxu_pallas (benchmark/kernels)."""
+    if kernel == "gf_mxu_pallas":
+        def gf8_mxu_pallas(bits, data):
+            return _gf_mxu_pallas(bits, data, w=w, interpret=interpret)
+        fn = gf8_mxu_pallas
+    elif kernel == "packet_mxu_pallas":
+        def packet_mxu_pallas(bits, data):
+            return _packet_mxu_pallas(bits, data, w=w,
+                                      packetsize=packetsize,
+                                      interpret=interpret)
+        fn = packet_mxu_pallas
+    else:
+        raise ValueError(f"no row-operand program for kernel {kernel!r}")
+    return jax.jit(fn, donate_argnums=(1,) if donate else ())
 
 
 def gf8_kernel() -> str:
@@ -413,16 +442,19 @@ def packet_kernel(packetsize: int) -> str:
 
 
 def gf8_inner(rows: np.ndarray):
-    """Unjitted traceable kernel for a GF(2^8) row set [.., C, L] ->
-    [.., R, L]: the SINGLE source of w=8 kernel routing (fused MXU
-    pallas kernel on TPU, XOR/xtime elementwise chain elsewhere),
-    shared by JaxBackend.gf8_fn and the mesh data plane
-    (parallel/mesh.py sharded_rows_fn)."""
+    """Unjitted traceable kernel for ONE GF(2^8) row set [.., C, L] ->
+    [.., R, L], the rows a constant of the caller's trace: what the
+    mesh data plane wraps in shard_map (parallel/mesh.py
+    sharded_rows_fn, one program per row set still) and what serves a
+    row set off a TPU.  Same routing as JaxBackend.gf8_fn: the fused
+    MXU pallas kernel on TPU, the XOR/xtime elementwise chain
+    elsewhere."""
     rows = np.asarray(rows, dtype=np.int64)
     if gf8_kernel() == "gf_mxu_pallas":
         from .matrix import matrix_to_bitmatrix
-        return _gf_mxu_pallas_fn(matrix_to_bitmatrix(rows, 8),
-                                 rows.shape[1], 8)
+        bits = jnp.asarray(gf_plane_bits(matrix_to_bitmatrix(rows, 8),
+                                         rows.shape[1], 8))
+        return functools.partial(_gf_mxu_pallas, bits, w=8)
     coeffs = tuple(tuple(int(v) for v in row) for row in rows)
     return functools.partial(_gf8_chain, coeffs=coeffs)
 
@@ -503,6 +535,17 @@ def _fetch(out, batch: int, L: int) -> np.ndarray:
         return np.asarray(out)[:batch, :, :L]
 
 
+def _call_section(kernel: str, fn):
+    """The ``dispatch.call`` section of one dispatch, with whether the
+    row set's binding has run before (``bound=hit``) or is called here
+    for the first time (``new``: a BoundRows made by this lookup — not
+    a compile; what is not a binding reads ``hit``).  The caller adds
+    ``rows``, the chunk rows out a stripe, once the call has returned
+    its output's shape."""
+    return section("dispatch.call", kernel=kernel,
+                   bound="hit" if getattr(fn, "calls", 1) else "new")
+
+
 def _run_sync(kernel: str, fn, padded: np.ndarray, batch: int,
               L: int) -> np.ndarray:
     """The synchronous twin of _staged_put + AsyncBatch.wait: put,
@@ -511,9 +554,39 @@ def _run_sync(kernel: str, fn, padded: np.ndarray, batch: int,
                  live_bytes=batch * padded.shape[1] * L,
                  batch=padded.shape[0]):
         dev = jnp.asarray(padded)
-    with section("dispatch.call", kernel=kernel):
+    with _call_section(kernel, fn) as sec:
         out = fn(dev)
+        sec.set_metadata(rows=out.shape[-2])
     return _fetch(out, batch, L)
+
+
+class BoundRows:
+    """What JaxBackend._chain_lru holds for one row set (a pool's
+    coding matrix, one erasure signature's recovery rows): the
+    one-argument callable [batch, C, L] -> [batch, R, L].
+
+    For a Pallas family it binds the row set's device-resident bits to
+    the family's shared program (rows_program), so making one compiles
+    nothing and every signature of one shape runs one executable.  A
+    static chain (the XOR schedules off a TPU, the mesh's shard_map
+    wrapper) is a program of its own per row set: ``bits`` is None and
+    ``program`` takes the data alone."""
+
+    __slots__ = ("program", "bits", "calls", "_note")
+
+    def __init__(self, program, bits, note):
+        self.program = program
+        self.bits = bits
+        self.calls = 0          # 0: bound, never run (dispatch.call)
+        self._note = note       # JaxBackend._note_program
+
+    def __call__(self, data):
+        self.calls += 1
+        if self.bits is None:
+            self._note((self, data.shape))
+            return self.program(data)
+        self._note((self.program, self.bits.shape, data.shape))
+        return self.program(self.bits, data)
 
 
 class _StageSlot:
@@ -808,10 +881,37 @@ class JaxBackend:
         # served, for dump_device and chip_smoke.py
         self.kernel_calls: dict = {}
         self._kernel_lock = threading.Lock()
+        # row sets bound to a program (BoundRows made: one per row set
+        # the cache holds or held) and the executables those bindings
+        # have needed: one per (family, row-set shape, input shape) on
+        # the Pallas families, one per (row set, input shape) on a
+        # static chain.  dump_device reports both
+        self.row_sets_bound = 0
+        self.row_programs_built = 0
+        self._row_programs: set = set()
 
     def _note_kernel(self, name: str) -> None:
         with self._kernel_lock:
             self.kernel_calls[name] = self.kernel_calls.get(name, 0) + 1
+
+    def _note_program(self, key: tuple) -> None:
+        """A binding is about to run at ``key``: the first time, jit
+        builds (or fetches from the persistent cache) an executable."""
+        if key not in self._row_programs:
+            with self._kernel_lock:
+                if key not in self._row_programs:
+                    self._row_programs.add(key)
+                    self.row_programs_built += 1
+
+    def _bound(self, key: tuple, make) -> BoundRows:
+        """The cache's binding under ``key``; ``make() -> (program,
+        device bits or None)`` runs for a row set not bound yet."""
+        def bind():
+            program, bits = make()
+            with self._kernel_lock:
+                self.row_sets_bound += 1
+            return BoundRows(program, bits, self._note_program)
+        return self._chain_lru.get_or_build(key, bind)
 
     # -- staging ring ------------------------------------------------
     def configure_staging(self, depth: int = 0) -> None:
@@ -872,7 +972,8 @@ class JaxBackend:
         key = (B.shape, B.tobytes())  # copycheck: ok - cache key over a tiny coding matrix (k*m bytes), not payload
         hit = self._dev_matrices.get(key)
         if hit is None:
-            hit = jnp.asarray(B, dtype=jnp.int8)
+            # cast on the host: a transfer, no program of its own
+            hit = jax.device_put(np.asarray(B, dtype=np.int8))
             self._dev_matrices[key] = hit
         return hit
 
@@ -1109,35 +1210,43 @@ class JaxBackend:
 
     def gf8_fn(self, rows: np.ndarray, donate: bool = False,
                mesh=None):
-        """Best compiled kernel for an arbitrary GF(2^8) row set over
-        [.., C, L] byte chunks, LRU-cached per row set — per-pool
-        coding matrices AND per-erasure-signature decode rows (the
-        compiled analog of ISA-L's decode-table LRU).  Routing lives
-        in gf8_inner (shared with the mesh path).  ``donate=True``
-        hands the staged device input to XLA for output aliasing —
-        legal only when output bytes == input bytes (square row set,
-        m == k), so it is silently ignored otherwise.  ``mesh`` (from
-        _staged_put) selects the sharded shard_map wrapper around the
-        SAME gf8_inner kernel — one dispatch = one sharded GF matmul,
-        bit-exact vs single-chip."""
+        """The binding (BoundRows) that applies an arbitrary GF(2^8)
+        row set to [.., C, L] byte chunks, LRU-cached per row set —
+        per-pool coding matrices AND per-erasure-signature decode rows
+        (the analog of ISA-L's decode-table LRU).  On a TPU the row
+        set's bit-matrix (plane order, built here once) is an operand
+        of the one gf8_mxu_pallas program (rows_program): a new
+        signature costs a 2 KiB transfer, not a compile.  Off a TPU
+        and on a mesh the rows are a constant of their own program
+        (gf8_inner).  ``donate=True`` hands the staged device input to
+        XLA for output aliasing — legal only when output bytes ==
+        input bytes (square row set, m == k), so it is silently
+        ignored otherwise.  ``mesh`` (from _staged_put) selects the
+        sharded shard_map wrapper around the SAME kernel — one
+        dispatch = one sharded GF matmul, bit-exact vs single-chip."""
         rows = np.asarray(rows, dtype=np.int64)
         donate = donate and rows.shape[0] == rows.shape[1]
         coeffs = tuple(tuple(int(v) for v in row) for row in rows)
-        self._note_kernel(gf8_kernel())
+        kernel = gf8_kernel()
+        self._note_kernel(kernel)
         if mesh is not None:
             from ..parallel import mesh as pmesh
             dp = int(mesh.shape["dp"])
             sp = int(mesh.shape["sp"])
-            return self._chain_lru.get_or_build(
+            return self._bound(
                 ("gf8mesh", dp, sp, donate, coeffs),
-                lambda: pmesh.sharded_rows_fn(mesh, rows,
-                                              donate=donate))
-        if donate:
-            return self._chain_lru.get_or_build(
-                ("gf8don", coeffs),
-                lambda: jax.jit(gf8_inner(rows), donate_argnums=(0,)))
-        return self._chain_lru.get_or_build(
-            ("gf8", coeffs), lambda: jax.jit(gf8_inner(rows)))
+                lambda: (pmesh.sharded_rows_fn(mesh, rows,
+                                               donate=donate), None))
+
+        def make():
+            if kernel != "gf_mxu_pallas":
+                return jax.jit(gf8_inner(rows), donate_argnums=(
+                    (0,) if donate else ())), None
+            from .matrix import matrix_to_bitmatrix
+            return (rows_program(kernel, 8, donate=donate),
+                    self._device_matrix(gf_plane_bits(
+                        matrix_to_bitmatrix(rows, 8), rows.shape[1], 8)))
+        return self._bound(("gf8don" if donate else "gf8", coeffs), make)
 
     def apply_gf8_rows(self, rows: np.ndarray, data: np.ndarray
                        ) -> np.ndarray:
@@ -1160,22 +1269,27 @@ class JaxBackend:
         return out[0] if squeeze else out
 
     def packet_chain_fn(self, B: np.ndarray, w: int, packetsize: int):
-        """Compiled static XOR schedule for a packet-layout bitmatrix
-        (cauchy/liberation families), LRU-cached per matrix.  Returns a
-        jitted [batch, k, L] -> [batch, R/w, L] callable."""
+        """The binding (BoundRows) that applies a packet-layout
+        bitmatrix (cauchy/liberation families) to [batch, k, L] ->
+        [batch, R/w, L], LRU-cached per matrix.  Where the fused
+        kernel serves (packet_kernel) the matrix is an operand of the
+        one packet_mxu_pallas program of its (w, packetsize).  The
+        static XOR schedule that serves elsewhere (off a TPU, packets
+        not lane-aligned) is unrolled from the matrix's ones, so it is
+        a program per matrix by nature: there every erasure signature
+        still compiles, at every batch bucket."""
         key = ("pkt", B.shape, B.tobytes(), w, packetsize)  # copycheck: ok - cache key over a tiny bitmatrix, not payload
-
         kernel = packet_kernel(packetsize)
 
-        def build():
+        def make():
             if kernel == "packet_mxu_pallas":
-                return jax.jit(_packet_mxu_pallas_fn(
-                    np.asarray(B, dtype=np.uint8), w, packetsize))
+                return (rows_program(kernel, w, packetsize),
+                        self._device_matrix(B))
             return jax.jit(functools.partial(
                 _packet_chain, schedule=build_xor_schedule(B), w=w,
-                packetsize=packetsize))
+                packetsize=packetsize)), None
         self._note_kernel(kernel)
-        return self._chain_lru.get_or_build(key, build)
+        return self._bound(key, make)
 
     def apply_packet_xor(self, B: np.ndarray, data: np.ndarray, w: int,
                          packetsize: int) -> np.ndarray:
@@ -1195,13 +1309,14 @@ class JaxBackend:
         return out[0] if squeeze else out
 
     def _staged_call(self, data: np.ndarray, quantum: int, kernel: str,
-                     call, shard: bool = True) -> "AsyncBatch":
+                     lookup, shard: bool = True) -> "AsyncBatch":
         """The one staged, asynchronous dispatch every lane entry
         below goes through: fill a staging slot and start its h2d
-        (_staged_put), run ``call(dev, mesh, donate)`` inside
-        ``dispatch.call``, start the d2h copy, hand the slot its fence
-        and return the handle with the seven-phase ledger and the
-        fenced h2d sample."""
+        (_staged_put), look the program up (``lookup(mesh, donate)``
+        -> the one-argument callable: a row set's binding, for the GF
+        kernels), run it inside ``dispatch.call``, start the d2h copy,
+        hand the slot its fence and return the handle with the
+        seven-phase ledger and the fenced h2d sample."""
         squeeze = data.ndim == 2
         if squeeze:
             data = data[None]
@@ -1210,12 +1325,14 @@ class JaxBackend:
         dev, batch, L, done, sample, ledger, mesh = self._staged_put(
             data, quantum, shard)
         try:
-            with section("dispatch.call", kernel=kernel):
-                out = call(dev, mesh, done is not None)
+            fn = lookup(mesh, done is not None)
+            with _call_section(kernel, fn) as sec:
+                out = fn(dev)
+                sec.set_metadata(rows=out.shape[-2])
                 ledger["compute_start"] = time.time()
                 out.copy_to_host_async()
         except BaseException:
-            # kernel dispatch failed: no fence will ever retire, so
+            # lookup or dispatch failed: no fence will ever retire, so
             # hand the slot back unfenced instead of leaking it
             if done is not None:
                 done(None)
@@ -1231,8 +1348,9 @@ class JaxBackend:
                              data: np.ndarray) -> "AsyncBatch":
         """Non-blocking apply_gf8_rows, for a pool's coding matrix
         (encode, delta) and per-erasure-signature inverse rows
-        (decode) alike: both ride the same staging rings,
-        signature-cached kernels, and device-phase ledger, so the OSD
+        (decode) alike: both ride the same staging rings, the same
+        program with their rows bound to it (gf8_fn), and the same
+        device-phase ledger, so the OSD
         batcher can pipeline recovery decode groups exactly like
         encode groups (double buffering: submitting the next batch
         before waiting overlaps transfers with compute).  Donation
@@ -1245,8 +1363,8 @@ class JaxBackend:
                                     8), data, 8)
         return self._staged_call(
             data, LENGTH_QUANTUM, gf8_kernel(),
-            lambda dev, mesh, donate:
-                self.gf8_fn(rows, donate=donate, mesh=mesh)(dev))
+            lambda mesh, donate:
+                self.gf8_fn(rows, donate=donate, mesh=mesh))
 
     def apply_packet_async(self, B: np.ndarray, data: np.ndarray, w: int,
                            packetsize: int) -> "AsyncBatch":
@@ -1254,13 +1372,12 @@ class JaxBackend:
         apply_gf8_rows_async, for encode (the pool's coding
         bit-matrix), decode (per-signature recovery rows) and delta
         alike.  The staging quantum is a whole region of w packets;
-        the program is looked up through packet_chain_fn on every
+        the binding is looked up through packet_chain_fn on every
         call, under its one cache key.  There is no sharded packet
         apply: on a mesh the batch takes the single-chip layout."""
         return self._staged_call(
             data, w * packetsize, packet_kernel(packetsize),
-            lambda dev, mesh, donate:
-                self.packet_chain_fn(B, w, packetsize)(dev),
+            lambda mesh, donate: self.packet_chain_fn(B, w, packetsize),
             shard=False)
 
     def apply_bitmatrix_bytes(self, B: np.ndarray, data: np.ndarray,
@@ -1295,13 +1412,15 @@ class JaxBackend:
                 f"chunk length must be a multiple of {wbytes} for w={w}")
         self._note_kernel("bitplane_xla")
 
-        def call(dev, mesh, donate):
+        def lookup(mesh, donate):
             if mesh is not None:
-                return self._mesh_apply_fn(mesh, w)(
-                    self._device_matrix_mesh(B, mesh), dev)
-            return _apply_byte_domain(self._device_matrix(B), dev, w)
+                return functools.partial(
+                    self._mesh_apply_fn(mesh, w),
+                    self._device_matrix_mesh(B, mesh))
+            return functools.partial(_apply_byte_domain,
+                                     self._device_matrix(B), w=w)
         return self._staged_call(data, LENGTH_QUANTUM * wbytes,
-                                 "bitplane_xla", call)
+                                 "bitplane_xla", lookup)
 
     def apply_bitmatrix_bytes_device(self, B: np.ndarray, dev_data, w: int):
         """Device-resident apply: input is already a device array (padded

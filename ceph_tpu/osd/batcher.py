@@ -324,6 +324,11 @@ class _Lane:
         """What _cpu_rate times."""
         self.twin_call(twin, key, reqs, stack)
 
+    def book(self, b, key: Tuple, reqs, nstripes: int) -> dict:
+        """Count one device dispatch of the lane; -> keywords for its
+        ``batcher.dispatch`` section."""
+        return {}
+
 
 class _EncLane(_Lane):
     name, prefix, group = "enc", "", "encode"
@@ -415,6 +420,24 @@ class _DecLane(_Lane):
 
     def single(self, b, key, r):
         return ecutil.decode(r.sinfo, r.ec_impl, r.have, set(r.want))
+
+    def book(self, b, key, reqs, nstripes):
+        """The plug-in's entry reconstructs every chunk absent from
+        the have-set key[2] (tpu.decode_batch_async), the riders want
+        the missing-set key[3]: chunk rows produced and asked for,
+        and the signature if the process dispatches it for the first
+        time."""
+        out = (reqs[0].ec_impl.get_chunk_count() - len(key[2])) * nstripes
+        wanted = len(key[3]) * nstripes
+        b.dec_rows_out += out
+        b.dec_rows_wanted += wanted
+        cls = EncodeBatcher
+        if key[1:] not in cls._dec_signatures:
+            with cls._breaker_lock:
+                if key[1:] not in cls._dec_signatures:
+                    cls._dec_signatures.add(key[1:])
+                    b.dec_signatures += 1
+        return {"rows_out": out, "rows_wanted": wanted}
 
     def split(self, b, key, reqs, result):
         """{wanted shard: bytes} per rider: reconstructed shards (the
@@ -552,6 +575,11 @@ class EncodeBatcher:
     # be visible from every OSD's dump_device
     _prewarm_errors: List[dict] = []
     PREWARM_ERRORS_CAP = 64
+    # (geometry, have-set, missing-set) of every decode group some
+    # batcher of the process has dispatched: a signature is a row set
+    # of the one shared backend, so it is new once per process, and
+    # the per-OSD ``dec_signatures`` sum to len() of this
+    _dec_signatures: set = set()
 
     def __init__(self, conf=None, perf=None, perf_coll=None,
                  recorder=None, contention=None, daemon: str = ""):
@@ -839,6 +867,14 @@ class EncodeBatcher:
         self.dec_reqs = 0            # decode requests served
         self.dec_coalesced = 0       # decode requests that shared a call
         self.dec_cpu_reqs = 0        # decode requests on the CPU twin
+        self.dec_signatures = 0      # erasure signatures (have/missing
+                                     # pairs) this batcher was the
+                                     # process's first to dispatch
+        self.dec_rows_out = 0        # chunk rows decode dispatches
+                                     # produced (every absent chunk id
+                                     # times the stripes) ...
+        self.dec_rows_wanted = 0     # ... and of those, rows a rider
+                                     # asked for
         self.delta_calls = 0         # batched parity-delta calls issued
         self.delta_reqs = 0          # delta requests served
         self.delta_coalesced = 0     # delta requests that shared a call
@@ -1550,6 +1586,7 @@ class EncodeBatcher:
         cls._last_device_ts = time.monotonic()
         cls._last_idle_probe_ts = time.monotonic()
         cls._prewarm_errors = []
+        cls._dec_signatures = set()
         cls.reset_breaker()
 
     @classmethod
@@ -1743,7 +1780,7 @@ class EncodeBatcher:
         nstripes = sum(r.nstripes for r in reqs)
         with section("batcher.dispatch", lane=lane.name,
                      reqs=len(reqs), stripes=nstripes,
-                     queue_wait_us=waited * 1e6):
+                     queue_wait_us=waited * 1e6) as sec:
             try:
                 stack = lane.form(key, reqs)
                 in_bytes = _nbytes(stack)
@@ -1788,6 +1825,9 @@ class EncodeBatcher:
                 self.bperf.hinc("batch_stripes", nstripes)
                 self.bperf.inc("h2d_bytes", in_bytes)
             self._mark(reqs, lane.dispatch_event)
+            booked = lane.book(self, key, reqs, nstripes)
+            if booked:
+                sec.set_metadata(**booked)
             return (tiles, t_disp, in_bytes)
 
     def _join(self, lane: _Lane, key: Tuple, reqs: List, handle,
@@ -2208,12 +2248,20 @@ class EncodeBatcher:
                 "encode": {"reqs": self.reqs_total,
                            "twin_reqs": self.cpu_reqs},
                 "decode": {"reqs": self.dec_reqs,
-                           "twin_reqs": self.dec_cpu_reqs},
+                           "twin_reqs": self.dec_cpu_reqs,
+                           "signatures": self.dec_signatures,
+                           "rows_out": self.dec_rows_out,
+                           "rows_wanted": self.dec_rows_wanted},
                 "delta": {"reqs": self.delta_reqs,
                           "twin_reqs": self.delta_cpu_reqs},
             },
             "kernels": dict(getattr(backend, "kernel_calls", None)
                             or {}),
+            # row sets bound to a program, and the executables those
+            # bindings needed (JaxBackend; process-wide)
+            "row_sets_bound": getattr(backend, "row_sets_bound", 0),
+            "row_programs_built": getattr(backend,
+                                          "row_programs_built", 0),
             "device_errors": self.device_errors,
             "last_device_error": self.last_device_error,
             "prewarm_errors": list(cls._prewarm_errors),

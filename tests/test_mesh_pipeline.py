@@ -213,9 +213,13 @@ def test_pallas_kernel_traces_under_sharded_rows_fn(monkeypatch):
     from ceph_tpu.parallel import mesh as pmesh
 
     def pallas_inner(rows):
+        import functools
+        import jax.numpy as jnp
         rows = np.asarray(rows, dtype=np.int64)
-        return je._gf_mxu_pallas_fn(matrix_to_bitmatrix(rows, 8),
-                                    rows.shape[1], 8, interpret=True)
+        bits = je.gf_plane_bits(matrix_to_bitmatrix(rows, 8),
+                                rows.shape[1], 8)
+        return functools.partial(je._gf_mxu_pallas, jnp.asarray(bits),
+                                 w=8, interpret=True)
     monkeypatch.setattr(je, "gf8_inner", pallas_inner)
     k, m, cs = 8, 4, 512
     cpu = make_cpu(k, m)

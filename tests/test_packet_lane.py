@@ -478,6 +478,47 @@ def test_cluster_reads_back_with_osds_down(pool, n_down):
         and lanes["errors"] == 0, lanes
 
 
+def decode_counts(pool) -> dict:
+    """The decode lane's row and signature counters over the live
+    OSDs (dump_device's ``lanes.decode``)."""
+    out = dict.fromkeys(("reqs", "signatures", "rows_out", "rows_wanted"),
+                        0)
+    for osd in pool.cl.osds.values():
+        if osd is not None:
+            for key, v in osd.encode_batcher.device_dump()[
+                    "lanes"]["decode"].items():
+                if key in out:
+                    out[key] += v
+    return out
+
+
+def test_cluster_decode_lane_counts_rows_and_signatures(pool):
+    """Two OSDs down (ISSUE 34): every object reads back, and the
+    decode lane's counters move as the reads say.  A read gathers k=4
+    of the 7 shards, so a dispatch produces all 3 absent chunks a
+    stripe where its riders lost 1 or 2 data chunks; the signatures
+    were all dispatched by the reads before, so none is new."""
+    assert pool.down == [0, 1]
+    known = len(EncodeBatcher._dec_signatures)
+    assert known >= 1
+    before = decode_counts(pool)
+    assert 1 <= before["signatures"] <= known
+    for name, obj in pool.objects.items():
+        assert pool.io.read(name, length=len(obj) + 1) == obj, name
+    after = decode_counts(pool)
+    d = {key: after[key] - before[key] for key in after}
+    stripes = sum(-(-len(o) // WIDTH) for o in pool.objects.values())
+    assert d["reqs"] >= 1 and d["signatures"] == 0, d
+    assert len(EncodeBatcher._dec_signatures) == known
+    assert d["rows_out"] % M == 0 and \
+        1 <= d["rows_out"] // M <= stripes, (d, stripes)
+    assert d["rows_out"] // M <= d["rows_wanted"] <= \
+        2 * d["rows_out"] // M, d
+    backend = tpu_plugin.shared_backend()
+    assert backend.row_sets_bound >= known
+    assert backend.row_programs_built >= 1
+
+
 def test_cluster_overwrites_degraded_and_recovers_every_shard(pool):
     assert pool.down == [0, 1]
     patch = np.random.default_rng(32).bytes(2 * SU)

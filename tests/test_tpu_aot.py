@@ -34,13 +34,12 @@ def v5e():
     return topo.devices
 
 
-def compile_for(fn, shape, sharding):
+def compile_for(program, bits_shape, shape, sharding):
+    """Lower and compile a rows_program for the described chip, the
+    row set an operand of ``bits_shape`` as the backend binds it."""
+    bits = jax.ShapeDtypeStruct(bits_shape, jnp.int8, sharding=sharding)
     arg = jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=sharding)
-    return jax.jit(fn).lower(arg).compile()
-
-
-RS_K8M4 = matrix_to_bitmatrix(
-    reed_sol_vandermonde_coding_matrix(8, 4, 8), 8)
+    return program.lower(bits, arg).compile()
 
 
 @pytest.mark.parametrize("shape", [
@@ -48,7 +47,19 @@ RS_K8M4 = matrix_to_bitmatrix(
     (64, 8, 131072),             # 1 MiB stripes
 ])
 def test_gf_mxu_kernel_compiles_for_v5e(v5e, shape):
-    compile_for(je._gf_mxu_pallas_fn(RS_K8M4, 8, 8), shape,
+    compile_for(je.rows_program("gf_mxu_pallas", 8), (32, 64), shape,
+                SingleDeviceSharding(v5e[0]))
+
+
+@pytest.mark.parametrize("rows,k,batch", [(2, 4, 64), (1, 4, 64),
+                                          (4, 4, 64)])
+def test_gf_mxu_kernel_compiles_at_the_degraded_cells_shapes(v5e, rows,
+                                                             k, batch):
+    """k4m2.degraded_read_4m: recovery rows of a read that gathered k
+    of 6 shards (2 rows, the encode matrix's shape), a single row, and
+    a square set with its input donated, at the 4 KiB unit."""
+    compile_for(je.rows_program("gf_mxu_pallas", 8, donate=rows == k),
+                (8 * rows, 8 * k), (batch, k, 4096),
                 SingleDeviceSharding(v5e[0]))
 
 
@@ -56,8 +67,8 @@ def test_gf_mxu_kernel_compiles_for_v5e(v5e, shape):
 def test_gf_mxu_kernel_compiles_at_the_block_cap(v5e):
     """4 MiB stripes: _pick_block_len hits its 1<<19 cap, the largest
     VMEM block the kernel ever asks for (~15 s of compile)."""
-    compile_for(je._gf_mxu_pallas_fn(RS_K8M4, 8, 8), (8, 8, 524288),
-                SingleDeviceSharding(v5e[0]))
+    compile_for(je.rows_program("gf_mxu_pallas", 8), (32, 64),
+                (8, 8, 524288), SingleDeviceSharding(v5e[0]))
 
 
 def test_packet_mxu_kernel_compiles_for_v5e(v5e):
@@ -66,28 +77,24 @@ def test_packet_mxu_kernel_compiles_for_v5e(v5e):
         "jerasure", {"k": "10", "m": "4", "technique": "cauchy_good"})
     core = cg.core
     compile_for(
-        je._packet_mxu_pallas_fn(np.asarray(core.bitmatrix, np.uint8),
-                                 core.w, core.packetsize),
-        (8, 10, cg.get_chunk_size(4 << 20)),
+        je.rows_program("packet_mxu_pallas", core.w, core.packetsize),
+        core.bitmatrix.shape, (8, 10, cg.get_chunk_size(4 << 20)),
         SingleDeviceSharding(v5e[0]))
 
 
-@pytest.mark.parametrize("rows,batch", [(32, 8), (32, 128), (16, 8)])
+@pytest.mark.parametrize("rows,batch", [(32, 8), (32, 128), (16, 8),
+                                        (8, 1024)])
 def test_packet_mxu_kernel_compiles_at_the_served_shapes(v5e, rows, batch):
-    """cauchy_k10m4.write_4m's dispatches: cauchy_good k=10 m=4
+    """The Cauchy cells' dispatches: cauchy_good k=10 m=4
     packetsize=2048 at the 64 KiB chunk (4 regions), one object (7
-    stripes staged as 8) and the largest group the load forms (128);
-    ``rows`` 32 is the encode bit-matrix, 16 the recovery rows of two
-    lost chunks."""
-    cg = ecreg.instance().factory(
-        "jerasure", {"k": "10", "m": "4", "technique": "cauchy_good",
-                     "packetsize": "2048"})
-    bits = np.asarray(cg.core.bitmatrix, np.uint8)
-    if rows != bits.shape[0]:
-        _, bits = cg.core._recovery_rows(tuple(range(2, 12)), (0, 1))
-    assert bits.shape == (rows, 80)
-    compile_for(je._packet_mxu_pallas_fn(bits, 8, 2048),
-                (batch, 10, 65536), SingleDeviceSharding(v5e[0]))
+    stripes staged as 8) and the largest group the load forms (128).
+    ``rows`` 32 is the encode bit-matrix AND the recovery rows of a
+    read that gathered 10 of 14 shards (cauchy_k10m4.degraded_read_4m:
+    one program for both, whatever the signature), 16 and 8 the rows
+    of two chunks and one (a caller that hands in more than k)."""
+    compile_for(je.rows_program("packet_mxu_pallas", 8, 2048),
+                (rows, 80), (batch, 10, 65536),
+                SingleDeviceSharding(v5e[0]))
 
 
 def test_sharded_rows_fn_compiles_for_a_v5e_2x2_mesh(v5e, monkeypatch):

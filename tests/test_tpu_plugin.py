@@ -225,7 +225,7 @@ def test_packet_mxu_pallas_kernel_interpret():
     import jax.numpy as jnp
 
     from ceph_tpu.ops.jax_engine import (_packet_chain,
-                                         _packet_mxu_pallas_fn,
+                                         _packet_mxu_pallas,
                                          build_xor_schedule)
     from ceph_tpu.ops.matrix import (cauchy_good_coding_matrix,
                                      matrix_to_bitmatrix)
@@ -237,8 +237,9 @@ def test_packet_mxu_pallas_kernel_interpret():
             sched = build_xor_schedule(rows)
             ref = np.asarray(_packet_chain(jnp.asarray(data), sched,
                                            w, ps))
-            out = np.asarray(_packet_mxu_pallas_fn(
-                rows, w, ps, interpret=True)(jnp.asarray(data)))
+            out = np.asarray(_packet_mxu_pallas(
+                jnp.asarray(rows, jnp.int8), jnp.asarray(data), w=w,
+                packetsize=ps, interpret=True))
             assert np.array_equal(out, ref), (k, m, w, ps, rows.shape)
 
 
@@ -250,7 +251,7 @@ def test_gf_mxu_pallas_kernel_interpret():
     import jax.numpy as jnp
 
     from ceph_tpu.ops.engine import NumpyBackend
-    from ceph_tpu.ops.jax_engine import _gf_mxu_pallas_fn
+    from ceph_tpu.ops.jax_engine import _gf_mxu_pallas, gf_plane_bits
     from ceph_tpu.ops.matrix import (make_decoding_matrix,
                                      matrix_to_bitmatrix,
                                      reed_sol_vandermonde_coding_matrix)
@@ -261,8 +262,9 @@ def test_gf_mxu_pallas_kernel_interpret():
     for mat, L in ((M, 256), (M, 192), (rows, 320)):
         B = matrix_to_bitmatrix(mat, w)
         data = rng.integers(0, 256, (2, k, L), dtype=np.uint8)
-        out = np.asarray(_gf_mxu_pallas_fn(B, k, w, interpret=True)(
-            jnp.asarray(data)))
+        out = np.asarray(_gf_mxu_pallas(
+            jnp.asarray(gf_plane_bits(B, k, w)), jnp.asarray(data), w=w,
+            interpret=True))
         ref = NumpyBackend().apply_matrix(mat, data, 8)
         assert np.array_equal(out, ref), (mat.shape, L)
 
@@ -282,8 +284,9 @@ def test_kernel_builder_failure_raises_no_chain_fallback(monkeypatch):
     monkeypatch.setattr(je, "gf8_kernel", lambda: "gf_mxu_pallas")
     monkeypatch.setattr(je, "packet_kernel",
                         lambda ps: "packet_mxu_pallas")
-    monkeypatch.setattr(je, "_gf_mxu_pallas_fn", refuse)
-    monkeypatch.setattr(je, "_packet_mxu_pallas_fn", refuse)
+    monkeypatch.setattr(je, "_gf_mxu_pallas", refuse)
+    monkeypatch.setattr(je, "_packet_mxu_pallas", refuse)
+    je.rows_program.cache_clear()    # programs traced before the patch
     reg = ecreg.instance()
     rs = reg.factory("tpu", {"k": "3", "m": "2"})
     rs.core.backend = be
