@@ -30,6 +30,7 @@ from ..mon.client import MonClient
 from ..msg.messages import MOSDOp, MOSDOpReply, MWatchNotify, OSDOp
 from ..msg.messenger import Connection, Dispatcher, Messenger
 from ..osd.osdmap import OSDMap, PGid
+from ..utils import copytrack
 from ..utils.config import Config, default_config
 from ..utils.hops import HopAccum
 from ..utils.log import Dout
@@ -51,6 +52,18 @@ class RadosTimeoutError(RadosError, TimeoutError):
 
     def __init__(self, msg: str):
         super().__init__(110, msg)       # errno 110 = ETIMEDOUT
+
+
+def _api_bytes(buf, site: str) -> bytes:
+    """A reply's payload as the API returns it: ``bytes``.  A large
+    payload is decoded as a view of the received frame
+    (``Decoder.buffer``); the synchronous API's return value is the
+    one place it is copied out, and says so.  ``aio_*`` callers read
+    ``Completion.reply.out_data`` and get the view itself."""
+    if type(buf) is bytes:
+        return buf
+    copytrack.note_copy(len(buf), site)
+    return bytes(buf)  # copycheck: ok - immutable result at the API boundary
 
 
 class Completion:
@@ -547,7 +560,8 @@ class IoCtx:
         atomically with the op; -> its output payload."""
         reply = self._obj_op(oid, [OSDOp("call", name=f"{cls}.{method}",
                                          data=indata)])
-        return reply.out_data[0] if reply.out_data else b""
+        return _api_bytes(reply.out_data[0], "rados.exec_cls") \
+            if reply.out_data else b""
 
     def dup(self) -> "IoCtx":
         """A sibling handle on the same pool with INDEPENDENT snap
@@ -672,7 +686,7 @@ class IoCtx:
     def read(self, oid: str, length: int = 0, offset: int = 0) -> bytes:
         reply = self._obj_op(
             oid, [OSDOp("read", offset=offset, length=length)])
-        return reply.out_data[0]
+        return _api_bytes(reply.out_data[0], "rados.read")
 
     def stat(self, oid: str) -> Tuple[int, Tuple[int, int]]:
         """-> (size, version)."""
@@ -681,7 +695,7 @@ class IoCtx:
 
     def getxattr(self, oid: str, name: str) -> bytes:
         reply = self._obj_op(oid, [OSDOp("getxattr", name=name)])
-        return reply.out_data[0]
+        return _api_bytes(reply.out_data[0], "rados.getxattr")
 
     def getxattrs(self, oid: str) -> Dict[str, bytes]:
         reply = self._obj_op(oid, [OSDOp("getxattrs")])
@@ -698,7 +712,8 @@ class IoCtx:
             if e.errno == 61:            # ENODATA: key absent
                 return None
             raise
-        return reply.out_data[0] if reply.out_data else None
+        return _api_bytes(reply.out_data[0], "rados.omap_get_by_key") \
+            if reply.out_data else None
 
     def copy_from(self, dst_oid: str, src_oid: str) -> None:
         """Server-side object copy (reference CEPH_OSD_OP_COPY_FROM,

@@ -32,15 +32,20 @@ from ..msg.message import (CRC_LEN, HEADER_LEN, decode_frame_body,
 from ..msg.messages import MAck
 from ..msg.messenger import (ACK_EVERY_BYTES, ACK_EVERY_MSGS, MAX_FRAME,
                              _IOV_BATCH, Connection, Messenger)
-from ..utils.encoding import DecodeError
+from ..utils.encoding import DecodeError, copied_bytes
 from ..utils.tracer import section
 from .reactor import Reactor
 
-# recv chunk per call; level-triggered readiness re-arms anything left
+# at most this many receive calls per readiness event, and this many
+# chunks' worth of bytes, so one firehose peer cannot monopolize a
+# tick; level-triggered readiness re-arms anything left
 _RECV_CHUNK = 1 << 18
-# at most this many recv() calls per readiness event, so one firehose
-# peer cannot monopolize a tick
 _RECV_ROUNDS = 64
+# a frame of this many bytes or more that has not wholly arrived is
+# received into a buffer of its own and decoded there (its header says
+# how long it is); smaller frames are cut out of the connection's one
+# reusable receive buffer, which is this long
+_DIRECT_MIN = 1 << 14
 # connection-to-shard affinity (ISSUE 13): every client op votes for
 # its PG's owning shard; after this many votes a strict majority for a
 # foreign shard re-pins the connection's pumps there
@@ -50,7 +55,7 @@ _VOTE_WINDOW = 32
 class CrimsonConnection(Connection):
     """A ``Connection`` whose pumps are reactor callbacks, not threads.
 
-    Reactor-owned fields (``_reg_sock``, ``_rbuf``, ``_wq``,
+    Reactor-owned fields (``_reg_sock``, the receive buffers, ``_wq``,
     ``_wants_write``) are touched only on the reactor thread; shared
     session state (queues, seqs, state) stays under the inherited lock
     because handshake/control threads still mutate it.
@@ -67,7 +72,15 @@ class CrimsonConnection(Connection):
         self._pumps_started = True
         self._reg_sock: Optional[socket.socket] = None
         self._reg_gen = 0
-        self._rbuf = bytearray()
+        # receive side: one reusable buffer, filled by recv_into and
+        # holding [_rlo, _rhi); and the large frame in flight, if any,
+        # in a buffer of its own (never reused, never written after
+        # its last recv_into: the decoder's views point into it)
+        self._rbuf = bytearray(_DIRECT_MIN)
+        self._rview = memoryview(self._rbuf)
+        self._rlo = self._rhi = 0
+        self._fview: Optional[memoryview] = None    # over its buffer
+        self._fgot = 0
         self._wq: deque = deque()       # pending iovecs (memoryviews)
         self._wants_write = False
         # write coalescing (ISSUE 13): replies generated within one
@@ -111,7 +124,7 @@ class CrimsonConnection(Connection):
                 return                  # raced with death/replace
         self._reg_sock = sock
         self._reg_gen = gen
-        self._rbuf.clear()
+        self._rx_reset()
         self._wq.clear()
         self._wants_write = False
         self._read_paused = False
@@ -121,7 +134,7 @@ class CrimsonConnection(Connection):
     def _detach(self, sock) -> None:
         if self._reg_sock is sock:
             self._reg_sock = None
-            self._rbuf.clear()
+            self._rx_reset()
             self._wq.clear()
             self._wants_write = False
             self._read_paused = False
@@ -173,8 +186,9 @@ class CrimsonConnection(Connection):
         if target is self._reactor:
             return
         self._migrating = True
-        # defer past the current read pump: migrating mid-parse would
-        # hand _rbuf to the new reactor while this one still walks it
+        # defer past the current read pump: migrating mid-delivery
+        # would hand the receive state to the new reactor while this
+        # one still works through the event's frames
         self._reactor.call_soon(self._migrate, target)
 
     def _migrate(self, target: Reactor) -> None:
@@ -192,8 +206,8 @@ class CrimsonConnection(Connection):
         old.unregister(sock)
         self._reactor = target
         # nothing fires this connection's callbacks between the old
-        # shard's unregister and the adopt below, so _rbuf/_wq hand
-        # over untouched; stale callbacks left on the old reactor
+        # shard's unregister and the adopt below, so the receive state
+        # and _wq hand over untouched; stale callbacks on the old reactor
         # re-route via the in_reactor() guard in _pump_writes
         target.call_soon(self._adopt, sock, gen)
 
@@ -351,58 +365,135 @@ class CrimsonConnection(Connection):
             # re-fire the selector on the next tick
             self._reactor.want_read(sock, True)
 
+    def _rx_reset(self) -> None:
+        """Forget what a socket generation left half received: the
+        bytes die with the socket, the peer resends whole frames."""
+        self._rlo = self._rhi = 0
+        self._fview = None
+        self._fgot = 0
+
+    def _shared_room(self) -> memoryview:
+        """The free tail of the reusable buffer; a leftover partial
+        frame moves to the front first (it is under ``_DIRECT_MIN``
+        long, so what it still lacks then fits)."""
+        lo, hi = self._rlo, self._rhi
+        if lo:
+            if hi > lo:
+                self._rview[:hi - lo] = self._rview[lo:hi]
+                self.rx_bytes_copied += hi - lo
+            self._rlo, self._rhi = 0, hi - lo
+        return self._rview[self._rhi:]
+
     def _recv_rounds(self, sock, gen) -> None:
-        got = 0
+        """One readiness event: receive, reassemble whole frames, then
+        decode and dispatch them.  Bytes land in the large frame in
+        flight if there is one (as many a call as the kernel has, never
+        past the frame's end), else in the reusable buffer, where the
+        next header says which of the two the following bytes go to."""
+        frames: list = []
+        alive = True
+        got = calls = 0
+        copied0 = self.rx_bytes_copied
         with section("msgr.recv", peer=self.peer_name) as sec:
+            left = _RECV_ROUNDS * _RECV_CHUNK
             try:
                 for _ in range(_RECV_ROUNDS):
-                    chunk = sock.recv(_RECV_CHUNK)
-                    if not chunk:
-                        self._io_error(sock, gen)
-                        return
-                    self._rbuf += chunk
-                    got += len(chunk)
-                    if len(chunk) < _RECV_CHUNK:
+                    direct = self._fview is not None
+                    room = self._fview[self._fgot:self._fgot + left] \
+                        if direct else self._shared_room()
+                    calls += 1
+                    n = sock.recv_into(room)
+                    if not n:
+                        alive = False
+                        break
+                    got += n
+                    left -= n
+                    if direct:
+                        self._fgot += n
+                        if self._fgot == len(self._fview):
+                            self._frame_whole(frames)
+                    else:
+                        self._rhi += n
+                        if not self._cut_frames(frames):
+                            break       # corrupt stream: read no more
+                    # enough for one event: the kernel gave all it had,
+                    # the budget is spent, or a large frame got whole
+                    # (what follows it re-fires the selector)
+                    if n < len(room) or left <= 0 or \
+                            (direct and self._fview is None):
                         break
             except (BlockingIOError, InterruptedError):
                 pass
             except (OSError, ConnectionError):
-                self._io_error(sock, gen)
-                return
+                alive = False
             finally:
-                sec.set_metadata(bytes=got)
-        self._parse_frames(sock, gen)
+                self.rx_calls += calls
+                sec.set_metadata(bytes=got, calls=calls,
+                                 copied=self.rx_bytes_copied - copied0)
+        if not alive:
+            self._io_error(sock, gen)
+            return
+        self._deliver_frames(frames, sock, gen)
 
-    def _parse_frames(self, sock, gen) -> None:
-        buf = self._rbuf
-        while True:
-            if len(buf) < HEADER_LEN:
-                return
-            head = bytes(buf[:HEADER_LEN])  # copycheck: ok - 18-byte header
+    def _cut_frames(self, frames: list) -> bool:
+        """Walk the reusable buffer: every whole frame in it is cut out
+        as ``bytes`` (its one copy); a frame of ``_DIRECT_MIN`` bytes
+        or more that is not whole yet gets its own buffer, what has
+        arrived of it moves in, and the rest is received there.  False
+        when a header is bad: the error joins ``frames`` in its place,
+        so what arrived before it is still delivered first."""
+        view, lo, hi = self._rview, self._rlo, self._rhi
+        while hi - lo >= HEADER_LEN:
+            head = bytes(view[lo:lo + HEADER_LEN])  # copycheck: ok - 18-byte header
             try:
                 mtype, seq, plen = decode_frame_header(head)
                 if plen > MAX_FRAME:
                     raise DecodeError(f"oversized frame {plen}")
-            except DecodeError:
-                if self.msgr.conf["ms_die_on_bad_msg"]:
-                    raise
-                self._io_error(sock, gen)
-                return
+            except DecodeError as e:
+                frames.append(e)
+                self._rlo = lo
+                return False
             total = HEADER_LEN + plen + CRC_LEN
-            if len(buf) < total:
-                return
-            # single-copy extraction through a view (a bytearray slice
-            # would copy once into a bytearray and again into bytes);
-            # the view must be released before the bytearray resizes
-            view = memoryview(buf)
-            payload = bytes(view[HEADER_LEN:HEADER_LEN + plen])  # copycheck: ok - rx reassembly into immutable frame
-            crc = bytes(view[HEADER_LEN + plen:total])  # copycheck: ok - 4-byte trailer crc
-            view.release()
-            del buf[:total]
+            if hi - lo < total:
+                if total >= _DIRECT_MIN:
+                    self._fview = memoryview(bytearray(total))
+                    self._fview[:hi - lo] = view[lo:hi]
+                    self.rx_bytes_copied += hi - lo - HEADER_LEN
+                    self._fgot = hi - lo
+                    lo = hi = 0
+                break
+            body = lo + HEADER_LEN
+            payload = bytes(view[body:body + plen])  # copycheck: ok - small frame cut out of the reusable buffer
+            crc = bytes(view[body + plen:lo + total])  # copycheck: ok - 4-byte trailer crc
+            self.rx_bytes_copied += plen
+            self._note_rx_frame(plen, direct=False)
+            frames.append((mtype, seq, head, payload, crc, plen))
+            lo += total
+        self._rlo, self._rhi = lo, hi
+        return True
+
+    def _frame_whole(self, frames: list) -> None:
+        """The large frame in flight has its last byte: its payload
+        goes to the decoder as a read-only view of the buffer."""
+        view, self._fview, self._fgot = self._fview, None, 0
+        head = bytes(view[:HEADER_LEN])  # copycheck: ok - 18-byte header
+        mtype, seq, plen = decode_frame_header(head)    # checked before
+        payload = view[HEADER_LEN:HEADER_LEN + plen].toreadonly()
+        crc = bytes(view[HEADER_LEN + plen:])  # copycheck: ok - 4-byte trailer crc
+        self._note_rx_frame(plen, direct=True)
+        frames.append((mtype, seq, head, payload, crc, plen))
+
+    def _deliver_frames(self, frames: list, sock, gen) -> None:
+        for frame in frames:
             try:
-                with section("msgr.decode", bytes=plen):
+                if isinstance(frame, DecodeError):
+                    raise frame             # the bad header, in its turn
+                mtype, seq, head, payload, crc, plen = frame
+                with section("msgr.decode", bytes=plen) as sec:
+                    c0 = copied_bytes()
                     msg = decode_frame_body(mtype, seq, head, payload,
                                             crc)
+                    sec.set_metadata(copied=copied_bytes() - c0)
                 msg.stamp_hop("recv")
             except DecodeError:
                 if self.msgr.conf["ms_die_on_bad_msg"]:

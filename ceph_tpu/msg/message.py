@@ -21,7 +21,7 @@ import zlib
 from typing import Callable, Dict, Optional, Type
 
 from ..utils import copytrack
-from ..utils.encoding import DecodeError
+from ..utils.encoding import DecodeError, note_copied
 
 FRAME_MAGIC = 0x43455048  # "CEPH" — version 2 framing
 _PREAMBLE = struct.Struct("<IHQI")  # magic, type, seq, payload_len
@@ -153,8 +153,12 @@ HEADER_LEN = _PREAMBLE.size
 CRC_LEN = _CRC.size
 
 
-def decode_frame_body(mtype: int, seq: int, head: bytes, payload: bytes,
+def decode_frame_body(mtype: int, seq: int, head: bytes, payload,
                       crc_bytes: bytes) -> Message:
+    """``payload`` is ``bytes`` or a read-only ``memoryview`` of the
+    buffer the frame was received into: the CRC is folded over it in
+    place and the message's ``decode_payload`` decodes it where it
+    lies (``Decoder.buffer`` hands its large fields out as views)."""
     (crc,) = _CRC.unpack(crc_bytes)
     if crc != 0:                         # 0 = sender ran ms_crc_data=false
         actual = zlib.crc32(payload, zlib.crc32(head))
@@ -171,6 +175,7 @@ def decode_frame_body(mtype: int, seq: int, head: bytes, payload: bytes,
             payload = codec.decompress(payload[1:])
         except Exception as e:
             raise DecodeError(f"decompress failed: {e}")
+        note_copied(len(payload))      # materialised anew, as bytes
     cls = MSG_REGISTRY.get(mtype)
     if cls is None:
         raise DecodeError(f"unknown message type {mtype}")

@@ -84,10 +84,18 @@ class OSDOp:
         e.str(self.op).u64(self.offset).u64(self.length)
         e.bytes(self.data).str(self.name)
 
+    # ops whose ``data`` is object data: a large one is decoded as a
+    # view of the received frame (Decoder.buffer).  Every other op's
+    # data (an xattr or omap value, a class call's input) is kept
+    # beyond the op and decodes as bytes.
+    _DATA_OPS = frozenset(("write", "writefull", "append"))
+
     @classmethod
     def decode(cls, d: Decoder) -> "OSDOp":
-        return cls(op=d.str(), offset=d.u64(), length=d.u64(),
-                   data=d.bytes(), name=d.str())
+        op, offset, length = d.str(), d.u64(), d.u64()
+        data = d.buffer() if op in cls._DATA_OPS else d.bytes()
+        return cls(op=op, offset=offset, length=length, data=data,
+                   name=d.str())
 
 
 @register
@@ -186,7 +194,8 @@ class MOSDOpReply(Message):
     def decode_payload(cls, buf: bytes) -> "MOSDOpReply":
         d = Decoder(buf)
         m = cls(tid=d.u64(), result=d.i32(), epoch=d.u32())
-        m.out_data = [d.bytes() for _ in range(d.u32())]
+        # a read's bytes: views of the frame when large
+        m.out_data = [d.buffer() for _ in range(d.u32())]
         m.extra = _dec_json(d.bytes())
         m.hops = decode_ledger(d)
         return m
@@ -226,7 +235,8 @@ class MOSDECSubOpWrite(Message):
                                      # dedup on (from, tid, seg))
         # encoded store Transaction: bytes, or a list of buffer
         # fragments (Transaction.encode_parts()) kept by reference
-        # until the socket — receivers always see joined bytes
+        # until the socket — receivers see one buffer: bytes, or a
+        # read-only view of the received frame (Decoder.buffer)
         self.txn = txn
         self.log_entries = log_entries or []   # pg-log dicts
         self.at_version = at_version
@@ -260,7 +270,7 @@ class MOSDECSubOpWrite(Message):
     def decode_payload(cls, buf: bytes) -> "MOSDECSubOpWrite":
         d = Decoder(buf)
         m = cls(pgid=d.str(), shard=d.i32(), from_osd=d.i32(),
-                tid=d.u64(), epoch=d.u32(), txn=d.bytes())
+                tid=d.u64(), epoch=d.u32(), txn=d.buffer())
         m.log_entries = _dec_json(d.bytes())
         m.at_version = (d.u32(), d.u64())
         m.trace_id = d.u64()
@@ -399,7 +409,7 @@ class MOSDECSubOpReadReply(Message):
         d = Decoder(buf)
         m = cls(pgid=d.str(), shard=d.i32(), from_osd=d.i32(),
                 tid=d.u64(), epoch=d.u32())
-        m.buffers = [(d.str(), d.u64(), d.bytes())
+        m.buffers = [(d.str(), d.u64(), d.buffer())
                      for _ in range(d.u32())]
         m.attrs = [(d.str(), d.str_bytes_map()) for _ in range(d.u32())]
         m.errors = [(d.str(), d.i32()) for _ in range(d.u32())]

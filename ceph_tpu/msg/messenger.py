@@ -46,7 +46,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 from ..utils import copytrack
 from ..utils import faults as faultlib
 from ..utils.config import Config, default_config
-from ..utils.encoding import DecodeError
+from ..utils.encoding import ZC_MIN, DecodeError, copied_bytes
 from .message import (CRC_LEN, HEADER_LEN, Message, decode_frame_body,
                       decode_frame_header, encode_frame_parts)
 from .messages import MAck
@@ -76,21 +76,47 @@ class Dispatcher:
         """A lossy connection died, or a lossless one gave up."""
 
 
-def _read_exact(sock: socket.socket, n: int) -> bytes:
-    """Read exactly n bytes into one preallocated buffer (recv_into —
-    no per-chunk concatenation); the final bytes() is the single
-    receive-side reassembly copy."""
-    if n == 0:
-        return b""
-    buf = bytearray(n)
-    view = memoryview(buf)
-    got = 0
+def _read_into(sock: socket.socket, view: memoryview) -> int:
+    """Fill ``view`` from the (blocking) socket with ``recv_into``;
+    -> the receive calls it took.  ``MSG_WAITALL`` asks the kernel for
+    the whole of it in one call, one hand-off of the interpreter a
+    frame however it trickles in; a call cut short (a signal, a
+    shutdown, a handshake's timeout) is resumed here."""
+    got = calls = 0
+    n = len(view)
     while got < n:
-        r = sock.recv_into(view[got:])
+        r = sock.recv_into(view[got:], 0, socket.MSG_WAITALL)
+        calls += 1
         if not r:
             raise ConnectionError("peer closed")
         got += r
-    return bytes(buf)  # copycheck: ok - rx reassembly into immutable frame
+    return calls
+
+
+def _read_exact(sock: socket.socket, n: int) -> bytes:
+    """Read exactly n bytes of handshake or frame header as ``bytes``
+    (small by nature; a frame's payload is ``_read_frame``'s)."""
+    if n == 0:
+        return b""
+    buf = bytearray(n)
+    _read_into(sock, memoryview(buf))
+    return bytes(buf)  # copycheck: ok - small handshake/header read
+
+
+def _read_frame(sock: socket.socket, plen: int):
+    """The payload and trailer of one frame, received into a buffer of
+    the frame's own; -> (payload, crc_bytes, calls).  A payload of
+    ``ZC_MIN`` bytes or more is a read-only view of that buffer, which
+    is never written again: the decoder's large fields point into it.
+    A smaller one can hold no such field and is cut out as ``bytes``
+    (one copy of it, which the caller accounts)."""
+    frame = bytearray(plen + CRC_LEN)
+    view = memoryview(frame)
+    calls = _read_into(sock, view)
+    crc = bytes(view[plen:])  # copycheck: ok - 4-byte trailer crc
+    if plen < ZC_MIN:
+        return bytes(view[:plen]), crc, calls  # copycheck: ok - small frame, decoded as bytes
+    return view[:plen].toreadonly(), crc, calls
 
 
 _IOV_BATCH = 64     # iovecs per sendmsg call (well under Linux IOV_MAX)
@@ -178,10 +204,11 @@ class _SecureSocket:
                          [struct.pack("<I", len(ct)), ct])
             return len(pt)
 
-    def recv_into(self, view) -> int:
+    def recv_into(self, view, nbytes: int = 0, flags: int = 0) -> int:
         """Serve decrypted plaintext into the caller's buffer (must be
         explicit: __getattr__ would leak recv_into to the raw socket
-        and bypass decryption)."""
+        and bypass decryption).  ``flags`` are the raw socket's affair:
+        a record is read whole here whatever they say."""
         data = self.recv(len(view))
         view[:len(data)] = data
         return len(data)
@@ -311,6 +338,24 @@ class Connection:
         self.intended_peer = ""        # who connect_to() meant to reach
         self._recv_since_ack = 0
         self._recv_bytes_since_ack = 0
+        # receive-path account (plain attributes, one writer: the pump
+        # that reads this session's socket).  A frame is *direct* when
+        # its payload is decoded where the kernel put it, *bulk* when it
+        # was cut out of a receive buffer as bytes; ``rx_bytes_copied``
+        # is payload bytes moved between user-space buffers on the way
+        # to a whole frame, ``rx_calls`` the receive system calls.
+        self.rx_frames_direct = 0
+        self.rx_frames_bulk = 0
+        self.rx_bytes = 0
+        self.rx_bytes_copied = 0
+        self.rx_calls = 0
+
+    def _note_rx_frame(self, plen: int, direct: bool) -> None:
+        if direct:
+            self.rx_frames_direct += 1
+        else:
+            self.rx_frames_bulk += 1
+        self.rx_bytes += plen
 
     # -- public API --------------------------------------------------------
     def send_message(self, msg: Message) -> None:
@@ -499,13 +544,20 @@ class Connection:
                     # the header read above parks until a frame comes;
                     # from here on its bytes are on their way
                     with section("msgr.recv", d=self.msgr.name,
-                                 peer=self.peer_name, bytes=plen):
-                        payload = _read_exact(sock, plen)
-                        crc = _read_exact(sock, CRC_LEN)
+                                 peer=self.peer_name, bytes=plen) as sec:
+                        payload, crc, calls = _read_frame(sock, plen)
+                        direct = type(payload) is memoryview
+                        copied = 0 if direct else plen
+                        sec.set_metadata(calls=calls, copied=copied)
+                    self.rx_calls += 1 + calls      # the header's too
+                    self.rx_bytes_copied += copied
+                    self._note_rx_frame(plen, direct)
                     with section("msgr.decode", d=self.msgr.name,
-                                 bytes=plen):
+                                 bytes=plen) as sec:
+                        c0 = copied_bytes()
                         msg = decode_frame_body(mtype, seq, head,
                                                 payload, crc)
+                        sec.set_metadata(copied=copied_bytes() - c0)
                     msg.stamp_hop("recv")
                 except (OSError, ConnectionError, DecodeError) as e:
                     if isinstance(e, DecodeError) and \

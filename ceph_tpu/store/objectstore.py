@@ -272,7 +272,10 @@ class Transaction:
                 t.ops.append((name, d.str(), GHObject(d.str(), d.i32())))
             elif name in ("write", "xor_write"):
                 coll, obj = d.str(), GHObject(d.str(), d.i32())
-                t.ops.append((name, coll, obj, d.u64(), d.bytes()))
+                # a large payload stays a view of ``buf`` (a received
+                # frame, a journal record): the store copies it into
+                # its own blocks when it applies
+                t.ops.append((name, coll, obj, d.u64(), d.buffer()))
             elif name == "zero":
                 coll, obj = d.str(), GHObject(d.str(), d.i32())
                 t.ops.append((name, coll, obj, d.u64(), d.u64()))

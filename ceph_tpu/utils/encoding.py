@@ -14,6 +14,7 @@ codec — as in the reference, where both are bufferlists.
 from __future__ import annotations
 
 import struct
+import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 
@@ -175,54 +176,116 @@ class Encoder:
         return out
 
 
-class Decoder:
-    """Cursor-based decoder over one buffer (reference decode(..., bl))."""
+class _Copied(threading.local):
+    """Bytes ``Decoder.bytes()`` has copied out of buffers on this
+    thread: a running count a caller differences around one decode
+    (the messenger's ``msgr.decode`` section reports the increment as
+    ``copied``).  Per thread, so no lock and no torn add."""
+    n = 0
 
-    def __init__(self, buf: bytes, pos: int = 0, end: Optional[int] = None):
+
+_copied = _Copied()
+
+
+def copied_bytes() -> int:
+    """This thread's running count of bytes decoders copied out."""
+    return _copied.n
+
+
+def note_copied(nbytes: int) -> None:
+    """Add a copy made on a decoder's behalf (a decompressed frame)."""
+    _copied.n += nbytes
+
+
+_U8, _U16, _U32, _U64 = (struct.Struct(f) for f in ("<B", "<H", "<I", "<Q"))
+_I32, _I64, _F64 = (struct.Struct(f) for f in ("<i", "<q", "<d"))
+
+
+class Decoder:
+    """Cursor-based decoder over one buffer (reference decode(..., bl)).
+
+    The buffer may be ``bytes``, a ``bytearray`` or a ``memoryview`` (a
+    received frame, decoded where it landed).  Mirror of ``Encoder``'s
+    ``ZC_MIN`` rule: every field comes out as an immutable value of its
+    own (``bytes``, ``str``, ``int``: nothing small may pin a frame)
+    except through ``buffer()``, which hands a large length-prefixed
+    payload out as a read-only view of the buffer."""
+
+    def __init__(self, buf, pos: int = 0, end: Optional[int] = None):
         self._buf = buf
         self._pos = pos
         self._end = len(buf) if end is None else end
 
-    def _take(self, n: int) -> bytes:
-        if self._pos + n > self._end:
+    def _advance(self, n: int) -> int:
+        """Claim the next ``n`` bytes; -> where they start."""
+        pos = self._pos
+        if pos + n > self._end:
             raise DecodeError(
-                f"truncated: need {n} bytes at {self._pos}, "
-                f"have {self._end - self._pos}")
-        v = self._buf[self._pos:self._pos + n]
-        self._pos += n
-        return v
+                f"truncated: need {n} bytes at {pos}, "
+                f"have {self._end - pos}")
+        self._pos = pos + n
+        return pos
+
+    def _take(self, n: int) -> bytes:
+        pos = self._advance(n)
+        v = self._buf[pos:pos + n]
+        # a slice of bytes is bytes; of a view or bytearray, a copy here
+        return v if type(v) is bytes else bytes(v)
 
     def remaining(self) -> int:
         return self._end - self._pos
 
     # -- fixed-width integers ---------------------------------------------
+    def _fixed(self, st: struct.Struct):
+        return st.unpack_from(self._buf, self._advance(st.size))[0]
+
     def u8(self) -> int:
-        return struct.unpack("<B", self._take(1))[0]
+        return self._fixed(_U8)
 
     def u16(self) -> int:
-        return struct.unpack("<H", self._take(2))[0]
+        return self._fixed(_U16)
 
     def u32(self) -> int:
-        return struct.unpack("<I", self._take(4))[0]
+        return self._fixed(_U32)
 
     def u64(self) -> int:
-        return struct.unpack("<Q", self._take(8))[0]
+        return self._fixed(_U64)
 
     def i32(self) -> int:
-        return struct.unpack("<i", self._take(4))[0]
+        return self._fixed(_I32)
 
     def i64(self) -> int:
-        return struct.unpack("<q", self._take(8))[0]
+        return self._fixed(_I64)
 
     def f64(self) -> float:
-        return struct.unpack("<d", self._take(8))[0]
+        return self._fixed(_F64)
 
     def bool(self) -> bool:
         return self.u8() != 0
 
     # -- length-prefixed payloads -----------------------------------------
     def bytes(self) -> bytes:
-        return self._take(self.u32())
+        n = self.u32()
+        _copied.n += n
+        return self._take(n)
+
+    def buffer(self):
+        """u32 length + payload, by reference when large: under
+        ``ZC_MIN`` the payload is ``bytes`` as from ``bytes()``; at or
+        above it a read-only ``memoryview`` of the decoder's buffer, so
+        a data field points into the frame the kernel filled.  Only a
+        field whose consumers take any bytes-like uses this (the data
+        messages' payloads, a transaction's writes); the view keeps
+        the whole buffer alive for as long as it is held."""
+        n = self.u32()
+        if n < ZC_MIN:
+            _copied.n += n
+            return self._take(n)
+        pos = self._advance(n)
+        buf = self._buf
+        m = (buf if type(buf) is memoryview
+             else memoryview(buf))[pos:pos + n]
+        return m if m.readonly else m.toreadonly()
 
     def str(self) -> str:
         try:

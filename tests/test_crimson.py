@@ -233,10 +233,31 @@ def test_crimson_messengers_exchange_and_reply_on_reactor():
         rb.stop()
 
 
-def test_crimson_lossless_survives_socket_death():
+def _numbered(i, big):
+    """Message ``i`` of a stream: a ping, or (``big``) a sub-write
+    whose 384 KiB transaction is received into a buffer of its own."""
+    from ceph_tpu.msg.messages import MOSDECSubOpWrite, MOSDPing
+    if not big:
+        return MOSDPing(op=MOSDPing.PING, from_osd=0, epoch=i)
+    return MOSDECSubOpWrite(pgid="1.0", shard=1, from_osd=0, tid=i,
+                            epoch=i, txn=bytes([i]) * ((384 << 10) + i))
+
+
+def _intact(msgs, big):
+    return not big or all(
+        isinstance(m.txn, memoryview)
+        and m.txn == bytes([m.epoch]) * ((384 << 10) + m.epoch)
+        for m in msgs if hasattr(m, "txn"))
+
+
+@pytest.mark.parametrize("big", (False, True), ids=("small", "large"))
+def test_crimson_lossless_survives_socket_death(big):
     """Kill the TCP socket under a lossless session: the base-class
     reconnect machinery must redial and the unacked queue must resend,
-    with the non-blocking pumps re-registered on the new socket."""
+    with the non-blocking pumps re-registered on the new socket.
+    ``large``: the stream is 384 KiB frames, so the socket dies with
+    one half received; its buffer is dropped with the generation and
+    the resend delivers it once."""
     from ceph_tpu.msg.messages import MOSDPing
 
     conf = make_conf()
@@ -260,15 +281,19 @@ def test_crimson_lossless_survives_socket_death():
         # yank the transport out from under the session
         with conn.lock:
             sock, gen = conn.sock, conn.gen
+        if big:
+            # frames already on their way when the socket goes
+            for i in range(1, 6):
+                conn.send_message(_numbered(i, big))
         sock.close()
-        for i in range(1, 21):
-            conn.send_message(MOSDPing(op=MOSDPing.PING, from_osd=0,
-                                       epoch=i))
+        for i in range(6 if big else 1, 21):
+            conn.send_message(_numbered(i, big))
         assert cb.wait_n(21, 20), \
             f"only {len(cb.got)}/21 after reconnect"
         # at-most-once delivery held across the reconnect
         epochs = [m.epoch for m, _ in cb.got]
         assert epochs == sorted(set(epochs)) == list(range(21))
+        assert _intact([m for m, _ in cb.got], big)
     finally:
         ma.shutdown()
         mb.shutdown()
@@ -276,13 +301,15 @@ def test_crimson_lossless_survives_socket_death():
         rb.stop()
 
 
-def test_socket_failure_injection_parity_with_classic():
+@pytest.mark.parametrize("big", (False, True), ids=("small", "large"))
+def test_socket_failure_injection_parity_with_classic(big):
     """``ms_inject_socket_failures`` must behave identically on the
     crimson messenger and the classic one: both consult the SAME
     fault-registry site (msg.send) before every frame write, both
     count their trips there, and both survive the injected socket
-    deaths with exactly-once in-order delivery."""
-    from ceph_tpu.msg.messages import MOSDPing
+    deaths with exactly-once in-order delivery.  ``large``: with
+    384 KiB frames, so sockets die with a frame's own buffer half
+    filled."""
     from ceph_tpu.msg.messenger import Messenger
     from ceph_tpu.utils import faults as faultlib
 
@@ -314,13 +341,13 @@ def test_socket_failure_injection_parity_with_classic():
             conn = ma.connect_to(addr, peer_name="osd.1")
             n = 60
             for i in range(n):
-                conn.send_message(MOSDPing(op=MOSDPing.PING,
-                                           from_osd=0, epoch=i))
+                conn.send_message(_numbered(i, big))
             assert sink.wait_n(n, 60), \
                 f"{flavor}: {len(sink.got)}/{n} after injection"
             epochs = [m.epoch for m, _ in sink.got]
             assert epochs == list(range(n)), \
                 f"{flavor}: delivery not exactly-once in-order"
+            assert _intact([m for m, _ in sink.got], big)
             c = faultlib.registry().counters()[faultlib.MSG_SEND]
         finally:
             ma.shutdown()

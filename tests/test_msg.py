@@ -220,9 +220,18 @@ def test_many_messages_in_order(pair):
     assert [m.tid for m in sink.msgs] == list(range(200))
 
 
-def test_lossless_survives_socket_failures(pair):
+def _big_blob(i: int, size: int = 192 << 10) -> bytes:
+    """A payload far above the receive path's view threshold, seeded
+    by ``i`` so a frame spliced from two sockets' halves would show."""
+    return bytes([i & 0xFF]) * 7 + os.urandom(size) + bytes([i & 0xFF])
+
+
+@pytest.mark.parametrize("big_every", (0, 6), ids=("small", "large"))
+def test_lossless_survives_socket_failures(pair, big_every):
     """With 1-in-8 sends killing the socket, every message still
-    arrives exactly once, in order (reconnect + resend + seq dedup)."""
+    arrives exactly once, in order (reconnect + resend + seq dedup);
+    ``large``: every sixth is a frame of 192 KiB, so sockets die with
+    such a frame half received, and its own buffer dies with them."""
     server, client, addr, conf = pair
     sink = Collector()
     server.add_dispatcher(sink)
@@ -230,29 +239,45 @@ def test_lossless_survives_socket_failures(pair):
     conn.send_message(M.MOSDPing(op=0, from_osd=0))   # establish
     assert sink.wait_for(1)
     conf.set("ms_inject_socket_failures", 8)
+    blobs = {}
     try:
         for i in range(150):
+            ops = []
+            if big_every and i % big_every == 0:
+                blobs[i] = _big_blob(i)
+                ops = [M.OSDOp("writefull", 0, len(blobs[i]), blobs[i])]
             conn.send_message(
-                M.MOSDOp(client="client.1", tid=i, oid=f"o{i}"))
-        assert sink.wait_for(151, timeout=30.0)
+                M.MOSDOp(client="client.1", tid=i, oid=f"o{i}", ops=ops))
+        assert sink.wait_for(151, timeout=60.0)
     finally:
         conf.set("ms_inject_socket_failures", 0)
     tids = [m.tid for m in sink.msgs[1:]]
     assert tids == list(range(150))
+    for m in sink.msgs[1:]:
+        if m.tid in blobs:
+            assert isinstance(m.ops[0].data, memoryview)
+            assert m.ops[0].data == blobs[m.tid]
 
 
-def test_bidirectional_lossless_under_injection(pair):
+@pytest.mark.parametrize("txn_len", (2048, 160 << 10),
+                         ids=("small", "large"))
+def test_bidirectional_lossless_under_injection(pair, txn_len):
     """Request/reply traffic with both directions' sockets being shot
     out 1-in-5: every reply arrives exactly once, in order, without
     thread churn (regression: the per-socket-thread design stranded
-    sessions when close() failed to wake a blocked recv)."""
+    sessions when close() failed to wake a blocked recv).  ``large``:
+    the requests are frames of 160 KiB, received into buffers of their
+    own, and the server checks each one's bytes."""
     server, client, addr, conf = pair
     replies = Collector()
     client.add_dispatcher(replies)
+    torn = []
 
     class ReplyingServer(Dispatcher):
         def ms_dispatch(self, conn, msg):
             if isinstance(msg, M.MOSDECSubOpWrite):
+                if bytes(msg.txn) != bytes([msg.tid]) * txn_len:
+                    torn.append(msg.tid)
                 conn.send_message(M.MOSDECSubOpWriteReply(
                     pgid=msg.pgid, shard=msg.shard, tid=msg.tid))
                 return True
@@ -264,16 +289,23 @@ def test_bidirectional_lossless_under_injection(pair):
     try:
         for tid in range(100):
             conn.send_message(M.MOSDECSubOpWrite(
-                pgid="1.0", shard=1, tid=tid, txn=b"\x00" * 2048))
+                pgid="1.0", shard=1, tid=tid,
+                txn=bytes([tid]) * txn_len))
         assert replies.wait_for(100, timeout=60.0)
     finally:
         conf.set("ms_inject_socket_failures", 0)
     tids = [m.tid for m in replies.msgs]
     assert tids == list(range(100))
+    assert torn == []
     assert len(threading.enumerate()) < 20   # persistent pumps, no churn
 
 
-def test_reconnect_after_server_side_kill(pair):
+@pytest.mark.parametrize("in_flight", (0, 4 << 20),
+                         ids=("idle", "large_frame_in_flight"))
+def test_reconnect_after_server_side_kill(pair, in_flight):
+    """``large_frame_in_flight``: a 4 MiB frame is on its way when the
+    server's socket goes; whatever had arrived of it is dropped with
+    the socket generation and the resend delivers it once, whole."""
     server, client, addr, _ = pair
     sink = Collector()
     server.add_dispatcher(sink)
@@ -283,11 +315,21 @@ def test_reconnect_after_server_side_kill(pair):
     # server kills its socket out from under the session
     with server.lock:
         sconn = server.conns_by_name["client.1"]
+    want = 2
+    if in_flight:
+        blob = _big_blob(9, in_flight)
+        conn.send_message(M.MOSDECSubOpWrite(
+            pgid="1.0", shard=1, tid=77, txn=blob))
+        want = 3
     sconn.sock.close()
     time.sleep(0.1)
     conn.send_message(M.MOSDBoot(osd=2))
-    assert sink.wait_for(2, timeout=10.0)
-    assert sink.msgs[1].osd == 2
+    assert sink.wait_for(want, timeout=20.0)
+    time.sleep(0.1)
+    assert len(sink.msgs) == want          # nothing delivered twice
+    assert sink.msgs[-1].osd == 2
+    if in_flight:
+        assert sink.msgs[1].tid == 77 and sink.msgs[1].txn == blob
 
 
 def test_acks_bound_resend_queue(pair):
