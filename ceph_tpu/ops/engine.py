@@ -19,10 +19,14 @@ MXU.  The numpy backend here is the bit-exact CPU reference oracle.
 """
 from __future__ import annotations
 
+import math
+import threading
+from collections import OrderedDict
 from typing import Optional
 
 import numpy as np
 
+from ..utils.tracer import section
 from .gf import gf
 from .matrix import (bitmatrix_invert, make_decoding_matrix,
                      matrix_to_bitmatrix)
@@ -165,6 +169,91 @@ class NumpyBackend:
 
 
 # ---------------------------------------------------------------------------
+# recovery rows, solved once a process
+# ---------------------------------------------------------------------------
+
+class RecoveryRowsCache:
+    """The solved rows of ONE code, by (kind, chosen shards, erased
+    shards): a bounded LRU shared by every codec of that code in the
+    process — the host half of ISA-L's decode-table cache (reference
+    isa/ErasureCodeIsaTableCache.cc: one table cache a process, not
+    one a PG).  A PG builds its own codec, and a pool whose reads
+    complete on whichever k shards answered first (fast_read) meets
+    nearly every have-set of C(k+m, k) in every PG: solved per codec
+    that is a k x k GF system inverted on the PG's thread for nearly
+    every read; solved here it is once a process."""
+
+    def __init__(self, cap: int):
+        self.cap = cap
+        self._d: OrderedDict = OrderedDict()
+        self._lock = threading.Lock()
+        self.hits = 0
+        self.misses = 0
+
+    def __contains__(self, key) -> bool:
+        return key in self._d
+
+    def __len__(self) -> int:
+        return len(self._d)
+
+    def get_or_solve(self, key: tuple, solve, *args):
+        """The rows under ``key``, a pair (GF rows or None, bit rows).
+        ``solve(*args)`` runs on a miss, outside the lock: two threads that
+        meet a signature together may both solve it (equal rows, the
+        first stored wins), which costs less than a PG thread parked
+        behind another's solve."""
+        with self._lock:
+            hit = self._d.get(key)
+            if hit is not None:
+                self._d.move_to_end(key)
+                self.hits += 1
+                return hit
+        val = solve(*args)
+        for rows in val:
+            if rows is not None:         # shared by every codec of the code
+                rows.flags.writeable = False
+        with self._lock:
+            self.misses += 1
+            val = self._d.setdefault(key, val)
+            self._d.move_to_end(key)
+            while len(self._d) > self.cap:
+                self._d.popitem(last=False)
+        return val
+
+
+_ROWS_CACHES: dict = {}
+_ROWS_CACHES_LOCK = threading.Lock()
+
+
+def rows_cache_for(k: int, m: int, w: int,
+                   bitmatrix: np.ndarray) -> RecoveryRowsCache:
+    """The process's cache of the code (k, m, w, bit-matrix): codecs
+    of one matrix share it, codecs of different matrices never share
+    an entry.  Sized so that a pool's whole have-set space stays
+    resident, each have-set under two erased-sets (a read's m absent
+    chunks, prewarm's single erasures): 990 entries at k=8 m=4, 2,002
+    at k=10 m=4, about 2.3 KiB each; never under 256 nor over 4,096
+    (a k=20 m=10 code has 30 million have-sets)."""
+    key = (k, m, w, bitmatrix.shape, bitmatrix.tobytes())  # copycheck: ok - cache key over a tiny coding bit-matrix, not payload
+    with _ROWS_CACHES_LOCK:
+        cache = _ROWS_CACHES.get(key)
+        if cache is None:
+            cap = max(256, min(2 * math.comb(k + m, k), 4096))
+            cache = _ROWS_CACHES[key] = RecoveryRowsCache(cap)
+        return cache
+
+
+def rows_cache_stats() -> dict:
+    """Hits, misses and resident entries over every code's cache
+    (``dump_device``: recovery_rows_hits / _misses / _entries)."""
+    with _ROWS_CACHES_LOCK:
+        caches = list(_ROWS_CACHES.values())
+    return {"recovery_rows_hits": sum(c.hits for c in caches),
+            "recovery_rows_misses": sum(c.misses for c in caches),
+            "recovery_rows_entries": sum(len(c) for c in caches)}
+
+
+# ---------------------------------------------------------------------------
 # codec core
 # ---------------------------------------------------------------------------
 
@@ -172,7 +261,8 @@ class CodecCore:
     """Executes one erasure code: k data + m coding chunks, either from a
     GF(2^w) coding matrix (layout 'byte') or a GF(2) bitmatrix (layout
     'packet'), single-shot or batched, with decode-matrix caching per
-    erasure signature (the moral equivalent of ISA-L's table cache,
+    erasure signature in the process's cache of this code's rows
+    (RecoveryRowsCache: the moral equivalent of ISA-L's table cache,
     reference src/erasure-code/isa/ErasureCodeIsaTableCache.cc)."""
 
     def __init__(self, k: int, m: int, w: int,
@@ -196,7 +286,7 @@ class CodecCore:
                 raise ValueError("need coding_matrix or bitmatrix")
             bitmatrix = matrix_to_bitmatrix(self.coding_matrix, w)
         self.bitmatrix = np.asarray(bitmatrix, dtype=np.uint8)
-        self._decode_cache: dict = {}
+        self._decode_cache = rows_cache_for(k, m, w, self.bitmatrix)
 
     def gf8_encode_fast(self) -> bool:
         """Single source of truth for the w=8 XOR-chain eligibility:
@@ -357,55 +447,55 @@ class CodecCore:
         rows] · Rbits over GF(2).  Cached per erasure signature; this
         is the matrix the device decode pipeline jit-caches per
         (geometry, erasure-set)."""
-        key = ("rec", chosen, erased)
-        hit = self._decode_cache.get(key)
-        if hit is not None:
-            return hit
+        return self._decode_cache.get_or_solve(
+            ("rec", chosen, erased), self._solve_recovery_rows, chosen,
+            erased)
+
+    def _solve_recovery_rows(self, chosen: tuple, erased: tuple):
+        """A miss of _recovery_rows: the k x k system of ``chosen``
+        inverted and expanded to bits, inside ``ec.solve_rows``."""
         w = self.w
-        if self.coding_matrix is not None:
-            R = make_decoding_matrix(self.coding_matrix, w,
-                                     list(chosen))
-            f = gf(w)
-            rows = [R[e] if e < self.k else
-                    f.matmul(self.coding_matrix[e - self.k][None, :],
-                             R)[0]
-                    for e in erased]
-            rows_gf = np.stack(rows, axis=0).astype(np.int64)
-            rows_bits = matrix_to_bitmatrix(rows_gf, w)
-        else:
-            rows_gf = None
-            _, Rbits = self._decode_rows(chosen, tuple(range(self.k)))
+        with section("ec.solve_rows", k=self.k, erased=len(erased)):
+            if self.coding_matrix is not None:
+                R = make_decoding_matrix(self.coding_matrix, w,
+                                         list(chosen))
+                f = gf(w)
+                rows = [R[e] if e < self.k else
+                        f.matmul(self.coding_matrix[e - self.k][None, :],
+                                 R)[0]
+                        for e in erased]
+                rows_gf = np.stack(rows, axis=0).astype(np.int64)
+                return rows_gf, matrix_to_bitmatrix(rows_gf, w)
+            _, Rbits = self._solve_decode_rows(chosen,
+                                               tuple(range(self.k)))
             # [I; B] · Rbits over GF(2): chunk e's w rows of it
             full = np.concatenate(
                 [Rbits, (self.bitmatrix.astype(np.int64)
                          @ Rbits.astype(np.int64) & 1).astype(np.uint8)],
                 axis=0)
-            rows_bits = np.concatenate(
+            return None, np.concatenate(
                 [full[e * w:(e + 1) * w] for e in erased], axis=0)
-        self._decode_cache[key] = (rows_gf, rows_bits)
-        return rows_gf, rows_bits
 
     def _decode_rows(self, chosen: tuple, data_erased: tuple):
         """(GF rows or None, bit rows) mapping chosen chunks -> erased data
         chunks; cached per erasure signature."""
-        key = (chosen, data_erased)
-        hit = self._decode_cache.get(key)
-        if hit is not None:
-            return hit
+        return self._decode_cache.get_or_solve(
+            ("dec", chosen, data_erased), self._solve_decode_rows, chosen,
+            data_erased)
+
+    def _solve_decode_rows(self, chosen: tuple, data_erased: tuple):
+        """A miss of _decode_rows (and the inverse a bit-matrix-only
+        code's recovery rows are composed through)."""
         if self.coding_matrix is not None:
             R = make_decoding_matrix(self.coding_matrix, self.w, list(chosen))
             rows_gf = R[list(data_erased)]
-            rows_bits = matrix_to_bitmatrix(rows_gf, self.w)
-        else:
-            kw = self.k * self.w
-            Gbits = np.concatenate([np.eye(kw, dtype=np.uint8),
-                                    self.bitmatrix], axis=0)
-            A = np.concatenate(
-                [Gbits[c * self.w:(c + 1) * self.w] for c in chosen], axis=0)
-            Rbits = bitmatrix_invert(A)
-            rows_gf = None
-            rows_bits = np.concatenate(
-                [Rbits[e * self.w:(e + 1) * self.w] for e in data_erased],
-                axis=0)
-        self._decode_cache[key] = (rows_gf, rows_bits)
-        return rows_gf, rows_bits
+            return rows_gf, matrix_to_bitmatrix(rows_gf, self.w)
+        kw = self.k * self.w
+        Gbits = np.concatenate([np.eye(kw, dtype=np.uint8),
+                                self.bitmatrix], axis=0)
+        A = np.concatenate(
+            [Gbits[c * self.w:(c + 1) * self.w] for c in chosen], axis=0)
+        Rbits = bitmatrix_invert(A)
+        return None, np.concatenate(
+            [Rbits[e * self.w:(e + 1) * self.w] for e in data_erased],
+            axis=0)

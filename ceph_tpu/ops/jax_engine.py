@@ -53,13 +53,21 @@ class ChainLRU:
     isa/ErasureCodeIsaTableCache.cc:253-306): erasure signatures are few
     (C(k+m, <=m)) and recovery hammers one signature for a whole rebuild.
     An entry is a one-argument callable: a row set bound to its family's
-    shared program (BoundRows; nothing compiles per entry) or, off a TPU
-    and on a mesh, a static program of its own, whose memory the cap
-    bounds."""
+    shared program (a BoundRows with ``bits``; nothing compiles per
+    entry) or, off a TPU and on a mesh, a static program of its own.
+    ``cap`` bounds what costs memory, the static programs.  A binding
+    is about 2 KiB of device memory and no executable, and a pool
+    whose reads complete on whichever k shards answer first (fast_read)
+    dispatches up to C(k+m, k) have-sets in a steady state, 495 at k=8
+    m=4: ``bindings_cap`` is as wide as the process's cache of solved
+    rows (engine.RecoveryRowsCache), so a pool's bindings stay
+    resident where ``cap`` alone evicted and rebound them for ever."""
 
-    def __init__(self, cap: int = 256):
+    def __init__(self, cap: int = 256, bindings_cap: int = 4096):
         self.cap = cap
+        self.bindings_cap = bindings_cap
         self._d: OrderedDict = OrderedDict()
+        self._count = {False: 0, True: 0}    # static programs, bindings
         self._lock = threading.Lock()
         # per-key in-progress markers: builder() is a full jit
         # trace+compile (seconds), so it must run OUTSIDE the lock —
@@ -67,6 +75,25 @@ class ChainLRU:
         # (other pools/geometries) proceed concurrently instead of
         # serializing every first-use behind one lock
         self._building: dict = {}
+
+    @staticmethod
+    def _is_binding(val) -> bool:
+        return getattr(val, "bits", None) is not None
+
+    def _insert_locked(self, key, val) -> None:
+        """Newest last; past its kind's bound the oldest of that kind
+        goes (static programs by ``cap``, bindings by
+        ``bindings_cap``)."""
+        self._d[key] = val
+        self._d.move_to_end(key)
+        light = self._is_binding(val)
+        self._count[light] += 1
+        while self._count[light] > (self.bindings_cap if light
+                                    else self.cap):
+            oldest = next(k for k, v in self._d.items()
+                          if self._is_binding(v) == light)
+            del self._d[oldest]
+            self._count[light] -= 1
 
     def get_or_build(self, key, builder):
         while True:
@@ -95,10 +122,7 @@ class ChainLRU:
                 ev.set()
                 raise
             with self._lock:
-                self._d[key] = val
-                self._d.move_to_end(key)
-                while len(self._d) > self.cap:
-                    self._d.popitem(last=False)
+                self._insert_locked(key, val)
                 self._building.pop(key, None)
             ev.set()
             return val

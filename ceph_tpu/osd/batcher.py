@@ -50,6 +50,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from . import ecutil
+from ..ops.engine import rows_cache_stats
 from ..utils import copytrack
 from ..utils import faults as faultlib
 from ..utils.device_ledger import DeviceLedgerAccum, overlap_stats
@@ -2262,6 +2263,9 @@ class EncodeBatcher:
             "row_sets_bound": getattr(backend, "row_sets_bound", 0),
             "row_programs_built": getattr(backend,
                                           "row_programs_built", 0),
+            # the process's caches of solved recovery rows
+            # (ops/engine.py RecoveryRowsCache): hits, misses, entries
+            **rows_cache_stats(),
             "device_errors": self.device_errors,
             "last_device_error": self.last_device_error,
             "prewarm_errors": list(cls._prewarm_errors),

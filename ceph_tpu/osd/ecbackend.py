@@ -252,6 +252,15 @@ class ECBackend(PGBackend):
         # total bytes requested through _start_read (observability +
         # the CLAY repair-bandwidth test)
         self.read_bytes_total = 0
+        # fast_read pools: reads that fanned to every up shard, and
+        # what their stragglers cost (answers that arrive after the
+        # k-th and are dropped at handle_message's tid-gone guard:
+        # read, checksummed, sent and decoded for nothing, by design)
+        self.fast_reads = 0
+        self.fast_read_stragglers = 0
+        self.fast_read_straggler_bytes = 0
+        # tid of a fast read that completed -> sub-reads still out
+        self._fast_read_tails: Dict[int, int] = {}
         self.subchunk_repairs = 0        # CLAY repairs taken
         self.repair_read_bytes = 0       # bytes those repairs read
         self.repair_whole_bytes = 0      # what whole-chunk would read
@@ -1574,13 +1583,36 @@ class ECBackend(PGBackend):
         def reads_done(received: Dict[int, bytes],
                        errors: Dict[int, int]) -> None:
             with section("ec.reconstruct", op=reqid,
-                         pg=self.host.pgid_str, bytes=length):
+                         pg=self.host.pgid_str, bytes=length,
+                         fanned=len(shards), used=len(received),
+                         missing=sum(1 for i in range(self.k)
+                                     if i not in received)):
                 reconstruct(received, errors)
 
         if hop_msg is not None:
             hop_msg.stamp_hop("read_queued")
         self._start_read(oid, chunk_off, chunk_len, shards, reads_done,
                          need=need, trace=trace)
+
+    #: completed fast reads remembered for their stragglers' count; a
+    #: shard that never answers leaves its entry to this bound
+    FAST_READ_TAILS_MAX = 1024
+
+    def _note_straggler(self, msg) -> None:
+        """A sub-read reply whose read is gone.  If that read was a
+        fast read that completed on its k-th answer, this is one of
+        the answers it did not wait for: dropped as before, and
+        counted with its payload."""
+        left = self._fast_read_tails.get(msg.tid)
+        if left is None:
+            return
+        if left <= 1:
+            del self._fast_read_tails[msg.tid]
+        else:
+            self._fast_read_tails[msg.tid] = left - 1
+        self.fast_read_stragglers += 1
+        self.fast_read_straggler_bytes += sum(
+            len(b) for _, _, b in msg.buffers)
 
     def _decode_impl(self, nbytes: int):
         """Decode through the CPU twin when the OSD batcher's learned
@@ -1727,8 +1759,17 @@ class ECBackend(PGBackend):
             rop.received[shard] = data
         if rop.need is not None and len(rop.received) >= rop.need:
             # fast_read: enough shards to reconstruct — don't wait for
-            # stragglers (their late replies hit the tid-gone guard)
+            # stragglers (their late replies hit the tid-gone guard,
+            # which counts them: _note_straggler)
             del self.in_flight_reads[rop.tid]
+            self.fast_reads += 1
+            tails = self._fast_read_tails
+            out = len(rop.want_shards) - len(rop.received) \
+                - len(rop.errors)
+            if out > 0:
+                tails[rop.tid] = out
+                while len(tails) > self.FAST_READ_TAILS_MAX:
+                    del tails[next(iter(tails))]   # its shard never answered
             rop.cb(rop.received, {})
             return
         if len(rop.received) + len(rop.errors) < len(rop.want_shards):
@@ -2164,6 +2205,7 @@ class ECBackend(PGBackend):
                 return True
             rop = self.in_flight_reads.get(msg.tid)
             if rop is None:
+                self._note_straggler(msg)
                 return True
             msg.stamp_hop("client_complete")
             _obs = getattr(self.host, "observe_hops", None)
@@ -2423,6 +2465,7 @@ class ECBackend(PGBackend):
         self._pending_objs.clear()
         self.waiting_commit.clear()
         self.in_flight_reads.clear()
+        self._fast_read_tails.clear()
         self.attr_fetches.clear()
         self.recovery_ops.clear()
         self._pipeline.clear()

@@ -1081,6 +1081,7 @@ class OSD(Dispatcher):
                            prefix=f"osd{self.whoami}-", n=10)}
             elif prefix == "dump_device":
                 out = self.encode_batcher.device_dump()
+                out["ec_reads"] = self._ec_read_counters()
             elif prefix == "dump_op_queue":
                 out = {"classes": self._refresh_op_queue_perf(),
                        "shards": [q.stats()
@@ -1109,6 +1110,16 @@ class OSD(Dispatcher):
         except Exception as e:
             retcode, rs = -22, str(e)
         return retcode, rs, out
+
+    def _ec_read_counters(self) -> dict:
+        """``dump_device`` ``ec_reads``: the EC backends' read counters
+        summed over this OSD's PGs (plain attributes of ECBackend)."""
+        names = ("read_bytes_total", "fast_reads",
+                 "fast_read_stragglers", "fast_read_straggler_bytes")
+        with self.pg_lock:
+            backends = [pg.backend for pg in self.pgs.values()]
+        return {n: sum(getattr(b, n, 0) for b in backends)
+                for n in names}
 
     def _health_dump(self) -> dict:
         """``dump_health``: this daemon's view of the named cluster

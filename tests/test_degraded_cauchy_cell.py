@@ -41,15 +41,15 @@ def test_the_cell_is_the_write_cells_pool_under_the_read_cells_traffic():
         assert cell.config[key] == healthy[key], key
     assert cell.row["traffic"] == spec.Cell("k4m2.degraded_read_4m") \
         .row["traffic"] == "radosbench_seq_4m_qd16"
-    assert [w["name"] for w in bench()["workloads"]][-1] == CELL
-    assert len(bench()["workloads"]) == 4
+    # the fourth cell; later PRs append theirs after it
+    assert [w["name"] for w in bench()["workloads"]][3] == CELL
     assert len(cell.row["why"]) <= 200
 
 
 def test_the_degraded_deployment_is_a_configuration_of_its_own():
     rows = {c["name"]: c for c in bench()["configs"]}
     row = rows["ec_cauchy_k10m4_15osd_2down"]
-    assert bench()["configs"][-1] == row
+    assert bench()["configs"][3] == row     # later PRs append after it
     assert row["source"] != rows["ec_cauchy_k10m4_15osd"]["source"]
     assert row["file"] != rows["ec_cauchy_k10m4_15osd"]["file"]
     assert len(row["source"]) <= 200 and len(row["why"]) <= 200
@@ -87,9 +87,12 @@ def test_the_cell_kills_osd_14_then_osd_13_with_their_data():
 def test_the_metrics_that_find_something_to_read_list_the_cell(metric,
                                                                cells):
     row = [m for m in bench()["per_layer"] if m["name"] == metric][0]
-    assert row["workloads"][-1] == CELL
+    # PR 34 appended the cell; a later PR's cells come after it
+    assert CELL in row["workloads"]
     if cells is not None:
-        assert row["workloads"] == cells
+        assert row["workloads"][:len(cells)] == cells
+    else:
+        assert row["workloads"].index(CELL) == 3
     assert row["moves"] == "throughput"
     reader = spec.metric_reader(metric)
     assert (reader.SOURCE, reader.LAYER, reader.MOVES) == \
