@@ -284,13 +284,15 @@ class CrimsonConnection(Connection):
                 return
             # stamped BEFORE encode so it rides the wire
             msg.stamp_hop("wire_sent")
-            with section("msgr.encode", type=type(msg).__name__):
+            with section("msgr.encode", type=type(msg).__name__) as sec:
+                c0 = copied_bytes()
                 for part in encode_frame_parts(
                         msg, compressor=self.msgr.compressor,
                         compress_min=self.msgr.compress_min,
                         crc_data=self.msgr.conf["ms_crc_data"]):
                     self._wq.append(part if isinstance(part, memoryview)
                                     else memoryview(part))
+                sec.set_metadata(copied=copied_bytes() - c0)
         if self._wq and not self._send_queued(sock, gen):
             return
         want = bool(self._wq)

@@ -71,8 +71,11 @@ class Message(abc.ABC):
     def encode_payload_parts(self) -> list:
         """Payload as an iovec-style list of buffers for scatter-gather
         sends.  Hot-path messages override this to keep large data
-        buffers by reference; the default materialises once."""
-        return [self.encode_payload()]
+        buffers by reference; the default materialises once, and
+        counts it as copied (``msgr.encode``'s ``copied``)."""
+        payload = self.encode_payload()
+        note_copied(len(payload))
+        return [payload]
 
     @classmethod
     @abc.abstractmethod
@@ -97,7 +100,9 @@ def encode_frame_parts(msg: Message, compressor=None,
     """Frame as an iovec list [head, *payload, crc] for scatter-gather
     ``socket.sendmsg`` — no payload byte is copied on the plain path.
     The CRC is folded incrementally over the parts, so it is identical
-    to the joined-frame CRC."""
+    to the joined-frame CRC.  What the encode copies into new buffers
+    (a payload with no parts of its own, the compressor's joined input)
+    is added to this thread's ``copied_bytes``."""
     parts = msg.encode_payload_parts()
     plen = sum(len(p) for p in parts)
     mtype = msg.TYPE
@@ -110,6 +115,8 @@ def encode_frame_parts(msg: Message, compressor=None,
             payload = bytes(payload)  # copycheck: ok - compressor input materialisation
         if len(parts) > 1:
             copytrack.note_copy(plen, "msg.compress_join")
+        if payload is not parts[0]:
+            note_copied(plen)
         comp = compressor.compress(payload)
         # require a REAL win, not a few bytes: a sub-percent size edge
         # is not worth the receiver's decompress cost (reference's

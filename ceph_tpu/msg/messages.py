@@ -388,7 +388,7 @@ class MOSDECSubOpReadReply(Message):
         self.attrs = attrs or []       # (oid, {attr: value})
         self.errors = errors or []     # (oid, -errno)
 
-    def encode_payload(self) -> bytes:
+    def _enc(self) -> Encoder:
         e = Encoder()
         e.str(self.pgid).i32(self.shard).i32(self.from_osd)
         e.u64(self.tid).u32(self.epoch)
@@ -402,7 +402,14 @@ class MOSDECSubOpReadReply(Message):
         for oid, err in self.errors:
             e.str(oid).i32(err)
         encode_ledger(e, self.hops)
-        return e.build()
+        return e
+
+    def encode_payload(self) -> bytes:
+        return self._enc().build()
+
+    def encode_payload_parts(self) -> list:
+        # a shard's read bytes ride by reference to the socket
+        return self._enc().build_parts()
 
     @classmethod
     def decode_payload(cls, buf: bytes) -> "MOSDECSubOpReadReply":

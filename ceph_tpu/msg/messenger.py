@@ -515,11 +515,13 @@ class Connection:
                     # stamped BEFORE encode so it rides the wire
                     msg.stamp_hop("wire_sent")
                     with section("msgr.encode", d=self.msgr.name,
-                                 type=type(msg).__name__):
+                                 type=type(msg).__name__) as sec:
+                        c0 = copied_bytes()
                         parts = encode_frame_parts(
                             msg, compressor=self.msgr.compressor,
                             compress_min=self.msgr.compress_min,
                             crc_data=self.msgr.conf["ms_crc_data"])
+                        sec.set_metadata(copied=copied_bytes() - c0)
                     with section("msgr.send", d=self.msgr.name,
                                  peer=self.peer_name,
                                  bytes=sum(map(len, parts))):

@@ -1714,10 +1714,14 @@ class ECBackend(PGBackend):
 
     def _chunk_read(self, oid: str, shard: int, off: int,
                     length: int) -> Tuple[bytes, int]:
+        """-> (the shard's bytes, 0) or (b"", -errno).  The bytes are
+        copied once, by the store's gather: checked against HashInfo in
+        place, then handed on as a read-only view (to the reply's
+        iovecs, or to the read op of a local shard)."""
         try:
-            data = self.host.store.read(
+            data = memoryview(self.host.store.read_buffer(
                 self.host.coll_of(shard), GHObject(oid, shard), off,
-                length)
+                length))
         except FileNotFoundError:
             return b"", -2
         except OSError:
@@ -1743,11 +1747,14 @@ class ECBackend(PGBackend):
                 hinfo = None
             if hinfo is not None and \
                     hinfo.total_chunk_size == len(data):
-                with section("crc.host", bytes=len(data), blocks=1):
+                # a writable buffer is read in place; crc32c copies an
+                # immutable one first (a store with no private gather)
+                with section("crc.host", bytes=len(data), blocks=1,
+                             copied=len(data) if data.readonly else 0):
                     crc = ecutil.chunk_crc(data)
                 if crc != hinfo.crcs[shard]:
                     return b"", -5
-        return data, 0
+        return data.toreadonly(), 0
 
     def _read_piece(self, rop: _ReadOp, shard: int, data: bytes,
                     err: int) -> None:

@@ -845,6 +845,11 @@ class BlueStore(BlockStore):
         self._barrier(coll, obj)
         return super().read(coll, obj, offset, length)
 
+    def read_buffer(self, coll: str, obj: GHObject, offset: int = 0,
+                    length: Optional[int] = None) -> memoryview:
+        self._barrier(coll, obj)
+        return super().read_buffer(coll, obj, offset, length)
+
     def stat(self, coll: str, obj: GHObject):
         self._barrier(coll, obj)
         return super().stat(coll, obj)

@@ -576,6 +576,14 @@ class ObjectStore(abc.ABC):
         """Byte extent; length=None reads to EOF.  Raises FileNotFoundError
         for a missing object (maps -ENOENT)."""
 
+    def read_buffer(self, coll: str, obj: GHObject, offset: int = 0,
+                    length: Optional[int] = None):
+        """``read`` for a caller that checks the bytes in place and
+        passes them on by reference (the EC sub-read): a bytes-like
+        the store no longer touches.  A store whose read gathers into
+        a private buffer hands out a view of it; the rest read."""
+        return self.read(coll, obj, offset, length)
+
     @abc.abstractmethod
     def stat(self, coll: str, obj: GHObject) -> ObjectStat:
         ...

@@ -177,10 +177,12 @@ class Encoder:
 
 
 class _Copied(threading.local):
-    """Bytes ``Decoder.bytes()`` has copied out of buffers on this
-    thread: a running count a caller differences around one decode
-    (the messenger's ``msgr.decode`` section reports the increment as
-    ``copied``).  Per thread, so no lock and no torn add."""
+    """Payload bytes the frame codec has copied on this thread:
+    ``Decoder.bytes()`` out of buffers, and a frame's encode into new
+    ones.  A running count a caller differences around one decode or
+    encode (the messenger's ``msgr.decode`` and ``msgr.encode``
+    sections report the increment as ``copied``).  Per thread, so no
+    lock and no torn add."""
     n = 0
 
 
@@ -188,12 +190,13 @@ _copied = _Copied()
 
 
 def copied_bytes() -> int:
-    """This thread's running count of bytes decoders copied out."""
+    """This thread's running count of bytes the codec copied."""
     return _copied.n
 
 
 def note_copied(nbytes: int) -> None:
-    """Add a copy made on a decoder's behalf (a decompressed frame)."""
+    """Add a copy made on the codec's behalf (a decompressed frame, a
+    payload joined for the wire)."""
     _copied.n += nbytes
 
 
