@@ -1,0 +1,12 @@
+"""Self CPU seconds of the batcher.* and dispatch.* sections, the host
+side of the device path (their ``cpu_ns`` less their child
+sections'), over the CPU seconds of all sections, both within the
+probed nests.  ``None`` where no section carries ``cpu_ns``."""
+SOURCE = "program_span"
+LAYER = "batcher"
+MOVES = "throughput"
+
+
+def read(ctx):
+    from harness import cpu
+    return cpu.share(ctx, "batcher")

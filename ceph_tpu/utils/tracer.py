@@ -19,11 +19,20 @@ span when on; one branch when off).
 
 ``section(name, **meta)`` is the other span: bound to one thread,
 nesting, and on the clock of ``jax.profiler``.  It is a
-``jax.profiler.TraceAnnotation`` and nothing else, so it keeps no
-ring, lock, clock or option: with no profiler session active it
-records nothing, and inside one (``benchmark/run.py --trace 1``, an
-operator's ``jax.profiler.start_trace``) it lands on its thread's line
-of the same ``.xplane.pb`` as the device's ``XLA Ops``.  Rules of
+``jax.profiler.TraceAnnotation``, so it keeps no ring, lock or option:
+with no profiler session active it is a shared no-op and builds
+nothing, and inside one (``benchmark/run.py --trace 1``, an operator's
+``jax.profiler.start_trace``) it lands on its thread's line of the
+same ``.xplane.pb`` as the device's ``XLA Ops``.  One nest in
+``1 / PROBE_SHARE`` (drawn at its thread's outermost section) is
+probed whole: each of its sections carries ``cpu_ns``, the thread's
+CPU time inside it (``CLOCK_THREAD_CPUTIME_ID``); the other nests carry
+no keyword of the tracer's.  A section's wall time less ``cpu_ns`` is
+time its thread was off the CPU with work in hand: waiting for the
+interpreter lock, or in a blocking call.  The probe never gives the
+interpreter up, but under a sandboxed kernel (gVisor) it is a system
+call of some 6 us, so it is taken in a share of nests only: whole
+nests, drawn at random, keep the sums of a window unbiased.  Rules of
 placement (PERF.md, "Spans and counters"): a section covers work,
 never parking (no ``select``, ``Condition.wait`` or idle queue
 ``get`` inside one); names are fixed ``<layer>.<verb>`` strings, ids
@@ -42,7 +51,8 @@ from typing import Deque, Dict, List, Optional
 
 
 class _NoSection:
-    """What ``section`` hands out in a process without JAX."""
+    """What ``section`` hands out where no profiler session records:
+    in a process without JAX, or with none started."""
     __slots__ = ()
 
     def __enter__(self) -> "_NoSection":
@@ -57,6 +67,41 @@ class _NoSection:
 
 _NO_SECTION = _NoSection()
 _annotation = None      # jax.profiler.TraceAnnotation, once JAX is loaded
+#: the share of nests whose sections carry ``cpu_ns``
+PROBE_SHARE = 1 / 16
+_draw = random.Random().random
+_nest = threading.local()   # .probe: the open nest's draw; None outside one
+
+
+class _Section:
+    """A recording section that reads its thread's CPU clock: the
+    outermost of its thread (``outer``), which draws for its nest, or
+    one inside a probed nest."""
+    __slots__ = ("_ta", "_outer", "_t0")
+
+    def __init__(self, ta, outer: bool):
+        self._ta = ta
+        self._outer = outer
+
+    def __enter__(self) -> "_Section":
+        self._ta.__enter__()
+        if self._outer:
+            probe = _nest.probe = _draw() < PROBE_SHARE
+            if not probe:
+                self._t0 = None
+                return self
+        self._t0 = time.thread_time_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._t0 is not None:
+            self._ta.set_metadata(cpu_ns=time.thread_time_ns() - self._t0)
+        if self._outer:
+            _nest.probe = None
+        self._ta.__exit__(*exc)
+
+    def set_metadata(self, **meta) -> None:
+        self._ta.set_metadata(**meta)
 
 
 def section(name: str, **meta):
@@ -72,6 +117,13 @@ def section(name: str, **meta):
         ta = _bind()
         if ta is None:
             return _NO_SECTION
+    if not ta.is_enabled():
+        return _NO_SECTION
+    probe = getattr(_nest, "probe", None)
+    if probe is None:
+        return _Section(ta(name, **meta), True)
+    if probe:
+        return _Section(ta(name, **meta), False)
     return ta(name, **meta)
 
 

@@ -26,6 +26,27 @@ from ceph_tpu.utils import compile_cache  # noqa: E402
 compile_cache.configure()
 
 
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def fresh_span_reduction():
+    """``benchmark/harness/spans.py`` keeps its last reduction under the
+    ``id()`` of the trace it reduced.  A test's trace, once freed, can
+    leave that id to the next test's trace, which would then read the
+    old reduction (a reader finds an empty trace where the test gave
+    one with sections).  Each test starts and ends with none kept."""
+    _forget_span_reduction()
+    yield
+    _forget_span_reduction()
+
+
+def _forget_span_reduction():
+    spans = sys.modules.get("harness.spans")
+    if spans is not None:
+        spans._cache.clear()
+
+
 def pytest_configure(config):
     # tier-1 runs with -m 'not slow'; the slow tier holds long thrash
     # soaks (e.g. the crimson RadosModel run) that CI runs separately

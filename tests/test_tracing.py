@@ -299,6 +299,10 @@ SECTIONS = {
 # crc.device opens only in deep scrub's device windows, where
 # jax.default_backend() is not "cpu" (osd/ecbackend.py): no CPU case.
 
+def own_keywords(meta: dict) -> dict:
+    """A section's keywords less the one a probed section carries."""
+    return {k: v for k, v in meta.items() if k != "cpu_ns"}
+
 
 def _drive_cluster():
     conf = make_conf(osd_objectstore="bluestore")
@@ -334,6 +338,13 @@ def _drive_cluster():
             io.write(n, b"X" * 100, 70000)      # sub-chunk: reads back
         for n in names:
             assert len(io.read(n, length=size)) == size
+        # a remote sub-read is dispatched where it arrives only when its
+        # connection's reactor owns its PG (else it hops through that
+        # reactor's mailbox): reads over many PGs make one of each sure
+        for i in range(24):
+            io.write_full(f"spread{i}", bytes([i]) * 8192)
+        for i in range(24):
+            assert io.read(f"spread{i}", length=8192) == bytes([i]) * 8192
         cl.kill_osd(0, lose_data=True)
         cl.wait_for_osd_down(0)
         for n in names:                          # degraded: reconstructs
@@ -446,6 +457,7 @@ def traced(tmp_path_factory):
     import jax
     sys.path.insert(0, os.path.join(ROOT, "benchmark"))
     from harness import spans
+    from ceph_tpu.utils import tracer
     from ceph_tpu.utils.tracer import section
     log_dir = str(tmp_path_factory.mktemp("trace"))
     with section("msgr.send", bytes=1):
@@ -453,12 +465,14 @@ def traced(tmp_path_factory):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
-    jax.profiler.start_trace(log_dir, profiler_options=opts)
-    try:
-        extras = _drive_instruments()
-        extras.update(_drive_cluster())
-    finally:
-        jax.profiler.stop_trace()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(tracer, "PROBE_SHARE", 1.0)     # every nest probed
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        try:
+            extras = _drive_instruments()
+            extras.update(_drive_cluster())
+        finally:
+            jax.profiler.stop_trace()
     plain = spans.load(log_dir)
     # every section with the names of the sections it nests under
     seen = {}
@@ -472,7 +486,8 @@ def traced(tmp_path_factory):
                 ([n for _, n, _ in stack], meta,
                  [m for _, _, m in stack]))
             stack.append((e, name, meta))
-    return {"plain": plain, "seen": seen, "spans": spans, **extras}
+    return {"plain": plain, "seen": seen, "spans": spans,
+            "log_dir": log_dir, **extras}
 
 
 @pytest.mark.parametrize("name", sorted(SECTIONS))
@@ -528,7 +543,8 @@ def test_a_bluestore_fold_is_one_host_crc_section_and_no_dispatch(traced):
     thread with the batch each one call covered; nothing of the write
     opens ``crc.device`` or stages a byte for the device."""
     def under_the_store(name):
-        return [meta for _, meta, theirs in traced["seen"].get(name, [])
+        return [own_keywords(meta)
+                for _, meta, theirs in traced["seen"].get(name, [])
                 if any(m.get("op") == "foldtest:1" for m in theirs)]
     assert traced["folds"] == 2
     assert under_the_store("crc.host") == [
@@ -584,7 +600,8 @@ def test_a_packet_dispatch_opens_the_byte_dispatchs_sections(traced):
     under = {}
     for name in ("dispatch.stage_acquire", "dispatch.h2d", "dispatch.call",
                  "dispatch.wait", "dispatch.d2h"):
-        under[name] = [meta for _, meta, theirs in traced["seen"][name]
+        under[name] = [own_keywords(meta)
+                       for _, meta, theirs in traced["seen"][name]
                        if any(m.get("lane") == "packettest"
                               for m in theirs)]
         assert len(under[name]) == 1, name
@@ -619,6 +636,176 @@ def test_section_records_nothing_and_costs_little_without_a_session():
 def test_no_section_of_before_the_session_is_in_the_trace(traced):
     sends = [m for _, m, _ in traced["seen"]["msgr.send"]]
     assert all(m.get("bytes") != 1 for m in sends)
+
+
+@pytest.mark.parametrize("name", sorted(SECTIONS))
+def test_section_carries_its_threads_cpu(traced, name):
+    for _, meta, _ in traced["seen"][name]:
+        assert isinstance(meta.get("cpu_ns"), int) and meta["cpu_ns"] >= 0, \
+            (name, meta)
+
+
+def test_the_cpu_printer_reads_the_session_and_its_largest_ack_gap(
+        traced, capsys):
+    from harness import cpu
+    assert cpu.main(["cpu.py", traced["log_dir"]]) == 0
+    out = capsys.readouterr().out
+    assert out.startswith("window ") and "cores" in out
+    rows = {}                       # the window's table comes first
+    for ln in out.splitlines():
+        if ln.startswith("   ") and ln.split()[0] in SECTIONS:
+            rows.setdefault(ln.split()[0], ln.split()[1:])
+    assert int(rows["msgr.recv"][0]) == len(traced["seen"]["msgr.recv"])
+    assert "-- largest ack gap" in out
+
+
+def test_section_without_a_session_is_the_shared_null_section():
+    from ceph_tpu.utils.tracer import _NO_SECTION, section
+    assert section("msgr.send", bytes=4096, peer="osd.1") is _NO_SECTION
+    with section("pg.do_op", op="c:1") as s:
+        assert s is _NO_SECTION
+        s.set_metadata(error="none recorded")
+
+
+@pytest.fixture(scope="module")
+def nests(tmp_path_factory):
+    """One session, 400 nests of three sections at each probe share:
+    ``pg.do_op`` around ``crc.host`` around ``store.read``."""
+    import jax
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    from harness import spans
+    from ceph_tpu.utils import tracer
+    from ceph_tpu.utils.tracer import section
+    log_dir = str(tmp_path_factory.mktemp("nests"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            for share in SHARES:
+                mp.setattr(tracer, "PROBE_SHARE", share)
+                for i in range(400):
+                    with section("pg.do_op", op=f"{share}:{i}"):
+                        with section("crc.host"):
+                            with section("store.read") as s:
+                                s.set_metadata(blocks=1)
+    finally:
+        jax.profiler.stop_trace()
+    out = {}
+    for evs in spans.clipped(spans.load(log_dir), 0, float("inf")):
+        stack = []
+        for s, e, name, meta in evs:
+            while stack and stack[-1][0] <= s:
+                stack.pop()
+            if name == "pg.do_op":
+                stack.append((e, out.setdefault(meta["op"], [])))
+            if stack:
+                stack[-1][1].append((name, meta))
+    return out
+
+
+SHARES = (0.0, 0.5, 1.0)
+
+
+@pytest.mark.parametrize("share", SHARES)
+def test_a_nest_is_probed_whole_or_not_at_all(nests, share):
+    """The outermost section draws for its nest: all three sections
+    carry ``cpu_ns`` or none does; about ``share`` of the nests do, and
+    every section is in the trace either way."""
+    probed = 0
+    for i in range(400):
+        nest = nests[f"{share}:{i}"]
+        assert [n for n, _ in nest] == ["pg.do_op", "crc.host", "store.read"]
+        has = {"cpu_ns" in m for _, m in nest}
+        assert len(has) == 1, nest
+        probed += has.pop()
+        assert nest[-1][1]["blocks"] == 1
+    assert {0.0: probed == 0, 0.5: 120 < probed < 280,
+            1.0: probed == 400}[share], probed
+
+
+def _spin(stop: threading.Event) -> None:
+    while not stop.is_set():
+        pass
+
+
+@pytest.fixture(scope="module")
+def probed(tmp_path_factory):
+    """One session of sections around known work, each the only section
+    of its name: a busy loop, twenty interpreter releases against a
+    thread that spins, keywords set at both ends, and two hundred empty
+    sections against the spinner."""
+    import jax
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    from harness import spans
+    from ceph_tpu.utils.tracer import section
+    from ceph_tpu.utils import tracer
+    log_dir = str(tmp_path_factory.mktemp("probes"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    mp = pytest.MonkeyPatch()
+    mp.setattr(tracer, "PROBE_SHARE", 1.0)         # every nest probed
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    stop = threading.Event()
+    spinner = threading.Thread(target=_spin, args=(stop,))
+    try:
+        with section("pg.do_op", op="probe:1"):
+            t0 = time.thread_time()
+            while time.thread_time() - t0 < 0.05:
+                pass
+        with section("crc.host", bytes=3) as s:
+            s.set_metadata(blocks=1, error="probe")
+        spinner.start()
+        time.sleep(0.01)
+        with section("msgr.recv"):
+            for _ in range(20):
+                time.sleep(0)           # gives the interpreter up
+        for _ in range(200):
+            with section("store.read"):
+                pass
+    finally:
+        stop.set()
+        if spinner.is_alive():
+            spinner.join(5)
+        jax.profiler.stop_trace()
+        mp.undo()
+    assert not spinner.is_alive()
+    out = {}
+    for evs in spans.load(log_dir)["lines"]:
+        for name, _, dur, meta in evs:
+            out.setdefault(name, []).append((dur, meta))
+    return out
+
+
+def test_section_around_a_busy_loop_is_mostly_cpu(probed):
+    [(wall_ns, meta)] = probed["pg.do_op"]
+    assert meta["op"] == "probe:1"
+    assert meta["cpu_ns"] >= 0.05e9 and meta["cpu_ns"] >= wall_ns / 2
+
+
+def test_section_around_interpreter_releases_is_mostly_off_the_cpu(probed):
+    """Each release against a spinning thread waits out its switch
+    interval off the CPU: little CPU, much wall."""
+    [(wall_ns, meta)] = probed["msgr.recv"]
+    assert meta["cpu_ns"] < wall_ns / 10
+
+
+def test_set_metadata_keywords_land_beside_the_sections_own(probed):
+    [(_, meta)] = probed["crc.host"]
+    assert own_keywords(meta) == {"bytes": 3, "blocks": 1, "error": "probe"}
+    assert "cpu_ns" in meta
+
+
+def test_the_probes_do_not_give_the_interpreter_up(probed):
+    """An empty probed section is short even with a thread waiting for
+    the interpreter: had a probe released it, the spinner would take it
+    and every one would wait out a switch interval.  (A forced switch
+    every interval can still land inside one of them.)"""
+    import statistics
+    reads = probed["store.read"]
+    assert len(reads) == 200 and all("cpu_ns" in m for _, m in reads)
+    assert statistics.median(d for d, _ in reads) < \
+        sys.getswitchinterval() * 1e9 / 4
 
 
 def test_section_without_jax_is_a_null_context():
